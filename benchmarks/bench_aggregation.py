@@ -26,8 +26,7 @@ DIM = 64
 # Gather-variant races run the REAL kernel body (interpret mode, CPU), so
 # the graph is kept small and the schedules coarse (few grid steps).  The
 # two schedules bracket the decision space: a compute-comfortable f32 tile
-# and a memory-bound bf16 tile (wide window, full-lane dt) where the
-# one-hot W build is pure overhead and `direct` should win.
+# and a memory-bound bf16 tile (wide window, full-lane dt).
 VARIANT_DATASET = "cora"
 VARIANT_MAX_NODES = 800
 VARIANT_SCHEDULES = [

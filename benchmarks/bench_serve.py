@@ -11,7 +11,8 @@ Two layers of measurement:
   p50/p99/SLO-attainment + completed-throughput, burst phase
   (``rate_rps=inf``) for saturation throughput.  With ``--shards 2`` the
   same comparison additionally runs against the 2-way sharded
-  halo-exchange executor in a forced-device subprocess (the
+  halo-exchange executor: on a TPU in this process (a chip belongs to the
+  one process that holds it), on CPU in a forced-device subprocess (the
   `bench_shard` pattern: device counts are fixed before jax initializes).
 
     PYTHONPATH=src python -m benchmarks.bench_serve [--smoke] \
@@ -69,7 +70,7 @@ def _build_serve_fn(prof: dict, shards: int):
                                ).astype(np.float32)
     cfg = GNNConfig(arch="gcn", in_dim=prof["in_dim"],
                     hidden_dim=prof["hidden"], num_classes=4,
-                    num_layers=2, backend="xla")
+                    num_layers=2)
     if shards > 1:
         serve_fn = make_sharded_serve_fn(g, feat, cfg, num_shards=shards,
                                          tune_iters=prof["tune_iters"])
@@ -177,7 +178,7 @@ def _sync_rows(smoke: bool) -> None:
     rng = np.random.default_rng(0)
     for arch in ["gcn", "gin"]:
         cfg = GNNConfig(arch=arch, in_dim=16, hidden_dim=16, num_classes=4,
-                        num_layers=2, backend="xla")
+                        num_layers=2)
         feat = rng.standard_normal((g.num_nodes, 16)).astype(np.float32)
         eng = ServingEngine(g, feat, cfg,
                             serving=ServingConfig(max_batch=batch,
@@ -264,12 +265,17 @@ def _comparison(configs: list) -> dict:
 
 def run(smoke: bool = True, *, shards: int = 1,
         json_out: str | None = None) -> None:
+    import jax
+
     from repro.obs import run_context
 
     _sync_rows(smoke)
     configs = _async_configs(smoke, shards=1)
     if shards > 1:
-        configs += _spawn_sharded(smoke, shards)
+        # the chip belongs to this process now: never spawn a child for it
+        configs += (_async_configs(smoke, shards)
+                    if jax.default_backend() == "tpu"
+                    else _spawn_sharded(smoke, shards))
     comparison = _comparison(configs)
     doc = {"schema": SCHEMA, "smoke": smoke, "context": run_context(),
            "configs": configs, "comparison": comparison}
@@ -292,13 +298,15 @@ def main(argv=None) -> int:
                    help="tiny graph + few requests (CI budget)")
     p.add_argument("--shards", type=int, default=1,
                    help="additionally measure the P-way sharded executor "
-                        "cells in a forced-device subprocess")
+                        "cells (on CPU: in a forced-device subprocess)")
     p.add_argument("--json-out", default=None,
                    help="write the BENCH_serve.json document here")
     p.add_argument("--worker", action="store_true",
                    help="internal: run the sharded measurement in THIS "
                         "process (expects forced devices already set)")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.worker:
         _worker(smoke=args.smoke, shards=args.shards)
     else:

@@ -3,10 +3,11 @@
 Times one full-graph GCN optimizer step (fwd+bwd through the per-shard
 group schedules, all-gather halo exchange, psum'd grads) at shard counts
 {1, 2, 4} against the single-device step, and reports the shard splitter's
-balance/halo metrics.  Device counts are fixed per process before jax
-initializes, so the measurement runs in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` — on real
-multi-chip hardware the same code path runs on the actual devices.
+balance/halo metrics.  On a TPU host the measurement runs in this process
+over the chips it holds (shard counts above the device count are
+skipped).  On CPU, device counts are fixed per process before jax
+initializes, so it runs in a subprocess with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 
     PYTHONPATH=src python -m benchmarks.bench_shard [--smoke]
 
@@ -24,6 +25,7 @@ SHARD_COUNTS = (1, 2, 4)
 
 def _worker(smoke: bool) -> None:
     """Body that runs inside the forced-device subprocess."""
+    import jax
     import jax.numpy as jnp
     import numpy as np
 
@@ -44,7 +46,7 @@ def _worker(smoke: bool) -> None:
     labels = rng.integers(0, 4, num_nodes).astype(np.int32)
 
     cfg = GNNConfig(arch="gcn", in_dim=in_dim, hidden_dim=hidden,
-                    num_classes=4, num_layers=2, backend="xla")
+                    num_classes=4, num_layers=2)
     model = build_gnn(g, cfg, reorder="on", tune_iters=2 if smoke else 4,
                       with_backward=True)
     batch = {"feat": jnp.asarray(model.plan.renumber_features(feat)),
@@ -61,7 +63,7 @@ def _worker(smoke: bool) -> None:
          f"tiles={model.plan.stats['tiles']}")
 
     for P in SHARD_COUNTS:
-        if P == 1:
+        if P == 1 or P > jax.device_count():
             continue
         shards = model.plan.shards(P)
         st = shards.stats()
@@ -77,6 +79,8 @@ def _worker(smoke: bool) -> None:
     import dataclasses
 
     P = 2
+    if P > jax.device_count():
+        return
     cfg16 = dataclasses.replace(cfg, feat_dtype="bfloat16")
     model16 = build_gnn(
         g, cfg16, reorder="on", tune_iters=2 if smoke else 4,
@@ -97,7 +101,14 @@ def _worker(smoke: bool) -> None:
 
 
 def run(smoke: bool = True) -> None:
-    """Spawn the forced-device subprocess and stream its CSV lines."""
+    """Measure here on a TPU; on CPU spawn the forced-device subprocess and
+    stream its CSV lines."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        # a chip belongs to one process: this one holds it now
+        _worker(smoke)
+        return
     env = dict(
         os.environ,
         XLA_FLAGS="--xla_force_host_platform_device_count="
@@ -134,6 +145,8 @@ def main(argv=None) -> int:
                    help="internal: run the measurement in THIS process "
                         "(expects forced devices already set)")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.worker:
         _worker(smoke=args.smoke)
     else:

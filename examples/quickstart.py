@@ -26,10 +26,10 @@ print(f"schedule: {plan.stats['tiles']} tiles, "
       f"occupancy {plan.stats['slot_occupancy']:.2f}, "
       f"{plan.stats['flushes']} output flushes")
 
-# 3. bind the plan to an executor.  backend="pallas_interpret" runs the
-#    actual TPU Pallas kernel body (interpreted on CPU); backend="xla" is
-#    the fast CPU path with identical semantics.
-ex = PlanExecutor(plan, backend="xla")
+# 3. bind the plan to an executor.  The backend follows the platform: the
+#    compiled Pallas kernel on a TPU, the XLA reference (same semantics)
+#    elsewhere; backend="pallas_interpret" runs the kernel body on CPU.
+ex = PlanExecutor(plan)
 
 # 4. aggregate: out[v] = sum of neighbor embeddings
 feat = jnp.asarray(np.random.default_rng(0).standard_normal(

@@ -17,7 +17,7 @@ from repro.serving import ServingConfig, ServingEngine
 def main():
     g = random_power_law(2000, 6.0, seed=0)
     cfg = GNNConfig(arch="gcn", in_dim=16, hidden_dim=16, num_classes=4,
-                    num_layers=2, backend="xla")
+                    num_layers=2)
     rng = np.random.default_rng(0)
     feat = rng.standard_normal((g.num_nodes, 16)).astype(np.float32)
 

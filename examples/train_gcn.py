@@ -29,8 +29,9 @@ def main():
     ap.add_argument("--dataset", default="cora")
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--max-nodes", type=int, default=2708)
-    ap.add_argument("--backend", default="xla",
-                    choices=["xla", "pallas", "pallas_interpret"])
+    ap.add_argument("--backend", default=None,
+                    choices=["xla", "pallas", "pallas_interpret"],
+                    help="default: pallas on a TPU, xla elsewhere")
     ap.add_argument("--arch", default="gcn", choices=["gcn", "gin", "gat"])
     ap.add_argument("--fail-at", type=int, default=150,
                     help="inject a simulated crash at this step (-1 = off)")
@@ -51,7 +52,7 @@ def main():
     print(f"[train_gcn] advisor: gs={model.plan.config.gs} "
           f"gpt={model.plan.config.gpt} src_win={model.plan.config.src_win} "
           f"renumbered={model.plan.perm is not None} "
-          f"tiles={model.plan.stats['tiles']} backend={args.backend} "
+          f"tiles={model.plan.stats['tiles']} backend={cfg.backend} "
           f"bwd_tiles={model.plan.partition_bwd.num_tiles if model.plan.partition_bwd is not None else '-'}")
     featp = jnp.asarray(model.plan.renumber_features(feat))
     labp = jnp.asarray(model.plan.renumber_features(labels))

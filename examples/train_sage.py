@@ -36,8 +36,9 @@ def main():
     ap.add_argument("--max-nodes", type=int, default=6000)
     ap.add_argument("--batch-nodes", type=int, default=512)
     ap.add_argument("--fanouts", default="10,5")
-    ap.add_argument("--backend", default="xla",
-                    choices=["xla", "pallas", "pallas_interpret"])
+    ap.add_argument("--backend", default=None,
+                    choices=["xla", "pallas", "pallas_interpret"],
+                    help="default: pallas on a TPU, xla elsewhere")
     args = ap.parse_args()
 
     fanouts = tuple(int(f) for f in args.fanouts.split(","))

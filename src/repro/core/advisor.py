@@ -112,7 +112,7 @@ def plan_for(g: CSRGraph, *, arch: str = "gcn", in_dim: int = 128,
     Example
     -------
     >>> plan = plan_for(g, arch="gcn", edge_vals=vals, with_backward=True)
-    >>> ex = PlanExecutor(plan, backend="pallas_interpret")
+    >>> ex = PlanExecutor(plan)          # "pallas" on a TPU, else "xla"
     >>> grads = jax.grad(lambda f: ex(f).sum())(feat)      # transposed kernel
     """
     if props is None:

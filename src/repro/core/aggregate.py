@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.plan import Plan
-from repro.kernels.ops import DeviceSchedule, aggregate as _kernel_aggregate
+from repro.kernels.ops import (DeviceSchedule, aggregate as _kernel_aggregate,
+                               resolve_backend)
 
 __all__ = ["PlanExecutor"]
 
@@ -21,11 +22,11 @@ __all__ = ["PlanExecutor"]
 class PlanExecutor:
     """Executable aggregation bound to one plan (device-resident schedule)."""
 
-    def __init__(self, plan: Plan, *, backend: str = "pallas_interpret"):
+    def __init__(self, plan: Plan, *, backend: str | None = None):
         self.plan = plan
         self.sched = plan.sched()
         self.sched_bwd = plan.sched_bwd()
-        self.backend = backend
+        self.backend = resolve_backend(backend)
         self.dt = plan.config.dt
         self.variant = plan.config.variant
         # outputs follow the plan's dtype policy (f32 accumulation inside;
@@ -39,7 +40,7 @@ class PlanExecutor:
 
     @classmethod
     def from_schedule(cls, sched: DeviceSchedule, *, dt: int, variant: str,
-                      backend: str = "pallas_interpret",
+                      backend: str | None = None,
                       sched_bwd: DeviceSchedule = None,
                       out_dtype="float32") -> "PlanExecutor":
         """Plan-less executor over a bare schedule.
@@ -55,8 +56,10 @@ class PlanExecutor:
             array members + static ints).  Arrays may be jax tracers.
         dt : int — dim-tile width handed to the kernel (clamped to the
             feature width at call time).
-        variant : "folded" | "slot_onehot" — kernel gather variant.
-        backend : see `repro.kernels.ops` Backend dispatch rules.
+        variant : kernel gather variant
+            (`repro.kernels.group_aggregate.VARIANTS`).
+        backend : see `repro.kernels.ops` Backend dispatch rules; None
+            resolves by platform.
         sched_bwd : optional TRANSPOSED-graph schedule (same duck typing);
             when given the executor is differentiable on every backend —
             the sampled mini-batch trainer passes one per layer block.
@@ -73,7 +76,7 @@ class PlanExecutor:
         ex.plan = None
         ex.sched = sched
         ex.sched_bwd = sched_bwd
-        ex.backend = backend
+        ex.backend = resolve_backend(backend)
         ex.dt = dt
         ex.variant = variant
         ex.out_dtype = jnp.dtype(out_dtype)

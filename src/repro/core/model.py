@@ -24,11 +24,12 @@ import math
 import numpy as np
 
 from repro.core.extractor import GraphProps
-from repro.hw import TPU_V5E, TPUSpec
+from repro.hw import LANES, SUBLANES, TPUSpec, device_spec
+from repro.kernels.ops import dim_tile
 
 __all__ = ["AggConfig", "paper_eq2_latency", "KernelModel", "vmem_working_set",
-           "config_is_feasible", "config_infeasibility", "feat_dtype_align",
-           "feat_dtype_bytes"]
+           "config_is_feasible", "config_infeasibility",
+           "feat_dtype_align", "feat_dtype_bytes"]
 
 # The end-to-end dtype policy's vocabulary.  ``feat_dtype`` names the dtype
 # of node features and activations flowing through the aggregation kernel;
@@ -134,39 +135,61 @@ def predict_tiles(props: GraphProps, cfg: AggConfig) -> float:
     return max(padded / cfg.gpt, 1.0)
 
 
-def vmem_working_set(cfg: AggConfig, bytes_feat: int | None = None) -> int:
-    """VMEM bytes per grid step (double-buffered window) — Eq. 4 analogue.
+def _round_up(x: int, unit: int) -> int:
+    return -(-x // unit) * unit
 
-    For the one-hot variants the 2x window factor models the pipelined
-    BlockSpec load; for ``direct`` it is the literal two-slot DMA scratch
-    the kernel allocates.  ``direct`` has no gather matrix at all — its
-    transient is the (gpt*gs, dt) gathered-rows block.
+
+def vmem_working_set(cfg: AggConfig, bytes_feat: int | None = None, *,
+                     dim: int | None = None) -> int:
+    """VMEM bytes per grid step of the folded kernel — Eq. 4 analogue.
+
+    Counts what Mosaic allocates: every VMEM array is laid out in (8, 128)
+    tiles, so minor dims round up to 128 lanes and second-minor dims to 8
+    sublanes.  Terms: the double-buffered (src_win, dt) feature window;
+    four (gpt*gs, src_win) 32-bit arrays the W-build materializes — the
+    column iota, the compare mask, the per-slot gather matrix, and its
+    product with the group one-hot's operand (the edge-grad kernel's
+    per-slot score matrix has the same shape); the (gpt, src_win) f32 W;
+    the double-buffered (8, gpt*gs) metadata blocks and (8, gpt)
+    local_node block; the (ont, dt) f32 output block and its aliased
+    prior-sum input, both double-buffered.  With ``dim`` the window is
+    priced at the tile `kernels.ops.dim_tile` runs for a ``dim``-wide
+    operand.
 
     ``bytes_feat`` defaults to the config's own dtype policy
     (``cfg.feat_dtype``); pass it explicitly only to price a hypothetical."""
     if bytes_feat is None:
         bytes_feat = cfg.bytes_feat
-    window = 2 * cfg.src_win * cfg.dt * bytes_feat          # double-buffered
-    if cfg.variant == "direct":
-        gather_mat = cfg.gpt * cfg.gs * cfg.dt * 4          # gathered rows, f32
-    else:
-        gather_mat = cfg.gpt * cfg.src_win * 4
-        if cfg.variant == "slot_onehot":
-            gather_mat *= cfg.gs
-    meta = cfg.gpt * cfg.gs * (4 + 4) + cfg.gpt * 4
-    out_block = cfg.ont * cfg.dt * 4
-    return window + gather_mat + meta + out_block
+    dt = _round_up(cfg.dt if dim is None
+                   else dim_tile(cfg.dt, dim, cfg.feat_dtype), LANES)
+    win = _round_up(cfg.src_win, LANES)
+    slots = cfg.gpt * cfg.gs
+    window = 2 * cfg.src_win * dt * bytes_feat              # double-buffered
+    slot_mat = 4 * _round_up(slots, SUBLANES) * win * 4
+    gather_mat = _round_up(cfg.gpt, SUBLANES) * win * 4
+    meta = 2 * SUBLANES * (2 * _round_up(slots, LANES)
+                           + _round_up(cfg.gpt, LANES)) * 4
+    out_block = 2 * 2 * cfg.ont * dt * 4
+    return window + slot_mat + gather_mat + meta + out_block
 
 
-def config_infeasibility(cfg: AggConfig, *, hw: TPUSpec = TPU_V5E,
-                         bytes_feat: int | None = None) -> str | None:
+def config_infeasibility(cfg: AggConfig, *, hw: TPUSpec | None = None,
+                         bytes_feat: int | None = None,
+                         dim: int | None = None) -> str | None:
     """Eq. 3 + Eq. 4 feasibility, TPU-re-derived: None when the config is
     feasible, else a human-readable reason naming the violated constraint
-    (the tuner surfaces it when rejection sampling exhausts the space)."""
+    (the tuner surfaces it when rejection sampling exhausts the space).
+
+    ``hw`` defaults to the local chip's spec (`repro.hw.device_spec`).
+    ``dim`` is the feature width the kernel sees; given it, the chip's
+    block rule applies: a ``dt`` narrower than the padded width must be a
+    multiple of 128 lanes."""
+    if hw is None:
+        hw = device_spec()
     if bytes_feat is None:
         bytes_feat = cfg.bytes_feat
     # Eq. 4: VMEM capacity (use half of VMEM as the safety envelope).
-    ws = vmem_working_set(cfg, bytes_feat)
+    ws = vmem_working_set(cfg, bytes_feat, dim=dim)
     if ws > hw.vmem_bytes * 0.5:
         return (f"Eq. 4 VMEM working set {ws}B > half of "
                 f"{hw.name} VMEM ({hw.vmem_bytes / 2:.0f}B) at "
@@ -181,34 +204,31 @@ def config_infeasibility(cfg: AggConfig, *, hw: TPUSpec = TPU_V5E,
     if cfg.dt % align != 0:
         return (f"dt={cfg.dt} not a multiple of the {cfg.feat_dtype} "
                 f"alignment unit {align}")
+    if dim is not None:
+        d_pad = _round_up(dim, align)
+        if cfg.dt < d_pad and cfg.dt % LANES:
+            return (f"dt={cfg.dt} is narrower than the padded D={d_pad} "
+                    f"but not a multiple of {LANES} lanes")
     if cfg.src_win % 8 != 0:
         return f"src_win={cfg.src_win} not a multiple of 8"
     return None
 
 
-def config_is_feasible(cfg: AggConfig, *, hw: TPUSpec = TPU_V5E,
-                       bytes_feat: int | None = None) -> bool:
-    return config_infeasibility(cfg, hw=hw, bytes_feat=bytes_feat) is None
-
-
-# fixed per-row cost of issuing one dynamic-slice gather (address compute +
-# copy setup) in the ``direct`` variant, in VPU-op units — small next to the
-# 2*dt multiply-accumulate for realistic dt, but it keeps tiny-dt configs
-# from looking free
-_DIRECT_ROW_ISSUE_OPS = 32
+def config_is_feasible(cfg: AggConfig, *, hw: TPUSpec | None = None,
+                       bytes_feat: int | None = None,
+                       dim: int | None = None) -> bool:
+    return config_infeasibility(cfg, hw=hw, bytes_feat=bytes_feat,
+                                dim=dim) is None
 
 
 @dataclasses.dataclass
 class KernelModel:
-    """Three-term latency model of the group_aggregate schedule.
+    """Three-term latency model of the group_aggregate schedule: the
+    gather matmul on the MXU against the full src_win window plus the
+    W-build iota-compares on the VPU (the term that scales with src_win),
+    window DMA bytes, and per-grid-step overhead."""
 
-    The gather term is per-variant (see `terms`): the one-hot paths pay an
-    MXU matmul against the full src_win window, ``direct`` pays a VPU
-    row-gather that never touches src_win — which is why direct wins on
-    wide-window memory-bound schedules and the measured selector
-    (`core.tuner.select_variant_measured`) exists to confirm it."""
-
-    hw: TPUSpec = TPU_V5E
+    hw: TPUSpec = dataclasses.field(default_factory=device_spec)
 
     def terms(self, props: GraphProps, dim: int, cfg: AggConfig,
               *, tiles: float | None = None,
@@ -216,30 +236,19 @@ class KernelModel:
         if bytes_feat is None:
             bytes_feat = cfg.bytes_feat
         T = float(tiles if tiles is not None else predict_tiles(props, cfg))
-        J = max(math.ceil(dim / cfg.dt), 1)
+        dt = dim_tile(cfg.dt, dim, cfg.feat_dtype)
+        J = max(math.ceil(dim / dt), 1)
         steps = T * J
-        # per-variant gather cost:
-        #   slot_onehot/folded — gather matmul on the MXU plus the W-build
-        #     iota-compares on the VPU (the term that scales with src_win);
-        #   direct — no gather matmul and no W build: gpt*gs dynamic-slice
-        #     row copies plus weight/reduce, all VPU, scaling with dt only.
-        if cfg.variant == "direct":
-            mxu_flops = steps * 2 * cfg.ont * cfg.gpt * cfg.dt  # scatter only
-            vpu_ops = steps * cfg.gs * cfg.gpt * (
-                2 * cfg.dt + _DIRECT_ROW_ISSUE_OPS)
-        else:
-            gather_rows = cfg.gpt * (cfg.gs if cfg.variant == "slot_onehot"
-                                     else 1)
-            mxu_flops = steps * 2 * (gather_rows * cfg.src_win * cfg.dt
-                                     + cfg.ont * cfg.gpt * cfg.dt)
-            vpu_ops = steps * cfg.gs * cfg.gpt * cfg.src_win  # W build
+        mxu_flops = steps * 2 * (cfg.gpt * cfg.src_win * dt
+                                 + cfg.ont * cfg.gpt * dt)
+        vpu_ops = steps * cfg.gs * cfg.gpt * cfg.src_win  # W build
         peak = self.hw.peak_flops_bf16 if bytes_feat == 2 else self.hw.peak_flops_f32
         t_compute = mxu_flops / peak + vpu_ops / (self.hw.peak_flops_f32 / 2)
         # memory: feature-window DMAs (dominant), metadata, output flushes
         n_blocks = max(props.num_nodes / cfg.ont, 1.0)
-        bytes_windows = steps * cfg.src_win * cfg.dt * bytes_feat
+        bytes_windows = steps * cfg.src_win * dt * bytes_feat
         bytes_meta = steps * (cfg.gpt * cfg.gs * 8 + cfg.gpt * 4)
-        bytes_out = n_blocks * J * cfg.ont * cfg.dt * 4 * 2  # zero + flush
+        bytes_out = n_blocks * J * cfg.ont * dt * 4 * 2  # load + flush
         t_memory = (bytes_windows + bytes_meta + bytes_out) / self.hw.hbm_bw
         t_overhead = steps * self.hw.grid_step_overhead_s
         return {

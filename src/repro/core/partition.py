@@ -96,23 +96,6 @@ class GroupPartition:
     def padded_out_rows(self) -> int:
         return int(-(-self.num_nodes // self.ont) * self.ont)
 
-    def block_visited(self, num_blocks: Optional[int] = None) -> np.ndarray:
-        """(num_blocks,) bool — output node blocks named by >= 1 tile.
-
-        The kernel zeroes an output block only on its first VISIT, so
-        blocks no tile names (bipartite sampled blocks' edge-less rows)
-        must be masked to zero by the caller.  This mask is schedule-static
-        — `DeviceSchedule` uploads it once instead of rebuilding it from
-        ``tile_node_block`` inside every jitted call.  ``num_blocks``
-        overrides the length for callers that widen the output geometry
-        (the sharded sampled trainer's node-bucket uniformization).
-        """
-        if num_blocks is None:
-            num_blocks = self.padded_out_rows // self.ont
-        v = np.zeros(num_blocks, dtype=bool)
-        v[self.tile_node_block] = True
-        return v
-
 
 def _sort_rows_by_neighbor(g: CSRGraph, edge_vals: Optional[np.ndarray]):
     """Sort each CSR row's neighbors ascending, permuting edge values along."""

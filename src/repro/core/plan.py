@@ -245,8 +245,9 @@ class Plan:
             self._sched_bwd_cache = cached
         return cached[1]
 
-    def executor(self, backend: str = "pallas_interpret"):
-        """Single-device `PlanExecutor` bound to this plan."""
+    def executor(self, backend: str | None = None):
+        """Single-device `PlanExecutor` bound to this plan (backend None
+        resolves by platform — `repro.kernels.ops.resolve_backend`)."""
         from repro.core.aggregate import PlanExecutor
         return PlanExecutor(self, backend=backend)
 
@@ -290,7 +291,7 @@ class Plan:
 
     @staticmethod
     def executor_from_args(statics: tuple, args: tuple, *,
-                           backend: str = "pallas_interpret"):
+                           backend: str | None = None):
         """Rebuild a working `PlanExecutor` from the (statics, args) pair
         INSIDE a traced function — arrays may be tracers.  This is the one
         convention shared by serving's shared forwards, the sampled

@@ -28,17 +28,17 @@ from repro.core.model import (AggConfig, KernelModel, config_infeasibility,
                               paper_eq2_latency)
 from repro.core.partition import partition_graph, partition_stats
 from repro.graphs.csr import CSRGraph, random_community_graph
-from repro.hw import TPU_V5E, TPUSpec
+from repro.hw import TPUSpec, device_spec
 
 __all__ = ["TunerResult", "evolve", "tune", "community_profile",
            "SEARCH_SPACE", "select_variant_measured", "measured_tune",
            "MEASURED_VARIANTS"]
 
-# gather paths the measured stage races by default: the folded one-hot
-# matmul (current default) vs the direct dynamic-slice gather.  slot_onehot
-# is strictly dominated by folded in the model and exists for paper
-# fidelity, so it is not raced unless a caller asks.
-MEASURED_VARIANTS = ("folded", "direct")
+# gather paths the measured stage races by default: every variant the
+# kernel has (`kernels.group_aggregate.VARIANTS`; only ``folded`` compiles
+# for the chip today, so the race is a single entry until a second variant
+# does)
+MEASURED_VARIANTS = ("folded",)
 
 SEARCH_SPACE = {
     "gs": [4, 8, 16, 32, 64],
@@ -208,7 +208,7 @@ def community_profile(community_sizes: Sequence[int], dim: int, *,
 def tune(g: CSRGraph, dim: int, *, props: GraphProps | None = None,
          mode: str = "model", iters: int = 12, pop: int = 16,
          seed: int = 0, feat_dtype: str = "float32",
-         hw: TPUSpec = TPU_V5E) -> TunerResult:
+         hw: TPUSpec | None = None) -> TunerResult:
     """Pick (gs, gpt, dt, src_win) for a given graph and embedding dim.
 
     mode="model":   white-box model over predicted tile counts (fast; §7.1).
@@ -220,8 +220,10 @@ def tune(g: CSRGraph, dim: int, *, props: GraphProps | None = None,
     (a bf16 feature window moves half the DMA bytes, so wider ``src_win``/
     ``dt`` become profitable), and feasibility uses the dtype-tightened
     Eq. 4 + alignment constraints — the returned ``best`` therefore passes
-    ``config_is_feasible`` under its own dtype.
+    ``config_is_feasible`` under its own dtype at width ``dim``.  ``hw``
+    defaults to the local chip's spec (`repro.hw.device_spec`).
     """
+    hw = hw or device_spec()
     pr = props or extract_graph_props(g, detect_communities=False)
     km = KernelModel(hw=hw)
     base = AggConfig(feat_dtype=feat_dtype)
@@ -236,7 +238,8 @@ def tune(g: CSRGraph, dim: int, *, props: GraphProps | None = None,
     else:
         raise ValueError(mode)
     return evolve(score, pop=pop, iters=iters, seed=seed, base=base,
-                  infeasibility_fn=lambda c: config_infeasibility(c, hw=hw))
+                  infeasibility_fn=lambda c: config_infeasibility(
+                      c, hw=hw, dim=dim))
 
 
 # ---------------------------------------------------------------------------
@@ -254,7 +257,7 @@ def plan_facing_dim(plan, default: int = 64) -> int:
     return arch.hidden_dim if plan.reduce_dim_first else arch.in_dim
 
 
-def select_variant_measured(plan, *, backend: str = "pallas_interpret",
+def select_variant_measured(plan, *, backend: str | None = None,
                             variants: Sequence[str] = MEASURED_VARIANTS,
                             dim: int | None = None, iters: int = 3,
                             warmup: int | None = 2, seed: int = 0,
@@ -321,9 +324,9 @@ def select_variant_measured(plan, *, backend: str = "pallas_interpret",
 
 def measured_tune(g: CSRGraph, dim: int, *, top_k: int = 2,
                   variants: Sequence[str] = MEASURED_VARIANTS,
-                  backend: str = "pallas_interpret", mode: str = "model",
+                  backend: str | None = None, mode: str = "model",
                   iters: int = 12, pop: int = 16, seed: int = 0,
-                  feat_dtype: str = "float32", hw: TPUSpec = TPU_V5E,
+                  feat_dtype: str = "float32", hw: TPUSpec | None = None,
                   measure_iters: int = 3, warmup: int | None = 2,
                   props: GraphProps | None = None) -> TunerResult:
     """Analytical search, then measure the top-k candidates per variant.

@@ -32,10 +32,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.plan import Plan
 from repro.core.shard import PlanShards
-from repro.kernels.ops import _SCHED_ARRAY_FIELDS, N_TILE_FIELDS
+from repro.kernels.ops import (_SCHED_ARRAY_FIELDS, N_TILE_FIELDS,
+                               resolve_backend)
 from repro.obs import MetricsRegistry
 
 __all__ = ["SHARD_AXIS", "ShardedExecutor", "local_step_value_and_grad",
@@ -44,9 +45,8 @@ __all__ = ["SHARD_AXIS", "ShardedExecutor", "local_step_value_and_grad",
 
 SHARD_AXIS = "shard"
 
-# the tile-tensor members of the jit-argument layout, incl. the
-# schedule-static block_visited mask (the (E,)-sized edge members are
-# stacked separately — see _stack_dir)
+# the tile-tensor members of the jit-argument layout (the (E,)-sized edge
+# members are stacked separately — see _stack_dir)
 _TILE_FIELDS = _SCHED_ARRAY_FIELDS[:N_TILE_FIELDS]
 
 
@@ -162,16 +162,16 @@ class ShardedExecutor:
     Example
     -------
     >>> plan = plan_for(g, arch="gcn", edge_vals=vals, with_backward=True)
-    >>> ex = ShardedExecutor(plan.shards(4), backend="xla")
+    >>> ex = ShardedExecutor(plan.shards(4))   # backend by platform
     >>> out = ex(feat)                        # == PlanExecutor(plan)(feat)
     """
 
-    def __init__(self, shards: PlanShards, *, backend: str = "xla",
+    def __init__(self, shards: PlanShards, *, backend: Optional[str] = None,
                  mesh: Optional[Mesh] = None,
                  registry: Optional[MetricsRegistry] = None):
         self.shards = shards
         self.spec = shards.spec
-        self.backend = backend
+        self.backend = resolve_backend(backend)
         self.mesh = mesh if mesh is not None else shard_mesh(
             shards.spec.num_shards)
         self.statics = shards.plans[0].jit_statics()
@@ -372,11 +372,17 @@ def make_sharded_train_step(cfg, shards: PlanShards, opt, *,
 
     step_c = jax.jit(step) if jit else step
 
-    def step_fn(state, batch):
+    def inputs(state, batch):
         mask = batch.get("mask")
         if mask is None:
             mask = jnp.ones(n, jnp.float32)
-        return step_c(state, batch["feat"], batch["labels"], mask,
-                      args_f, args_b)
+        return state, batch["feat"], batch["labels"], mask, args_f, args_b
 
+    def step_fn(state, batch):
+        return step_c(*inputs(state, batch))
+
+    if jit:
+        # ahead-of-time view of the jitted step (its HLO shows the kernels
+        # and the collectives), like a `jax.jit` function's ``lower``
+        step_fn.lower = lambda state, batch: step_c.lower(*inputs(state, batch))
     return step_fn

@@ -1,15 +1,18 @@
-"""Target-hardware constants (TPU v5e) used by the analytical model,
-the advisor, and the roofline analysis.
+"""Hardware constants used by the analytical model, the advisor, and the
+roofline analysis, keyed by the `device_kind` JAX reports.
 
-The container runs on CPU; these constants describe the TARGET the system is
-designed and analyzed for (assignment: 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI).
+`device_spec()` is the spec of the chip the process runs on.  Off a TPU
+(CPU tests, planning ahead of a chip run) it is the design target, TPU v5e;
+on a TPU whose kind is not in `TPU_SPECS` it is an error, never a default.
+Peaks: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s
+HBM, 16 GB HBM per chip).
 """
 from __future__ import annotations
 
 import dataclasses
 
-__all__ = ["TPUSpec", "TPU_V5E", "MXU_DIM", "SUBLANES", "LANES"]
+__all__ = ["TPUSpec", "TPU_V5E", "TPU_SPECS", "device_spec", "MXU_DIM",
+           "SUBLANES", "LANES"]
 
 MXU_DIM = 128      # systolic array edge; matmul dims should be multiples
 SUBLANES = 8       # vreg sublane count (f32)
@@ -46,3 +49,20 @@ TPU_V5E = TPUSpec(
     ici_links=4,
     grid_step_overhead_s=1.5e-6,
 )
+
+# device_kind (as `jax.devices()[0].device_kind` reports it) -> spec
+TPU_SPECS = {"TPU v5 lite": TPU_V5E}
+
+
+def device_spec() -> TPUSpec:
+    """Spec of the local chip; TPU v5e (the design target) off a TPU."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return TPU_V5E
+    kind = jax.devices()[0].device_kind
+    try:
+        return TPU_SPECS[kind]
+    except KeyError:
+        raise ValueError(f"no hardware spec for TPU kind {kind!r}; "
+                         f"known kinds: {sorted(TPU_SPECS)}") from None

@@ -16,30 +16,32 @@ One `pl.pallas_call` realizes the paper's §5 workload-management stack:
                             paper's coalesced thread→dim mapping (Fig. 6b) is
                             lane order on TPU.
 
-Three gather variants:
-
-  * ``slot_onehot`` — paper-faithful mapping: one one-hot row per neighbor
-    slot ((gpt*gs, src_win) @ (src_win, dt)), i.e. one lane-row per "thread".
-    MXU-native realization of a sparse gather.
-  * ``folded`` — beyond-paper optimization: edge weights and the intra-group
-    sum are folded INTO the gather matrix (W[g, r] = Σ_s ev[g,s]·1[nbr=r]),
-    shrinking the matmul contracting work by gs× ((gpt, src_win) @
-    (src_win, dt)).  Recorded as a §Perf hillclimb step.
-  * ``direct`` — the CUDA-faithful mapping (GNNAdvisor's
-    `partSize`/`dimWorker` indexing): gather each group's `gs` neighbor rows
-    with per-slot dynamic slices (`jnp.take`) out of the VMEM-resident
-    feature window — no one-hot `W` materialization, no gs×src_win
-    iota-compare — then weight and reduce on the VPU.  For this variant the
-    feature operand stays off-chip (`pltpu.ANY`) and the window load is a
-    **double-buffered DMA** (`pltpu.make_async_copy` into a two-slot VMEM
-    scratch): the next grid step's window fetch overlaps the current step's
-    gather/reduce, replacing the BlockSpec-driven window load.
+The gather is ``folded``: edge weights and the intra-group sum are folded
+INTO the gather matrix (W[g, r] = Σ_s ev[g,s]·1[nbr=r]), so each grid step
+runs one (gpt, src_win) @ (src_win, dt) MXU matmul against the VMEM-resident
+feature window.  (The paper-faithful one-hot-row-per-slot mapping and a
+dynamic-slice ``direct`` gather were dropped: neither passes the Mosaic
+compiler — `docs/performance.md`.)
 
 Grid = (D/dt, T) with tiles innermost so output/feature block revisits are
 consecutive.  Scalar-prefetched per-tile metadata (`tile_node_block`,
-`tile_window`) drives the BlockSpec index maps (and, for ``direct``, the
-DMA source slices) — the kernel body never does a dynamic HBM load outside
-the explicit async copies.
+`tile_window`) drives the BlockSpec index maps — the kernel body never does
+a dynamic HBM load.
+
+Block layouts obey the TPU rule that a block's last two dims are multiples
+of (8, 128) or span the whole array, and keep HBM lane-dense: a (T, gpt,
+gs) operand would be padded to 128 lanes in its minor gs dim (32x at
+gs=4).  So per-tile metadata runs as (T, gpt*gs) rows read in (8, gpt*gs)
+blocks — 8 tiles per block — and ``local_node`` as (8, gpt) blocks of
+(T, gpt), T padded to a multiple of 8.  Each grid step takes its tile's
+row out of the block in-kernel (a transposed block gives the slots as a
+column).
+
+The scalar-prefetched (T,) metadata lives in SMEM (1 MiB on v5e), so a
+schedule longer than `MAX_TILES_PER_CALL` tiles runs as several launches
+over consecutive tile ranges.  Each launch accumulates into the previous
+one's output (aliased in place): a node block whose tiles straddle two
+launches starts its second run from the first one's partial sums.
 """
 from __future__ import annotations
 
@@ -53,9 +55,24 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["group_aggregate_pallas", "group_edge_grad_pallas", "VARIANTS"]
 
-Variant = Literal["folded", "slot_onehot", "direct"]
+Variant = Literal["folded"]
 # canonical order: default first (tuner/selector candidate lists index this)
-VARIANTS: tuple = ("folded", "slot_onehot", "direct")
+VARIANTS: tuple = ("folded",)
+
+# tiles per metadata block: the sublane tile (see module docstring)
+_ROWS = 8
+# tiles per pallas_call: two (T,) int32 prefetch arrays must fit SMEM
+# (a multiple of _ROWS, so every launch starts on a metadata block)
+MAX_TILES_PER_CALL = 1 << 16
+# in-kernel f32 matmuls that select or sum exact values (one-hot operands)
+# must not round them to bf16 on the MXU
+_EXACT = jax.lax.Precision.HIGHEST
+
+
+def _launches(T: int) -> list:
+    """(first tile, tile count) of each launch covering T tiles."""
+    return [(s, min(MAX_TILES_PER_CALL, T - s))
+            for s in range(0, T, MAX_TILES_PER_CALL)]
 
 
 def _check_variant(variant: str) -> None:
@@ -64,221 +81,118 @@ def _check_variant(variant: str) -> None:
                          f"expected one of {VARIANTS}")
 
 
+def _rows(x: jax.Array) -> jax.Array:
+    """(T, ...) -> (T rounded up to 8, prod(...)); pad rows are never read."""
+    x = x.reshape(x.shape[0], -1)
+    return jnp.pad(x, ((0, -x.shape[0] % _ROWS), (0, 0)))
+
+
+def _tile_row(ref, t) -> jax.Array:
+    """This tile's (1, n) row out of its (8, n) block."""
+    return ref[pl.ds(jax.lax.rem(t, _ROWS), 1), :]
+
+
+def _tile_column(ref, t) -> jax.Array:
+    """This tile's row of an (8, n) block, as an (n, 1) column."""
+    blk = ref[...].T                                        # (n, 8)
+    lane = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    return jnp.sum(jnp.where(lane == jax.lax.rem(t, _ROWS), blk, 0),
+                   axis=1, keepdims=True)
+
+
+def _group_onehot(gpt: int, gs: int) -> jax.Array:
+    """(gpt, gpt*gs) f32: 1 where slot k belongs to group g (k // gs == g)."""
+    shape = (gpt, gpt * gs)
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    g0 = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * gs
+    return jnp.logical_and(k >= g0, k < g0 + gs).astype(jnp.float32)
+
+
+def _node_scatter(lnode_ref, t, ont: int, gpt: int) -> jax.Array:
+    """(ont, gpt) f32 one-hot: group g lands on row local_node[g] of the
+    tile's node block."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, (ont, gpt), 0)
+    return (rows == _tile_row(lnode_ref, t)).astype(jnp.float32)
+
+
 def _kernel(nb_ref, tw_ref,                       # scalar prefetch (SMEM)
             feat_ref, nbrs_ref, eval_ref, lnode_ref,  # VMEM inputs
+            acc_ref,                               # prior sums (aliased)
             out_ref,                               # VMEM output block
-            *, gs: int, gpt: int, ont: int, src_win: int, variant: Variant):
+            *, gs: int, gpt: int, ont: int, src_win: int):
     t = pl.program_id(1)
 
-    # --- leader-node flush boundary: zero the accumulator on first visit ---
+    # --- leader-node flush boundary: load the block's prior sums (zero
+    # --- unless an earlier launch visited it) on first visit ---
     prev = nb_ref[jnp.maximum(t - 1, 0)]
     first_visit = jnp.logical_or(t == 0, nb_ref[t] != prev)
 
     @pl.when(first_visit)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    def _load():
+        out_ref[...] = acc_ref[...]
 
-    nbrs = nbrs_ref[0]                              # (gpt, gs) int32, global ids
-    evals = eval_ref[0]                             # (gpt, gs) f32, 0 => padding
-    local = nbrs - tw_ref[t] * src_win              # ids within the window
-    feat = feat_ref[...]                            # (src_win, dt)
-    fdtype = feat.dtype
+    local = _tile_column(nbrs_ref, t) - tw_ref[t] * src_win  # (gpt*gs, 1)
+    evals = _tile_column(eval_ref, t).astype(jnp.float32)   # 0 => padding
+    feat = feat_ref[...]                                     # (src_win, dt)
 
-    if variant == "slot_onehot":
-        # One one-hot row per neighbor slot — the direct image of
-        # "one thread per group element" (paper Fig. 4a).
-        flat = local.reshape(gpt * gs, 1)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (gpt * gs, src_win), 1)
-        onehot = (flat == cols).astype(fdtype)
-        onehot = onehot * evals.reshape(gpt * gs, 1).astype(fdtype)
-        gathered = jnp.dot(onehot, feat, preferred_element_type=jnp.float32)
-        per_group = gathered.reshape(gpt, gs, -1).sum(axis=1)       # (gpt, dt)
-    else:
-        # Folded: W[g, r] = sum_s evals[g, s] * 1[local[g, s] == r];
-        # the intra-group reduction happens inside the gather matrix,
-        # cutting matmul FLOPs by gs (beyond-paper §Perf optimization).
-        # One 3-D compare-and-reduce — NOT a Python loop over gs, which
-        # unrolled gs compare+add pairs into the trace and made high-gs
-        # configs compile-time-bound.
-        cols = jax.lax.broadcasted_iota(jnp.int32, (gpt, gs, src_win), 2)
-        hit = (local[:, :, None] == cols).astype(jnp.float32)
-        w = (hit * evals[:, :, None].astype(jnp.float32)).sum(axis=1)
-        per_group = jnp.dot(w.astype(fdtype), feat,
-                            preferred_element_type=jnp.float32)      # (gpt, dt)
+    # W[g, r] = sum_s evals[g, s] * 1[local[g, s] == r]: the intra-group
+    # reduction folds into the gather matrix, so one (gpt, src_win) @
+    # (src_win, dt) matmul gathers and sums every group of the tile
+    cols = jax.lax.broadcasted_iota(jnp.int32, (gpt * gs, src_win), 1)
+    slot_w = jnp.where(local == cols, evals, 0.0)            # (gpt*gs, W)
+    w = jnp.dot(_group_onehot(gpt, gs), slot_w, precision=_EXACT,
+                preferred_element_type=jnp.float32)          # (gpt, W)
+    # (bf16 windows multiply bf16 operands: exact products, f32 sums)
+    per_group = jnp.dot(w.astype(feat.dtype), feat,
+                        precision=_EXACT if feat.dtype == jnp.float32
+                        else None,
+                        preferred_element_type=jnp.float32)  # (gpt, dt)
 
     # --- inter-group scatter within the node block: one-hot matmul on MXU ---
-    rows = jax.lax.broadcasted_iota(jnp.int32, (ont, gpt), 0)
-    ln = lnode_ref[0].reshape(1, gpt)
-    scatter = (rows == ln).astype(jnp.float32)
     # padded groups carry all-zero evals => per_group row is 0: safe to land on row 0
-    out_ref[...] += jnp.dot(scatter, per_group, preferred_element_type=jnp.float32)
-
-
-def _direct_kernel(nb_ref, tw_ref,                    # scalar prefetch (SMEM)
-                   feat_ref,                          # ANY (stays off-chip)
-                   nbrs_ref, eval_ref, lnode_ref,     # VMEM inputs
-                   out_ref,                           # VMEM output block
-                   win_ref, sem_ref,                  # 2-slot scratch + DMA sems
-                   *, gs: int, gpt: int, ont: int, src_win: int, dt: int):
-    """``direct`` gather: dynamic-slice rows out of a double-buffered window.
-
-    The feature window is NOT a BlockSpec operand here — each grid step DMAs
-    its (src_win, dt) window slice into one slot of a two-slot VMEM scratch
-    and prefetches the NEXT tile's window into the other slot before doing
-    any compute, so the fetch for step t+1 overlaps the gather/reduce of
-    step t.  Every DMA started is waited within the same j-row (the t+1
-    prefetch is suppressed on the last tile), so nothing leaks across the
-    dim-tile boundary; the t==0 warm-up re-issues the first fetch for each j.
-    """
-    j = pl.program_id(0)
-    t = pl.program_id(1)
-    num_t = pl.num_programs(1)
-
-    def window_copy(slot, tile):
-        # descriptor is reconstructed identically at start() and wait()
-        return pltpu.make_async_copy(
-            feat_ref.at[pl.ds(tw_ref[tile] * src_win, src_win),
-                        pl.ds(j * dt, dt)],
-            win_ref.at[slot], sem_ref.at[slot])
-
-    slot = jax.lax.rem(t, 2)
-
-    @pl.when(t == 0)
-    def _warmup():
-        window_copy(0, 0).start()
-
-    @pl.when(t + 1 < num_t)
-    def _prefetch_next():
-        window_copy(1 - slot, t + 1).start()
-
-    # --- leader-node flush boundary: zero the accumulator on first visit ---
-    prev = nb_ref[jnp.maximum(t - 1, 0)]
-    first_visit = jnp.logical_or(t == 0, nb_ref[t] != prev)
-
-    @pl.when(first_visit)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    window_copy(slot, t).wait()
-
-    nbrs = nbrs_ref[0]                              # (gpt, gs) int32
-    evals = eval_ref[0]                             # (gpt, gs), 0 => padding
-    local = nbrs - tw_ref[t] * src_win              # in [0, src_win) by constr.
-    feat = win_ref[slot]                            # (src_win, dt)
-
-    # per-slot dynamic-slice gather — padded slots point at the window base
-    # (local == 0) and carry evals == 0, so no masking is needed
-    gathered = jnp.take(feat, local.reshape(gpt * gs), axis=0)
-    weighted = (gathered.astype(jnp.float32)
-                * evals.reshape(gpt * gs, 1).astype(jnp.float32))
-    per_group = weighted.reshape(gpt, gs, dt).sum(axis=1)            # (gpt, dt)
-
-    # --- inter-group scatter within the node block: one-hot matmul on MXU ---
-    rows = jax.lax.broadcasted_iota(jnp.int32, (ont, gpt), 0)
-    ln = lnode_ref[0].reshape(1, gpt)
-    scatter = (rows == ln).astype(jnp.float32)
-    out_ref[...] += jnp.dot(scatter, per_group, preferred_element_type=jnp.float32)
+    out_ref[...] += jnp.dot(_node_scatter(lnode_ref, t, ont, gpt), per_group,
+                            precision=_EXACT,
+                            preferred_element_type=jnp.float32)
 
 
 def _edge_grad_kernel(nb_ref, tw_ref,                 # scalar prefetch (SMEM)
                       grad_ref, feat_ref, nbrs_ref, lnode_ref,  # VMEM inputs
-                      out_ref,                         # (1, gpt, gs) per tile
+                      prior_ref,                       # aliased, never read
+                      out_ref,                         # (8, gpt*gs) block
                       *, gs: int, gpt: int, ont: int, src_win: int):
+    del prior_ref
+    t = pl.program_id(0)
     j = pl.program_id(1)
+    row = pl.ds(jax.lax.rem(t, _ROWS), 1)
 
     # dim tiles are innermost here (grid (T, J)), so every j-step revisits
-    # the same (1, gpt, gs) output block: zero on the first, accumulate after.
+    # the tile's output row: zero on the first, accumulate after.
     @pl.when(j == 0)
     def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        out_ref[row, :] = jnp.zeros((1, gpt * gs), jnp.float32)
 
-    nbrs = nbrs_ref[0]                                  # (gpt, gs) global ids
-    t = pl.program_id(0)
-    local = nbrs - tw_ref[t] * src_win                  # ids within the window
-    feat = feat_ref[...]                                # (src_win, dt)
-    grad = grad_ref[...]                                # (ont, dt)
-    fdtype = feat.dtype
+    local = _tile_column(nbrs_ref, t) - tw_ref[t] * src_win  # (gpt*gs, 1)
+    feat = feat_ref[...].astype(jnp.float32)                 # (src_win, dt)
+    grad = grad_ref[...].astype(jnp.float32)                 # (ont, dt)
 
-    # gather the neighbor features: one one-hot row per slot (the same
-    # MXU-native gather the forward kernel uses).
-    flat = local.reshape(gpt * gs, 1)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (gpt * gs, src_win), 1)
-    onehot = (flat == cols).astype(fdtype)
-    fsel = jnp.dot(onehot, feat, preferred_element_type=jnp.float32)
-
-    # gather each slot's output-row cotangent: one-hot over the node block,
-    # broadcast from per-group local_node to every slot of the group.
-    ln = lnode_ref[0].reshape(gpt, 1)
-    ln_slot = jnp.broadcast_to(ln, (gpt, gs)).reshape(gpt * gs, 1)
-    gcols = jax.lax.broadcasted_iota(jnp.int32, (gpt * gs, ont), 1)
-    gsel = jnp.dot((ln_slot == gcols).astype(jnp.float32),
-                   grad.astype(jnp.float32),
-                   preferred_element_type=jnp.float32)
-
-    # per-slot gather-dot over this dt-slice; padded slots produce garbage
-    # that the caller never reads (only (edge_slot, edge_pos) entries are
-    # gathered back out).
-    out_ref[...] += (fsel * gsel).sum(axis=1).reshape(1, gpt, gs)
-
-
-def _direct_edge_grad_kernel(nb_ref, tw_ref,          # scalar prefetch (SMEM)
-                             grad_ref,                # VMEM (ont, dt) block
-                             feat_ref,                # ANY (stays off-chip)
-                             nbrs_ref, lnode_ref,     # VMEM inputs
-                             out_ref,                 # (1, gpt, gs) per tile
-                             win_ref, sem_ref,        # 2-slot scratch + sems
-                             *, gs: int, gpt: int, ont: int, src_win: int,
-                             dt: int):
-    """``direct`` edge-value cotangent: same dynamic-slice gather as the
-    forward direct kernel, mirrored so `jax.custom_vjp` stays
-    variant-consistent.  Grid is (T, J) with dim tiles innermost; the
-    double buffer cycles on the LINEAR step index so the prefetch crosses
-    tile boundaries (the window for (t+1, j=0) loads while (t, J-1)
-    computes)."""
-    t = pl.program_id(0)
-    j = pl.program_id(1)
-    num_t = pl.num_programs(0)
-    num_j = pl.num_programs(1)
-    step = t * num_j + j
-
-    def window_copy(slot, tile, dim):
-        return pltpu.make_async_copy(
-            feat_ref.at[pl.ds(tw_ref[tile] * src_win, src_win),
-                        pl.ds(dim * dt, dt)],
-            win_ref.at[slot], sem_ref.at[slot])
-
-    slot = jax.lax.rem(step, 2)
-
-    @pl.when(step == 0)
-    def _warmup():
-        window_copy(0, 0, 0).start()
-
-    @pl.when(step + 1 < num_t * num_j)
-    def _prefetch_next():
-        wrap = j + 1 >= num_j
-        nt = jnp.where(wrap, t + 1, t)
-        nj = jnp.where(wrap, 0, j + 1)
-        window_copy(1 - slot, nt, nj).start()
-
-    @pl.when(j == 0)
-    def _zero():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    window_copy(slot, t, j).wait()
-
-    nbrs = nbrs_ref[0]                                  # (gpt, gs) global ids
-    local = nbrs - tw_ref[t] * src_win
-    feat = win_ref[slot]                                # (src_win, dt)
-    grad = grad_ref[...]                                # (ont, dt)
-
-    # dynamic-slice gathers replace both one-hot matmuls: neighbor features
-    # out of the DMA'd window, output-row cotangents out of the grad block
-    fsel = jnp.take(feat, local.reshape(gpt * gs),
-                    axis=0).astype(jnp.float32)          # (gpt*gs, dt)
-    gsel = jnp.take(grad, lnode_ref[0],
-                    axis=0).astype(jnp.float32)          # (gpt, dt)
-    contrib = (fsel.reshape(gpt, gs, dt) * gsel[:, None, :]).sum(axis=2)
-    out_ref[...] += contrib.reshape(1, gpt, gs)
+    # each group's output-row cotangent: scatter^T @ grad -> (gpt, dt)
+    gsel = jax.lax.dot_general(_node_scatter(lnode_ref, t, ont, gpt), grad,
+                               (((0,), (0,)), ((), ())), precision=_EXACT,
+                               preferred_element_type=jnp.float32)
+    # p[g, r] = <grad[v_g], feat[r]> for every window row r, spread to the
+    # group's slots; each slot then keeps its own row: out[k] = p[g_k, local_k]
+    p = jax.lax.dot_general(gsel, feat, (((1,), (1,)), ((), ())),
+                            precision=_EXACT,
+                            preferred_element_type=jnp.float32)      # (gpt, W)
+    p_slot = jax.lax.dot_general(_group_onehot(gpt, gs), p,
+                                 (((0,), (0,)), ((), ())), precision=_EXACT,
+                                 preferred_element_type=jnp.float32)  # (K, W)
+    cols = jax.lax.broadcasted_iota(jnp.int32, p_slot.shape, 1)
+    val = jnp.sum(jnp.where(local == cols, p_slot, 0.0), axis=1,
+                  keepdims=True)                                      # (K, 1)
+    # padded slots produce values the caller never reads (only
+    # (edge_slot, edge_pos) entries are gathered back out)
+    out_ref[row, :] += jnp.broadcast_to(val, (gpt * gs, _ROWS)).T[0:1, :]
 
 
 @functools.partial(
@@ -290,22 +204,19 @@ def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
                            nbrs: jax.Array, local_node: jax.Array,
                            tile_node_block: jax.Array, tile_window: jax.Array,
                            *, gs: int, gpt: int, ont: int, src_win: int,
-                           dt: int, variant: Variant = "slot_onehot",
+                           dt: int, variant: Variant = "folded",
                            interpret: bool = False) -> jax.Array:
     """Per-slot edge-value cotangent: the backward of aggregation w.r.t. the
     (T, gpt, gs) edge-value tensor.
 
     For slot (t, g, s) holding edge (v <- u):  out[t, g, s] = <grad[v], feat[u]>
-    — a per-edge gather-dot realized as two one-hot matmuls against the
-    VMEM-resident feature window and output node block (same schedule
+    — each group's output-row cotangent is selected from the VMEM-resident
+    node block (one-hot matmul), dotted against the whole feature window
+    (one MXU matmul), and each slot keeps its own window row (same schedule
     metadata, same scalar-prefetch-driven BlockSpecs as the forward kernel).
 
     grad_padded: (out_rows, D_pad) output cotangent, out_rows % ont == 0.
     feat_padded: (N_src_pad, D_pad), N_src_pad % src_win == 0, D_pad % dt == 0.
-    variant: "direct" runs the dynamic-slice gather with double-buffered
-    window DMA (mirroring the forward direct kernel); any other variant
-    runs the one-hot-matmul gather (forward ``folded``/``slot_onehot``
-    share it — the per-slot cotangent has no folded form).
     Returns (T, gpt, gs) float32.  Padded slots hold garbage; callers gather
     only real (edge_slot, edge_pos) entries.
     """
@@ -317,43 +228,35 @@ def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
     T = nbrs.shape[0]
     assert nbrs.shape == (T, gpt, gs) and local_node.shape == (T, gpt)
     J = d_pad // dt
-
-    if variant == "direct":
+    kernel = functools.partial(_edge_grad_kernel, gs=gs, gpt=gpt, ont=ont,
+                               src_win=src_win)
+    K = gpt * gs
+    nbrs_rows, ln = _rows(nbrs), _rows(local_node)
+    out = jnp.zeros(nbrs_rows.shape, jnp.float32)
+    for t0, nt in _launches(T):
+        meta = lambda t, j, nb, tw, t0=t0: ((t0 + t) // _ROWS, 0)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(T, J),
-            in_specs=[
-                pl.BlockSpec((ont, dt), lambda t, j, nb, tw: (nb[t], j)),
-                pl.BlockSpec(memory_space=pltpu.ANY),   # feat: manual DMA
-                pl.BlockSpec((1, gpt, gs), lambda t, j, nb, tw: (t, 0, 0)),
-                pl.BlockSpec((1, gpt), lambda t, j, nb, tw: (t, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, gpt, gs), lambda t, j, nb, tw: (t, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, src_win, dt), feat_padded.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        )
-        kernel = functools.partial(_direct_edge_grad_kernel, gs=gs, gpt=gpt,
-                                   ont=ont, src_win=src_win, dt=dt)
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(T, J),
+            grid=(nt, J),
             in_specs=[
                 pl.BlockSpec((ont, dt), lambda t, j, nb, tw: (nb[t], j)),
                 pl.BlockSpec((src_win, dt), lambda t, j, nb, tw: (tw[t], j)),
-                pl.BlockSpec((1, gpt, gs), lambda t, j, nb, tw: (t, 0, 0)),
-                pl.BlockSpec((1, gpt), lambda t, j, nb, tw: (t, 0)),
+                pl.BlockSpec((_ROWS, K), meta),
+                pl.BlockSpec((_ROWS, gpt), meta),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((1, gpt, gs), lambda t, j, nb, tw: (t, 0, 0)),
+            out_specs=pl.BlockSpec((_ROWS, K), meta),
         )
-        kernel = functools.partial(_edge_grad_kernel, gs=gs, gpt=gpt, ont=ont,
-                                   src_win=src_win)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, gpt, gs), jnp.float32),
-        interpret=interpret,
-    )(tile_node_block, tile_window, grad_padded, feat_padded, nbrs, local_node)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(out.shape, jnp.float32),
+            input_output_aliases={6: 0},
+            interpret=interpret,
+            name="group_edge_grad",
+        )(tile_node_block[t0:t0 + nt], tile_window[t0:t0 + nt],
+          grad_padded, feat_padded, nbrs_rows, ln, out)
+    return out[:T].reshape(T, gpt, gs)
 
 
 @functools.partial(
@@ -376,6 +279,8 @@ def group_aggregate_pallas(feat_padded: jax.Array,
     feat_padded : (N_src_pad, D_pad) float — source features;
         N_src_pad % src_win == 0 and D_pad % dt == 0 (caller pads; see
         `repro.kernels.ops.aggregate` for the padding/unpadding wrapper).
+        ``dt`` is a multiple of 128 unless it spans all of D_pad
+        (`repro.kernels.ops.dim_tile`).
     nbrs : (T, gpt, gs) int32 — global source ids per slot.  Padded slots
         point at their tile's window base so local ids stay in range.
     edge_val : (T, gpt, gs) float32 — per-edge weights; exactly 0 marks a
@@ -385,12 +290,11 @@ def group_aggregate_pallas(feat_padded: jax.Array,
         output-block / feature-window indices driving the BlockSpec index
         maps.
     gs, gpt, ont, src_win, dt, out_rows : static ints; out_rows % ont == 0.
-    variant : "folded" | "slot_onehot" | "direct" — see module docstring.
-        ``direct`` keeps the feature operand off-chip and double-buffers the
-        window fetch (`pltpu.make_async_copy` into a 2-slot VMEM scratch).
+    variant : "folded" — see module docstring.
     interpret : run under the Pallas interpreter (CPU).
 
-    Returns (out_rows, D_pad) float32: out[v] = Σ_slots ev · feat[nbr].
+    Returns (out_rows, D_pad) float32: out[v] = Σ_slots ev · feat[nbr]
+    (0 on node blocks no tile names).
 
     This entry point is forward-only; `repro.kernels.ops.aggregate` adds the
     custom VJP (backward = this kernel over the transposed schedule).
@@ -412,40 +316,32 @@ def group_aggregate_pallas(feat_padded: jax.Array,
     assert nbrs.shape == (T, gpt, gs) and edge_val.shape == (T, gpt, gs)
     assert local_node.shape == (T, gpt)
     J = d_pad // dt
-
-    if variant == "direct":
+    kernel = functools.partial(_kernel, gs=gs, gpt=gpt, ont=ont,
+                               src_win=src_win)
+    K = gpt * gs
+    nbrs_rows, ev_rows, ln = _rows(nbrs), _rows(edge_val), _rows(local_node)
+    out = jnp.zeros((out_rows, d_pad), jnp.float32)
+    for t0, nt in _launches(T):
+        meta = lambda j, t, nb, tw, t0=t0: ((t0 + t) // _ROWS, 0)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(J, T),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),   # feat: manual DMA
-                pl.BlockSpec((1, gpt, gs), lambda j, t, nb, tw: (t, 0, 0)),
-                pl.BlockSpec((1, gpt, gs), lambda j, t, nb, tw: (t, 0, 0)),
-                pl.BlockSpec((1, gpt), lambda j, t, nb, tw: (t, 0)),
-            ],
-            out_specs=pl.BlockSpec((ont, dt), lambda j, t, nb, tw: (nb[t], j)),
-            scratch_shapes=[pltpu.VMEM((2, src_win, dt), feat_padded.dtype),
-                            pltpu.SemaphoreType.DMA((2,))],
-        )
-        kernel = functools.partial(_direct_kernel, gs=gs, gpt=gpt, ont=ont,
-                                   src_win=src_win, dt=dt)
-    else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(J, T),
+            grid=(J, nt),
             in_specs=[
                 pl.BlockSpec((src_win, dt), lambda j, t, nb, tw: (tw[t], j)),
-                pl.BlockSpec((1, gpt, gs), lambda j, t, nb, tw: (t, 0, 0)),
-                pl.BlockSpec((1, gpt, gs), lambda j, t, nb, tw: (t, 0, 0)),
-                pl.BlockSpec((1, gpt), lambda j, t, nb, tw: (t, 0)),
+                pl.BlockSpec((_ROWS, K), meta),
+                pl.BlockSpec((_ROWS, K), meta),
+                pl.BlockSpec((_ROWS, gpt), meta),
+                pl.BlockSpec((ont, dt), lambda j, t, nb, tw: (nb[t], j)),
             ],
             out_specs=pl.BlockSpec((ont, dt), lambda j, t, nb, tw: (nb[t], j)),
         )
-        kernel = functools.partial(_kernel, gs=gs, gpt=gpt, ont=ont,
-                                   src_win=src_win, variant=variant)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_rows, d_pad), jnp.float32),
-        interpret=interpret,
-    )(tile_node_block, tile_window, feat_padded, nbrs, edge_val, local_node)
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((out_rows, d_pad), jnp.float32),
+            input_output_aliases={6: 0},
+            interpret=interpret,
+            name="group_aggregate",
+        )(tile_node_block[t0:t0 + nt], tile_window[t0:t0 + nt],
+          feat_padded, nbrs_rows, ev_rows, ln, out)
+    return out

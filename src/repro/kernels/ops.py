@@ -8,16 +8,22 @@ Backend dispatch rules
 ----------------------
 ``backend`` selects how the group schedule is executed:
 
-  * ``"xla"`` — `repro.kernels.ref.group_aggregate_ref`, a pure gather +
-    segment-sum lowering.  Runs anywhere, is the semantic ground truth, and
-    is natively differentiable (every op has an XLA AD rule).  This is the
+  * ``"xla"`` — a pure gather + segment-sum lowering over the schedule's
+    real edges (`repro.kernels.ref.schedule_edges`; without edge members,
+    over every slot with `repro.kernels.ref.group_aggregate_ref`).  Runs
+    anywhere, is the semantic ground truth, and is natively
+    differentiable (every op has an XLA AD rule).  This is the
     reference both the tests and `benchmarks/bench_train.py` compare
     against.
   * ``"pallas"`` — `group_aggregate_pallas` compiled for the local TPU.
     Fastest path; requires a TPU backend.
   * ``"pallas_interpret"`` — the same Pallas kernel executed by the Pallas
-    interpreter (`interpret=True`).  Bit-for-bit the kernel's semantics on
-    CPU; used by CI and anywhere without a TPU.
+    interpreter (`interpret=True`).  The kernel's semantics on CPU; a test
+    device only, never chosen by default.
+
+``backend=None`` (the default everywhere) resolves by platform through
+`resolve_backend`: ``"pallas"`` when JAX's default backend is a TPU,
+``"xla"`` otherwise.
 
 Differentiation: the Pallas backends have no built-in AD rule, so
 ``aggregate`` installs a `jax.custom_vjp` whenever a *backward schedule* is
@@ -65,6 +71,7 @@ import numpy as np
 
 from typing import TYPE_CHECKING
 
+from repro.hw import LANES
 from repro.kernels import ref as _ref
 from repro.kernels.group_aggregate import (group_aggregate_pallas,
                                            group_edge_grad_pallas)
@@ -74,21 +81,31 @@ if TYPE_CHECKING:                      # avoid core<->kernels import cycle
 
 __all__ = ["aggregate", "DeviceSchedule", "dim_tile", "schedule_to_device",
            "SchedView", "sched_arrays", "sched_static", "sched_statics",
-           "sched_statics_for"]
+           "sched_statics_for", "BACKENDS", "resolve_backend"]
 
 Backend = Literal["pallas", "pallas_interpret", "xla"]
+BACKENDS: tuple = ("pallas", "pallas_interpret", "xla")
+
+
+def resolve_backend(backend: Optional[str] = None) -> str:
+    """The aggregation backend to run: ``backend`` when given, else the
+    compiled kernel (``"pallas"``) on a TPU and the XLA reference
+    elsewhere.  ``"pallas_interpret"`` is only ever run when asked for."""
+    if backend is None:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; one of {BACKENDS}")
+    return backend
 
 
 def dim_tile(dt: int, d: int, dtype) -> int:
     """Effective dim-tile width for a D-wide feature operand.
 
     The kernel pads D up to a multiple of the tile and launches D/dt_eff
-    dim steps, so the tile must divide a lane-aligned padded width: round D
-    up to the dtype's lane-tile unit (8 rows for 32-bit, 16 for 16-bit —
-    the vreg second-minor packing) BEFORE clamping ``dt`` to it.  Clamping
-    to the raw D (the old behavior) produced unaligned tiles for any D not
-    a multiple of 8 (e.g. D=100 -> dt_eff=100), which `config_is_feasible`
-    forbids and only the interpreter tolerates.
+    dim steps.  A TPU block's minor dim must be a multiple of 128 lanes or
+    span the whole padded array, so D is first rounded up to the dtype's
+    alignment unit (8 for 32-bit, 16 for 16-bit); when ``dt`` covers that,
+    one tile spans it, otherwise ``dt`` is rounded up to a multiple of 128.
     """
     # policy dtypes take their alignment from the model layer's single
     # source of truth (what config_infeasibility enforces); dtypes outside
@@ -100,22 +117,18 @@ def dim_tile(dt: int, d: int, dtype) -> int:
         unit = feat_dtype_align(dtype.name)
     except ValueError:
         unit = max(8, 8 * 4 // max(dtype.itemsize, 1))
-    dt_aligned = -(-max(dt, 1) // unit) * unit
     d_aligned = -(-max(d, 1) // unit) * unit
-    return min(dt_aligned, max(unit, d_aligned))
+    return min(-(-max(dt, 1) // LANES) * LANES, d_aligned)
 
 
 class DeviceSchedule:
     """Device-resident copy of a GroupPartition's arrays + static config.
 
     Array members (T = tiles): ``nbrs``/``edge_val`` (T, gpt, gs),
-    ``local_node`` (T, gpt), ``tile_node_block``/``tile_window`` (T,),
-    ``block_visited`` (padded_out_rows/ont,) bool — the schedule-static
-    unvisited-output-block mask, precomputed host-side so jitted calls do
-    not rebuild it from ``tile_node_block`` — and ``edge_slot``/
-    ``edge_pos`` (E,).  Static ints mirror the partition's config (`gs`,
-    `gpt`, `ont`, `src_win`) and padding geometry (`padded_src_rows`,
-    `padded_out_rows`).
+    ``local_node`` (T, gpt), ``tile_node_block``/``tile_window`` (T,), and
+    ``edge_slot``/``edge_pos`` (E,).  Static ints mirror the partition's
+    config (`gs`, `gpt`, `ont`, `src_win`) and padding geometry
+    (`padded_src_rows`, `padded_out_rows`).
 
     When a schedule is built from a TRANSPOSED partition to serve as a
     backward schedule, ``edge_perm`` maps its CSR edge order back to the
@@ -130,7 +143,6 @@ class DeviceSchedule:
         self.local_node = jnp.asarray(p.local_node)
         self.tile_node_block = jnp.asarray(p.tile_node_block)
         self.tile_window = jnp.asarray(p.tile_window)
-        self.block_visited = jnp.asarray(p.block_visited())
         self.edge_slot = jnp.asarray(p.edge_slot)
         self.edge_pos = jnp.asarray(p.edge_pos)
         self.edge_perm = None if edge_perm is None else jnp.asarray(edge_perm)
@@ -160,12 +172,11 @@ def schedule_to_device(p: "GroupPartition") -> DeviceSchedule:
 # call sites over using these helpers directly.
 
 _SCHED_ARRAY_FIELDS = ("nbrs", "edge_val", "local_node", "tile_node_block",
-                       "tile_window", "block_visited",
-                       "edge_slot", "edge_pos", "edge_perm")
+                       "tile_window", "edge_slot", "edge_pos", "edge_perm")
 # the first N fields are tile-shaped (uniform after tile padding) — the
 # (E,)-sized edge members sit after this split point so callers can drop
 # or pad them independently (Plan.jit_args, graph_shard stacking)
-N_TILE_FIELDS = 6
+N_TILE_FIELDS = 5
 # num_edges deliberately NOT part of the static signature: raw edge counts
 # are unbucketed and nothing in the compute path reads them — including
 # them would defeat shape bucketing (one retrace per distinct edge count).
@@ -251,18 +262,6 @@ def _scatter_edge_values(sched: DeviceSchedule,
         edge_values.astype(ev_dtype)).reshape(T, gpt, gs)
 
 
-def _visited_rows(sched) -> jax.Array:
-    """(padded_out_rows,) bool row mask from the schedule-static
-    block-visited mask (precomputed by `DeviceSchedule`; duck-typed views
-    without one fall back to rebuilding it from ``tile_node_block``)."""
-    visited = getattr(sched, "block_visited", None)
-    if visited is None:
-        nblk = sched.padded_out_rows // sched.ont
-        visited = jnp.zeros((nblk,), jnp.bool_).at[
-            sched.tile_node_block].set(True)
-    return jnp.repeat(visited, sched.ont)
-
-
 def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
                     dt: int, backend: Backend, variant: str,
                     edge_values: Optional[jax.Array] = None,
@@ -276,6 +275,17 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
     assert n == sched.num_nodes, (n, sched.num_nodes)
     if sched.num_tiles == 0:
         return jnp.zeros((n, d), out_dtype)
+    if backend == "xla" and sched.edge_slot is not None:
+        # E-sized, not slot-sized: padded slots can outnumber edges many
+        # times over, and their (slots, D) gather outgrows HBM first
+        src, dst, flat = _ref.schedule_edges(
+            sched.nbrs, sched.local_node, sched.tile_node_block,
+            sched.edge_slot, sched.edge_pos, sched.ont)
+        vals = (sched.edge_val.reshape(-1)[flat] if edge_values is None
+                else edge_values)
+        out = _ref.segment_aggregate_ref(feat, src, dst, vals,
+                                         sched.padded_out_rows)
+        return out[:n].astype(out_dtype)
     if edge_values is not None:
         ev = _scatter_edge_values(sched, edge_values)
     else:
@@ -297,41 +307,36 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
         dt=dt_eff, out_rows=sched.padded_out_rows,
         variant=variant, interpret=(backend == "pallas_interpret"),
     )
-    # The kernel zeroes an output block on its FIRST VISIT (leader-node
-    # flush), so node blocks no tile names are never written and the
-    # out_shape buffer is undefined there.  Full graphs visit every block;
-    # bipartite sampled blocks (edge-less rows past num_dst) do not — mask
-    # unvisited blocks to true zeros (schedule-static mask, precomputed).
-    return jnp.where(_visited_rows(sched)[:n, None],
-                     out[:n, :d], 0.0).astype(out_dtype)
+    # node blocks no tile names (bipartite sampled blocks: edge-less rows
+    # past num_dst) keep the zeros the kernel's accumulator starts from
+    return out[:n, :d].astype(out_dtype)
 
 
 def _edge_cotangent(g_out: jax.Array, feat: jax.Array,
                     sched: DeviceSchedule, *, dt: int,
                     backend: Backend,
-                    variant: str = "slot_onehot") -> jax.Array:
+                    variant: str = "folded") -> jax.Array:
     """Cotangent w.r.t. per-edge values (original CSR order): the per-edge
-    gather-dot <g_out[dst], feat[src]>, via the forward schedule.  The
-    gather variant mirrors the forward kernel's (``direct`` runs the
-    dynamic-slice + double-buffered-DMA edge-grad kernel)."""
+    gather-dot <g_out[dst], feat[src]>, via the forward schedule."""
     n, d = feat.shape
     T, gpt, gs = sched.edge_val.shape
     if backend == "xla":
-        per_slot = _ref.group_edge_grad_ref(
-            _pad_to(g_out, sched.padded_out_rows, d),
-            _pad_to(feat, sched.padded_src_rows, d),
-            sched.nbrs, sched.local_node, sched.tile_node_block, sched.ont)
-    else:
-        dt_eff = dim_tile(dt, d, feat.dtype)
-        d_pad = -(-d // dt_eff) * dt_eff
-        per_slot = group_edge_grad_pallas(
-            _pad_to(g_out, sched.padded_out_rows, d_pad),
-            _pad_to(feat, sched.padded_src_rows, d_pad),
-            sched.nbrs, sched.local_node,
-            sched.tile_node_block, sched.tile_window,
-            gs=sched.gs, gpt=sched.gpt, ont=sched.ont,
-            src_win=sched.src_win, dt=dt_eff, variant=variant,
-            interpret=(backend == "pallas_interpret"))
+        src, dst, _ = _ref.schedule_edges(
+            sched.nbrs, sched.local_node, sched.tile_node_block,
+            sched.edge_slot, sched.edge_pos, sched.ont)
+        return (_pad_to(g_out, sched.padded_out_rows, d)[dst]
+                .astype(jnp.float32)
+                * feat[src].astype(jnp.float32)).sum(axis=-1)
+    dt_eff = dim_tile(dt, d, feat.dtype)
+    d_pad = -(-d // dt_eff) * dt_eff
+    per_slot = group_edge_grad_pallas(
+        _pad_to(g_out, sched.padded_out_rows, d_pad),
+        _pad_to(feat, sched.padded_src_rows, d_pad),
+        sched.nbrs, sched.local_node,
+        sched.tile_node_block, sched.tile_window,
+        gs=sched.gs, gpt=sched.gpt, ont=sched.ont,
+        src_win=sched.src_win, dt=dt_eff, variant=variant,
+        interpret=(backend == "pallas_interpret"))
     return per_slot.reshape(T * gpt, gs)[sched.edge_slot, sched.edge_pos]
 
 
@@ -386,7 +391,7 @@ _aggregate_diff.defvjp(_aggregate_diff_fwd, _aggregate_diff_bwd)
 
 
 def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
-              dt: int = 128, backend: Backend = "pallas_interpret",
+              dt: int = 128, backend: Optional[Backend] = None,
               variant: str = "folded",
               edge_values: Optional[jax.Array] = None,
               sched_bwd: Optional[DeviceSchedule] = None,
@@ -399,10 +404,12 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
     rules; the bf16 policy passes the feature dtype to keep activations
     16-bit between layers).
 
-    variant: gather path on the Pallas backends — "folded" | "slot_onehot"
-    | "direct" (see `repro.kernels.group_aggregate`); applies to forward,
-    feature backward, and the edge-value cotangent alike so the custom VJP
-    stays variant-consistent.  The XLA reference ignores it (one lowering).
+    backend: None resolves by platform (`resolve_backend`).
+
+    variant: gather path on the Pallas backends — one of
+    `repro.kernels.group_aggregate.VARIANTS`; applies to forward, feature
+    backward, and the edge-value cotangent alike so the custom VJP stays
+    variant-consistent.  The XLA reference ignores it (one lowering).
 
     edge_values: optional (E,) per-edge weights in ORIGINAL CSR edge order,
     overriding the schedule's static values — the dynamic-edge-value path
@@ -414,6 +421,7 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
     ``edge_perm`` when ``edge_values`` is used.  `core.advisor.plan_for`
     builds the pair with ``with_backward=True``.
     """
+    backend = resolve_backend(backend)
     if sched_bwd is None:
         return _aggregate_impl(feat, sched, dt=dt, backend=backend,
                                variant=variant, edge_values=edge_values,

@@ -15,6 +15,7 @@ __all__ = [
     "segment_aggregate_ref",
     "group_aggregate_ref",
     "group_edge_grad_ref",
+    "schedule_edges",
     "edge_centric_aggregate_ref",
     "node_centric_aggregate_ref",
     "selective_scan_ref",
@@ -71,6 +72,21 @@ def group_aggregate_ref(feat: jax.Array, nbrs: jax.Array, edge_val: jax.Array,
     return jax.ops.segment_sum(
         per_group.reshape(T * gpt, -1), rows.reshape(-1), num_segments=out_rows
     )
+
+
+def schedule_edges(nbrs: jax.Array, local_node: jax.Array,
+                   tile_node_block: jax.Array, edge_slot: jax.Array,
+                   edge_pos: jax.Array, ont: int):
+    """``(src, dst, flat_slot)`` of every real edge a group schedule holds,
+    in original CSR edge order (``edge_slot``/``edge_pos`` name each edge's
+    group and position): the E-sized view of the schedule, which skips
+    the padded slots the (T, gpt, gs) arrays carry."""
+    _, gpt, gs = nbrs.shape
+    flat = edge_slot * gs + edge_pos
+    src = nbrs.reshape(-1)[flat]
+    dst = (tile_node_block[edge_slot // gpt] * ont
+           + local_node.reshape(-1)[edge_slot])
+    return src, dst, flat
 
 
 def group_edge_grad_ref(grad_out: jax.Array, feat: jax.Array,

@@ -3,21 +3,13 @@
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required because smoke tests must see 1
 device while the dry-run forces 512 placeholder devices via XLA_FLAGS before
-any jax import.
+any jax import.  Enter a mesh with `jax.set_mesh(mesh)`.
 """
 from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh", "set_mesh"]
-
-
-def set_mesh(mesh: "jax.sharding.Mesh"):
-    """Version-portable mesh context: `jax.set_mesh` on new jax; on older
-    versions `Mesh` is itself the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+__all__ = ["make_production_mesh", "make_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -30,11 +22,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_mesh(shape: tuple, axes: tuple) -> jax.sharding.Mesh:
-    """Arbitrary mesh with the Auto axis-type convention (where the
-    installed jax has typed mesh axes; older versions have a single kind)."""
+    """Arbitrary mesh with every axis of the Auto axis type."""
     shape, axes = tuple(shape), tuple(axes)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))

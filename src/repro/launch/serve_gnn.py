@@ -218,8 +218,10 @@ def main(argv=None) -> int:
     p.add_argument("--layers", type=int, default=2)
     p.add_argument("--hops", type=int, default=None,
                    help="ego radius (default: --layers)")
-    p.add_argument("--backend", default="xla",
-                   choices=["xla", "pallas", "pallas_interpret"])
+    p.add_argument("--backend", default=None,
+                   choices=["xla", "pallas", "pallas_interpret"],
+                   help="aggregation backend (default: pallas on a TPU, "
+                        "xla elsewhere)")
     p.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16"],
                    help="feature/activation dtype policy "
@@ -296,11 +298,13 @@ def main(argv=None) -> int:
     import numpy as np
 
     from repro.graphs.csr import random_power_law
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.gnn import GNNConfig
     from repro.obs import (MetricsRegistry, SpanTracer, registry_to_json,
                            run_context, write_metrics)
     from repro.serving import ServingConfig, ServingEngine
 
+    enable_compile_cache()
     t0 = time.time()
     registry = MetricsRegistry()
     tracer = SpanTracer(registry)
@@ -324,7 +328,7 @@ def main(argv=None) -> int:
                                          else args.max_plans)),
         registry=registry, tracer=tracer)
     print(f"[serve_gnn] graph n={g.num_nodes} e={g.num_edges} arch={args.arch} "
-          f"backend={args.backend} hops={engine.hops} "
+          f"backend={cfg.backend} hops={engine.hops} "
           f"(setup {time.time() - t0:.1f}s)")
 
     trace = build_trace(g.num_nodes, args.requests, zipf=args.zipf,

@@ -7,9 +7,11 @@ config.  On this CPU container you run reduced configs:
         --steps 100 --global-batch 8 --seq-len 64 --ckpt-dir /tmp/ckpt
 
 GNN archs (gcn / gin / gat) train a node classifier on a paper-dataset
-replica through the advisor path; ``--backend pallas``/``pallas_interpret``
-runs forward AND backward through the group-aggregate kernel (the backward
-pass is the transposed schedule — docs/training.md):
+replica through the advisor path.  On a TPU the default backend is the
+compiled group-aggregate kernel, forward AND backward (the backward pass is
+the transposed schedule — docs/training.md); elsewhere it is the XLA
+reference, and ``--backend pallas_interpret`` runs the kernel body under
+the Pallas interpreter:
 
     PYTHONPATH=src python -m repro.launch.train --arch gcn --dataset cora \
         --steps 50 --backend pallas_interpret
@@ -41,6 +43,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import os
+import tempfile
 import time
 
 GNN_ARCHS = ("gcn", "gin", "gat")
@@ -174,9 +177,10 @@ def _main_gnn_sampled(args) -> int:
               f"{eb} edges/batch")
     params = init_gnn_params(cfg, jax.random.PRNGKey(args.seed))
     ckpt_dir = args.ckpt_dir or os.path.join(
-        "/tmp", f"repro_train_sampled_{args.arch}_{args.dataset}"
-                f"_s{args.scale}_h{args.hidden_dim}_b{args.batch_nodes}"
-                f"_p{args.shards}_{args.backend}_{args.seed}")
+        tempfile.gettempdir(),
+        f"repro_train_sampled_{args.arch}_{args.dataset}"
+        f"_s{args.scale}_h{args.hidden_dim}_b{args.batch_nodes}"
+        f"_p{args.shards}_{cfg.backend}_{args.seed}")
     trainer = Trainer(
         TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=args.ckpt_every,
                       log_every=10),
@@ -195,7 +199,7 @@ def _main_gnn_sampled(args) -> int:
     cache = st["cache"]
     deltas = (f"graph_epoch={st['graph_epoch']} "
               if st.get("graph_swaps") else "")
-    print(f"[train] arch={args.arch} backend={args.backend} "
+    print(f"[train] arch={args.arch} backend={cfg.backend} "
           f"dtype={args.dtype} sampled "
           f"fanouts={fanouts} batch={args.batch_nodes} "
           f"shards={args.shards} steps={len(hist)} "
@@ -262,8 +266,9 @@ def _main_gnn(args) -> int:
     # unlike the LM branch, arch+seed does not determine parameter shapes —
     # key the auto-restore dir on everything that does
     ckpt_dir = args.ckpt_dir or os.path.join(
-        "/tmp", f"repro_train_{args.arch}_{args.dataset}_h{args.hidden_dim}"
-                f"_p{args.shards}_{args.backend}_{args.seed}")
+        tempfile.gettempdir(),
+        f"repro_train_{args.arch}_{args.dataset}_h{args.hidden_dim}"
+        f"_p{args.shards}_{cfg.backend}_{args.seed}")
     trainer = Trainer(
         TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=args.ckpt_every,
                       log_every=10),
@@ -276,7 +281,7 @@ def _main_gnn(args) -> int:
     hist = trainer.metrics_history
     losses = (f"first_loss={hist[0]['loss']:.4f} "
               f"last_loss={hist[-1]['loss']:.4f} " if hist else "")
-    print(f"[train] arch={args.arch} backend={args.backend} "
+    print(f"[train] arch={args.arch} backend={cfg.backend} "
           f"dtype={args.dtype} "
           f"dataset={args.dataset} shards={args.shards} steps={len(hist)} "
           f"{losses}avg_step={trainer.avg_step_time()*1e3:.1f}ms "
@@ -289,9 +294,10 @@ def _main_gnn(args) -> int:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
-    p.add_argument("--backend", default="xla",
+    p.add_argument("--backend", default=None,
                    choices=["xla", "pallas", "pallas_interpret"],
-                   help="aggregation backend (GNN archs only)")
+                   help="aggregation backend (GNN archs only; default: "
+                        "pallas on a TPU, xla elsewhere)")
     p.add_argument("--dtype", default="float32",
                    choices=["float32", "bfloat16"],
                    help="feature/activation dtype policy (GNN archs; "
@@ -358,6 +364,8 @@ def main(argv=None) -> int:
         p.error("--shards must be >= 1")
     if args.shards > 1 and args.arch not in ("gcn", "gin"):
         p.error("--shards supports gcn/gin only")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.arch in GNN_ARCHS:
         return _main_gnn_sampled(args) if args.sampled else _main_gnn(args)
 
@@ -399,7 +407,7 @@ def main(argv=None) -> int:
         return (params, opt_state), metrics
 
     ckpt_dir = args.ckpt_dir or os.path.join(
-        "/tmp", f"repro_train_{args.arch}_{args.seed}")
+        tempfile.gettempdir(), f"repro_train_{args.arch}_{args.seed}")
     trainer = Trainer(
         TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=args.ckpt_every,
                       log_every=10),

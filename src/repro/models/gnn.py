@@ -31,6 +31,7 @@ from repro.core.advisor import advise
 from repro.core.aggregate import PlanExecutor
 from repro.core.plan import Plan
 from repro.graphs.csr import CSRGraph
+from repro.kernels.ops import resolve_backend
 
 Pytree = Any
 
@@ -49,12 +50,17 @@ class GNNConfig:
     num_layers: int = 2
     gin_eps: float = 0.0
     gat_slope: float = 0.2      # LeakyReLU slope for attention logits
-    backend: str = "xla"        # "xla" | "pallas" | "pallas_interpret"
+    # "xla" | "pallas" | "pallas_interpret"; None resolves by platform at
+    # construction ("pallas" on a TPU, "xla" elsewhere)
+    backend: Optional[str] = None
     # feature/activation dtype policy: "float32" | "bfloat16".  Parameters
     # and loss stay float32 (mixed precision with an f32 master copy);
     # matmuls and the aggregation kernel run on feat_dtype operands with
     # f32 accumulation, and logits are cast back to f32 before the loss.
     feat_dtype: str = "float32"
+
+    def __post_init__(self):
+        object.__setattr__(self, "backend", resolve_backend(self.backend))
 
     @property
     def compute_dtype(self):

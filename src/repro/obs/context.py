@@ -33,24 +33,21 @@ def _git_sha() -> str:
 
 
 def run_context() -> dict:
-    """Provenance dict (cached); jax fields degrade to "unavailable" so
-    the stamp never takes a run down with it."""
+    """Provenance dict (cached).  A failed device query raises: an
+    artifact must never be stamped with a device it did not run on."""
     global _context
     if _context is None:
-        ctx = {
+        import jax
+
+        _context = {
             "git_sha": _git_sha(),
             "timestamp": datetime.now(timezone.utc).isoformat(
                 timespec="seconds"),
             "python": platform.python_version(),
             "platform": platform.platform(),
+            "jax": jax.__version__,
+            "jax_backend": jax.default_backend(),
+            "device": jax.devices()[0].device_kind,
+            "num_devices": jax.device_count(),
         }
-        try:
-            import jax
-            ctx["jax"] = jax.__version__
-            ctx["jax_backend"] = jax.default_backend()
-            ctx["device"] = jax.devices()[0].device_kind
-            ctx["num_devices"] = jax.device_count()
-        except Exception:
-            ctx["jax"] = "unavailable"
-        _context = ctx
     return dict(_context)

@@ -235,7 +235,7 @@ class ProfileReport:
         return [s.to_row() for s in self.schedules]
 
 
-def profile_plan(plan, feat=None, *, backend: str = "xla",
+def profile_plan(plan, feat=None, *, backend: Optional[str] = None,
                  dim: Optional[int] = None, iters: int = 5,
                  warmup: Optional[int] = None, registry=None,
                  label: str = "", shards: Optional[int] = None,
@@ -255,7 +255,8 @@ def profile_plan(plan, feat=None, *, backend: str = "xla",
     plan : repro.core.plan.Plan (advisor/`plan_for` output).
     feat : optional (N, D) features in the plan's node order; generated
         deterministically (``seed``) at ``dim`` columns when omitted.
-    backend : kernel backend ("xla" | "pallas" | "pallas_interpret").
+    backend : kernel backend ("xla" | "pallas" | "pallas_interpret");
+        None resolves by platform.
     registry : optional MetricsRegistry — when given, every schedule lands
         ``kernel_model_residual{schedule=...}`` /
         ``profile_achieved_bytes_per_s{schedule=...}`` gauges and a
@@ -275,7 +276,9 @@ def profile_plan(plan, feat=None, *, backend: str = "xla",
 
     from repro.core.extractor import extract_graph_props
     from repro.core.model import KernelModel
+    from repro.kernels.ops import resolve_backend
 
+    backend = resolve_backend(backend)
     g = plan.graph
     if feat is None:
         d = dim if dim is not None else 64

@@ -589,7 +589,6 @@ class ShardedSampledTrainStep:
             parts = [pad_partition_tiles(p.partition, t_f) for p in plans]
             st_f = sched_statics_for(gs=c.gs, gpt=c.gpt, ont=c.ont,
                                      src_win=c.src_win, num_nodes=n_t)
-            nblk = sched_static(st_f, "padded_out_rows") // c.ont
             st_b = None
             arrs_b = None
             if plans[0].partition_bwd is not None:
@@ -597,9 +596,9 @@ class ShardedSampledTrainStep:
                 parts_b = [pad_partition_tiles(p.partition_bwd, t_b)
                            for p in plans]
                 st_b = st_f
-                arrs_b = self._stack_parts(parts_b, jnp, nblk)
+                arrs_b = self._stack_parts(parts_b, jnp)
             statics.append((st_f, st_b, c.dt, c.variant, c.feat_dtype))
-            blocks.append((self._stack_parts(parts, jnp, nblk), arrs_b))
+            blocks.append((self._stack_parts(parts, jnp), arrs_b))
             layer_shapes.append((n_t, t_f,
                                  None if st_b is None else arrs_b[0].shape))
         n0 = sched_static(statics[0][0], "num_nodes")
@@ -620,18 +619,12 @@ class ShardedSampledTrainStep:
                      jnp.asarray(mask), tuple(blocks)), statics
 
     @staticmethod
-    def _stack_parts(parts, jnp, num_blocks: int) -> tuple:
-        # sched_arrays layout; edge members dropped (see SampledTrainStep).
-        # block_visited is rebuilt at the UNIFORMIZED geometry: the step's
-        # widest node bucket decides the output-row count, so every
-        # partition's mask is widened to `num_blocks` (its own blocks keep
-        # their visited bits; the widening rows are unvisited -> masked).
+    def _stack_parts(parts, jnp) -> tuple:
+        # sched_arrays layout; edge members dropped (see SampledTrainStep)
         from repro.kernels.ops import _SCHED_ARRAY_FIELDS, N_TILE_FIELDS
-        assert _SCHED_ARRAY_FIELDS[N_TILE_FIELDS - 1] == "block_visited"
         return tuple(
             jnp.stack([np.asarray(getattr(p, f)) for p in parts])
-            for f in _SCHED_ARRAY_FIELDS[:N_TILE_FIELDS - 1]) + (
-            jnp.stack([p.block_visited(num_blocks) for p in parts]),
+            for f in _SCHED_ARRAY_FIELDS[:N_TILE_FIELDS]
         ) + (None,) * (len(_SCHED_ARRAY_FIELDS) - N_TILE_FIELDS)
 
     # -------------- per-bucket executable --------------
@@ -640,7 +633,7 @@ class ShardedSampledTrainStep:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.core.plan import Plan
         from repro.distributed.graph_shard import (SHARD_AXIS,
                                                    local_step_value_and_grad,

@@ -36,6 +36,7 @@ from repro.core.model import AggConfig
 from repro.core.partition import pad_partition_tiles
 from repro.core.plan import Plan
 from repro.graphs.csr import CSRGraph
+from repro.kernels.ops import resolve_backend
 from repro.obs import MetricsRegistry
 
 __all__ = [
@@ -151,7 +152,8 @@ class PlanCache:
     land there (a private registry is kept when none is given).
     """
 
-    def __init__(self, *, backend: str = "xla", tune_mode: str = "model",
+    def __init__(self, *, backend: Optional[str] = None,
+                 tune_mode: str = "model",
                  tune_iters: int = 8, max_entries: int = 64,
                  max_plans: Optional[int] = _UNSET,
                  max_configs: Optional[int] = None,
@@ -163,7 +165,7 @@ class PlanCache:
                  variant_measure_iters: int = 3,
                  fingerprint_fn: Callable = graph_fingerprint,
                  registry: Optional[MetricsRegistry] = None):
-        self.backend = backend
+        self.backend = resolve_backend(backend)
         # fingerprint_fn: (CSRGraph, arch_key) -> hashable — the config/
         # variant memo key.  Default is the content-aware
         # `graph_fingerprint`; the sampled loader opts into the coarser

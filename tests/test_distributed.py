@@ -26,14 +26,14 @@ def test_moe_sharded_matches_local():
         import numpy as np, jax, jax.numpy as jnp
         from repro.nn.layers import Initializer
         from repro.nn.moe import MoEParams, moe_init, moe_apply
-        from repro.launch.mesh import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         mp = MoEParams(n_experts=8, topk=2, d_ff=64, capacity_factor=8.0)
         pm, _ = moe_init(Initializer(jax.random.PRNGKey(5),
                                      dtype=jnp.float32), 32, mp)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
         om0, aux0, _ = moe_apply(pm, x, mp, mesh=None)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             om, aux, _ = moe_apply(pm, x, mp, mesh=mesh, batch_axes=("data",))
         assert np.allclose(om, om0, atol=2e-3), float(jnp.abs(om-om0).max())
         assert np.allclose(aux, aux0, atol=1e-5)
@@ -49,7 +49,7 @@ def test_train_step_sharded_matches_single():
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import ARCHS
         from repro.data import PipelineConfig, TokenPipeline, make_lm_batch
-        from repro.launch.mesh import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.lm import make_train_step
         from repro.nn.transformer import lm_init
         from repro.optim.adamw import AdamWConfig, adamw_init
@@ -71,7 +71,7 @@ def test_train_step_sharded_matches_single():
         fns1 = make_train_step(cfg, opt, mesh=mesh, n_micro=1,
                                param_specs=specs, params_shape=params,
                                donate=False)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             p1, o1, m1 = fns1.step(params, opt_state, batch)
         assert np.allclose(float(m0["loss"]), float(m1["loss"]), atol=5e-3), \
             (float(m0["loss"]), float(m1["loss"]))
@@ -88,7 +88,7 @@ def test_decode_step_sharded_matches_single():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.configs import ARCHS
-        from repro.launch.mesh import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.lm import make_decode_step
         from repro.nn.transformer import init_lm_cache, lm_init
 
@@ -105,7 +105,7 @@ def test_decode_step_sharded_matches_single():
         d1, _, _ = make_decode_step(cfg, mesh=mesh, param_specs=specs,
                                     params_shape=params, cache_shape=cache,
                                     donate_cache=False)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             l1, c1 = d1(params, cache, tok, jnp.int32(0))
         assert np.allclose(l0, l1, atol=2e-3), float(jnp.abs(l0-l1).max())
         print("OK")
@@ -121,11 +121,11 @@ def test_compressed_psum_shardmap():
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum
-        from repro.launch.mesh import make_mesh, set_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(0), (4, 64))
 
-        from repro.compat import shard_map
+        from jax import shard_map
         @partial(shard_map, mesh=mesh, in_specs=(P("pod", None), P("pod", None)),
                  out_specs=(P("pod", None), P("pod", None)), check_vma=False)
         def run(gl, el):

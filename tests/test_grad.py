@@ -29,13 +29,13 @@ def _scheds(g, ev, *, gs=8, gpt=8, ont=8, src_win=64):
     return DeviceSchedule(p), DeviceSchedule(pT, edge_perm=perm)
 
 
-@pytest.mark.parametrize("variant", ["folded", "slot_onehot", "direct"])
-def test_grad_feat_static_edge_values(variant, rng):
+@pytest.mark.parametrize("gs", [4, 8, 16])
+def test_grad_feat_static_edge_values(gs, rng):
     """Static (GCN-style) edge values: d out / d feat via the transposed
-    schedule matches XLA autodiff."""
+    schedule matches XLA autodiff, across group sizes."""
     g = random_power_law(150, 5.0, seed=11)
     ev = rng.uniform(0.5, 1.5, g.num_edges).astype(np.float32)
-    sched, sched_bwd = _scheds(g, ev)
+    sched, sched_bwd = _scheds(g, ev, gs=gs)
     feat = jnp.asarray(rng.standard_normal((g.num_nodes, 24)), jnp.float32)
     cot = jnp.asarray(rng.standard_normal((g.num_nodes, 24)), jnp.float32)
 
@@ -43,18 +43,19 @@ def test_grad_feat_static_edge_values(variant, rng):
                              * cot).sum())(feat)
     gp = jax.grad(lambda f: (aggregate(f, sched, dt=16,
                                        backend="pallas_interpret",
-                                       variant=variant, sched_bwd=sched_bwd)
+                                       sched_bwd=sched_bwd)
                              * cot).sum())(feat)
     np.testing.assert_allclose(gp, gx, atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("variant", ["folded", "slot_onehot", "direct"])
-def test_grad_dynamic_edge_value_cotangents(variant, rng):
+@pytest.mark.parametrize("gs", [4, 8, 16])
+def test_grad_dynamic_edge_value_cotangents(gs, rng):
     """Dynamic (GAT-style) edge values: BOTH cotangents — feat via the
-    transposed schedule, edge values via the per-edge gather-dot kernel."""
+    transposed schedule, edge values via the per-edge gather-dot kernel —
+    across group sizes."""
     g = random_power_law(130, 4.0, seed=12)
     ev0 = rng.uniform(0.5, 1.5, g.num_edges).astype(np.float32)
-    sched, sched_bwd = _scheds(g, ev0)
+    sched, sched_bwd = _scheds(g, ev0, gs=gs)
     feat = jnp.asarray(rng.standard_normal((g.num_nodes, 20)), jnp.float32)
     cot = jnp.asarray(rng.standard_normal((g.num_nodes, 20)), jnp.float32)
     evj = jnp.asarray(ev0)
@@ -62,7 +63,7 @@ def test_grad_dynamic_edge_value_cotangents(variant, rng):
     def loss(backend, sb):
         def f(feat, ev):
             out = aggregate(feat, sched, dt=16, backend=backend,
-                            variant=variant, edge_values=ev, sched_bwd=sb)
+                            edge_values=ev, sched_bwd=sb)
             return (out * cot).sum()
         return f
 
@@ -172,11 +173,12 @@ def test_model_grad_pallas_matches_xla(arch, rng):
                                    err_msg=k)
 
 
-@pytest.mark.parametrize("variant", ["folded", "slot_onehot", "direct"])
-def test_model_grad_both_variants(variant, rng):
-    """Both kernel variants differentiate correctly end to end."""
+@pytest.mark.parametrize("gs", [4, 8, 16])
+def test_model_grad_both_variants(gs, rng):
+    """The kernel differentiates correctly end to end at every group size
+    (the (gpt, gs, src_win) compare mask is the shape gs changes)."""
     g = random_power_law(210, 4.0, seed=32)
-    cc = AggConfig(gs=8, gpt=8, ont=8, src_win=64, dt=16, variant=variant)
+    cc = AggConfig(gs=gs, gpt=8, ont=8, src_win=64, dt=16)
     feat = jnp.asarray(rng.standard_normal((g.num_nodes, 12)), jnp.float32)
     labels = jnp.asarray(rng.integers(0, 3, g.num_nodes).astype(np.int32))
     cfg = GNNConfig(arch="gcn", in_dim=12, hidden_dim=8, num_classes=3,

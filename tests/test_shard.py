@@ -104,22 +104,25 @@ def test_plan_save_load_roundtrip(tmp_path):
 
 def test_plan_jit_args_convention():
     """jit_args/jit_statics + executor_from_args reproduce the plan's own
-    executor (the one convention serving/sampling/sharding share)."""
+    executor (the one convention serving/sampling/sharding share): the
+    kernel from the default (edge-less) args, and the XLA reference —
+    which sums over the schedule's real edges when it has them — from
+    the args that keep the edge members."""
     import jax.numpy as jnp
     from repro.core.plan import Plan
     plan = _gcn_plan()
-    feat = np.random.default_rng(1).standard_normal(
-        (plan.graph.num_nodes, 16)).astype(np.float32)
-    ex = Plan.executor_from_args(plan.jit_statics(), plan.jit_args(),
-                                 backend="xla")
-    ref = plan.executor("xla")(jnp.asarray(feat))
-    np.testing.assert_array_equal(np.asarray(ex(jnp.asarray(feat))),
-                                  np.asarray(ref))
+    feat = jnp.asarray(np.random.default_rng(1).standard_normal(
+        (plan.graph.num_nodes, 16)).astype(np.float32))
+    for backend, args in (("pallas_interpret", plan.jit_args()),
+                          ("xla", plan.jit_args(with_edges=True))):
+        ex = Plan.executor_from_args(plan.jit_statics(), args,
+                                     backend=backend)
+        np.testing.assert_array_equal(np.asarray(ex(feat)),
+                                      np.asarray(plan.executor(backend)(feat)))
     # default drops the unbucketed edge members (they sit after the
-    # tile-shaped fields, incl. the block_visited mask); with_edges keeps
-    # them
+    # tile-shaped fields); with_edges keeps them
     from repro.kernels.ops import N_TILE_FIELDS
-    assert plan.jit_args()[0][N_TILE_FIELDS - 1] is not None  # block_visited
+    assert plan.jit_args()[0][N_TILE_FIELDS - 1] is not None  # tile_window
     assert plan.jit_args()[0][N_TILE_FIELDS] is None          # edge_slot
     assert plan.jit_args(with_edges=True)[0][N_TILE_FIELDS] is not None
 
@@ -272,7 +275,10 @@ def test_sharded_aggregation_matches_single():
 def test_sharded_model_matches_single():
     """gcn + gin on a reorder-renumbered graph: sharded logits match the
     single-device model to 1e-5 and a sharded train step reproduces the
-    1-device loss/params (shard counts {1,2,4})."""
+    1-device loss/params (shard counts {1,2,4}).  Logit and loss errors
+    are magnitude-normalised, max|a - b| / (1 + max|ref|): GIN logits reach
+    O(100), where f32 accumulation-order differences between schedules
+    are ~1e-5 absolute."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.distributed.graph_shard import (make_sharded_logits_fn,
@@ -301,11 +307,14 @@ def test_sharded_model_matches_single():
             for P in (1, 2, 4):
                 shards = model.plan.shards(P)
                 lg = make_sharded_logits_fn(cfg, shards)(model.params, feat)
-                assert np.abs(np.asarray(lg) - ref_lg).max() < 1e-5, (arch, P)
+                err = (np.abs(np.asarray(lg) - ref_lg).max()
+                       / (1.0 + np.abs(ref_lg).max()))
+                assert err < 1e-5, (arch, P, err)
                 s1, m1 = make_sharded_train_step(cfg, shards, opt)(
                     state0, batch)
-                assert abs(float(m1["loss"]) - float(m0["loss"])) < 1e-4, \\
-                    (arch, P)
+                err = (abs(float(m1["loss"]) - float(m0["loss"]))
+                       / (1.0 + abs(float(m0["loss"]))))
+                assert err < 1e-4, (arch, P, err)
                 d = max(float(jnp.abs(a - b).max()) for a, b in
                         zip(jax.tree_util.tree_leaves(s0[0]),
                             jax.tree_util.tree_leaves(s1[0])))
