@@ -17,69 +17,10 @@ from repro.core.extractor import extract_graph_props
 from repro.core.model import AggConfig, KernelModel
 from repro.core.partition import partition_graph, partition_stats
 from repro.kernels import ref
-from repro.kernels.group_aggregate import VARIANTS
 from repro.kernels.ops import DeviceSchedule, aggregate
 
 DATASETS = ["cora", "pubmed", "proteins_full", "artist", "com-amazon"]
 DIM = 64
-
-# Gather-variant races run the REAL kernel body (interpret mode, CPU), so
-# the graph is kept small and the schedules coarse (few grid steps).  The
-# two schedules bracket the decision space: a compute-comfortable f32 tile
-# and a memory-bound bf16 tile (wide window, full-lane dt).
-VARIANT_DATASET = "cora"
-VARIANT_MAX_NODES = 800
-VARIANT_SCHEDULES = [
-    ("f32_d64", dict(gs=8, gpt=32, ont=8, src_win=128, dt=32), 64,
-     "float32"),
-    ("bf16_membound_d128", dict(gs=16, gpt=16, ont=8, src_win=512, dt=128,
-                                feat_dtype="bfloat16"), 128, "bfloat16"),
-]
-
-
-def run_variants():
-    """Per-variant gather-path rows + the measured selector's verdict.
-
-    Emits ``agg_variant/<ds>/<sched>/<variant>`` per candidate and an
-    ``.../selected`` row from `select_variant_measured` so the baseline
-    gate tracks both the raw per-variant latencies and the selector's
-    choice (which must never be slower than the `folded` default)."""
-    import jax
-    from repro.core.advisor import plan_for
-    from repro.core.tuner import select_variant_measured
-
-    g, _, _ = load_replica(VARIANT_DATASET, max_nodes=VARIANT_MAX_NODES)
-    rng = np.random.default_rng(0)
-    for label, knobs, dim, feat_dtype in VARIANT_SCHEDULES:
-        dt = knobs["dt"]
-        jdt = jnp.dtype(feat_dtype)
-        feat = jnp.asarray(rng.standard_normal((g.num_nodes, dim)), jdt)
-        p = partition_graph(g, gs=knobs["gs"], gpt=knobs["gpt"],
-                            ont=knobs["ont"], src_win=knobs["src_win"])
-        sched = DeviceSchedule(p)
-        p50 = {}
-        meas = {}
-        for v in VARIANTS:
-            fn = jax.jit(lambda f, _v=v: aggregate(
-                f, sched, dt=dt, backend="pallas_interpret", variant=_v,
-                out_dtype=jdt))
-            meas[v] = measure_fn(fn, feat, iters=5)
-            p50[v] = meas[v].p50
-        for v in VARIANTS:
-            emit(f"agg_variant/{VARIANT_DATASET}/{label}/{v}",
-                 p50[v] * 1e6, f"vs_folded={p50['folded'] / p50[v]:.2f}x",
-                 stats=meas[v])
-
-        cfg = AggConfig(**knobs)
-        plan = plan_for(g, arch="gcn", in_dim=dim, config=cfg,
-                        feat_dtype=feat_dtype)
-        best, sel_p50 = select_variant_measured(
-            plan, backend="pallas_interpret", dim=dim, iters=3)
-        emit(f"agg_variant/{VARIANT_DATASET}/{label}/selected",
-             sel_p50[best] * 1e6,
-             f"variant={best} "
-             f"vs_folded={sel_p50['folded'] / sel_p50[best]:.2f}x")
-
 
 def run():
     import jax
@@ -158,8 +99,6 @@ def run():
              f"bytes_ratio={term16['bytes'] / term32['bytes']:.2f} "
              f"tpu_model_us_bf16={tpu16 * 1e6:.1f} "
              f"tpu_model_speedup={tpu / tpu16:.2f}x", stats=m_grp16)
-
-    run_variants()
 
 
 if __name__ == "__main__":

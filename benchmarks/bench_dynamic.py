@@ -70,7 +70,7 @@ def _profile(smoke: bool) -> dict:
     return dict(dataset="reddit", max_nodes=None, deltas=2,
                 min_speedup=10.0,
                 config=AggConfig(gs=8, gpt=32, dt=64, src_win=16384,
-                                 ont=8, variant="folded"))
+                                 ont=8))
 
 
 def _parity(plan_a, plan_b) -> float:

@@ -44,6 +44,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 PARITY_TOL = 1e-4        # Pallas step vs the XLA reference, f32
 VERIFY_TOL = 1e-5        # batched vs single-request serving, f32
 KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
+STEPS = 5                # train steps
+REQUESTS = 64            # Zipf serving requests
+LR = 1e-2                # AdamW learning rate
 
 
 def log(msg: str) -> None:
@@ -127,14 +130,14 @@ def peak_hbm() -> int:
     return int(jax.devices()[0].memory_stats()["peak_bytes_in_use"])
 
 
-def train_phase(model, batch, args) -> None:
+def train_phase(model, batch) -> None:
     import jax
     import numpy as np
     from repro.models.gnn import make_gnn_train_step
     from repro.optim.adamw import AdamWConfig, adamw_init
     from repro.runtime.trainer import Trainer, TrainerConfig
 
-    step_fn = make_gnn_train_step(model, AdamWConfig(lr=args.lr))
+    step_fn = make_gnn_train_step(model, AdamWConfig(lr=LR))
     state = (model.params, adamw_init(model.params))
 
     t0 = time.perf_counter()
@@ -167,10 +170,10 @@ def train_phase(model, batch, args) -> None:
 
     with tempfile.TemporaryDirectory() as ckpt_dir:
         trainer = Trainer(
-            TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=args.steps + 1,
-                          log_every=args.steps + 1),
+            TrainerConfig(ckpt_dir=ckpt_dir, ckpt_every=STEPS + 1,
+                          log_every=STEPS + 1),
             step_fn, lambda step: batch, state, log_fn=log)
-        trainer.run(args.steps)
+        trainer.run(STEPS)
         trainer.close()
     losses = [m["loss"] for m in trainer.metrics_history]
     log(f"train: {len(losses)} steps, losses "
@@ -193,7 +196,7 @@ def serve_phase(g, feat, cfg, model, args) -> None:
     t0 = time.perf_counter()
     engine = ServingEngine(g, feat, cfg, params=model.params,
                            serving=ServingConfig(max_batch=16, tune_iters=4))
-    trace = build_trace(g.num_nodes, args.requests, seed=args.seed)
+    trace = build_trace(g.num_nodes, REQUESTS, seed=args.seed)
     reqs = engine.run_trace(trace)
     s = engine.summary()
     log(f"serve: {s['requests']} requests answered, plan-cache hit rate "
@@ -201,7 +204,7 @@ def serve_phase(g, feat, cfg, model, args) -> None:
         f"smoke wall {time.perf_counter() - t0:.1f}s "
         f"(p50 {s['p50_ms']:.2f} ms, p99 {s['p99_ms']:.2f} ms, compiles "
         f"included)")
-    check(s["requests"] == args.requests, "not every request was answered")
+    check(s["requests"] == REQUESTS, "not every request was answered")
     check(all(np.isfinite(r.result).all() for r in reqs),
           "non-finite serving logits")
 
@@ -233,13 +236,13 @@ def serve_phase(g, feat, cfg, model, args) -> None:
     check(err <= VERIFY_TOL, "batched serving disagrees with single-request")
 
 
-def four_chip_phase(model, batch, cfg, args) -> None:
+def four_chip_phase(model, batch, cfg) -> None:
     import jax
     from repro.distributed.graph_shard import make_sharded_train_step
     from repro.models.gnn import make_gnn_train_step
     from repro.optim.adamw import AdamWConfig, adamw_init
 
-    opt = AdamWConfig(lr=args.lr)
+    opt = AdamWConfig(lr=LR)
     state = (model.params, adamw_init(model.params))
     shards = model.plan.shards(4)
     st = shards.stats()
@@ -280,9 +283,6 @@ def main(argv=None) -> int:
                          "and its single-chip comparison")
     ap.add_argument("--dataset", default="soc-blogcatalog",
                     help="paper-dataset replica (repro.graphs.datasets)")
-    ap.add_argument("--steps", type=int, default=5)
-    ap.add_argument("--requests", type=int, default=64)
-    ap.add_argument("--lr", type=float, default=1e-2)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -292,9 +292,9 @@ def main(argv=None) -> int:
     g, feat, labels, cfg = load_graph(args)
     model, batch = build_model(g, feat, labels, cfg, args)
     if args.four_chips:
-        four_chip_phase(model, batch, cfg, args)
+        four_chip_phase(model, batch, cfg)
     else:
-        train_phase(model, batch, args)
+        train_phase(model, batch)
         serve_phase(g, feat, cfg, model, args)
     print(json.dumps({"ok": True, "device": dev}), flush=True)
     return 0

@@ -28,7 +28,6 @@ class PlanExecutor:
         self.sched_bwd = plan.sched_bwd()
         self.backend = resolve_backend(backend)
         self.dt = plan.config.dt
-        self.variant = plan.config.variant
         # outputs follow the plan's dtype policy (f32 accumulation inside;
         # see the dtype rules in repro.kernels.ops)
         self.out_dtype = jnp.dtype(plan.config.feat_dtype)
@@ -39,7 +38,7 @@ class PlanExecutor:
                           jnp.asarray(np.argsort(plan.perm)))
 
     @classmethod
-    def from_schedule(cls, sched: DeviceSchedule, *, dt: int, variant: str,
+    def from_schedule(cls, sched: DeviceSchedule, *, dt: int,
                       backend: str | None = None,
                       sched_bwd: DeviceSchedule = None,
                       out_dtype="float32") -> "PlanExecutor":
@@ -56,8 +55,6 @@ class PlanExecutor:
             array members + static ints).  Arrays may be jax tracers.
         dt : int — dim-tile width handed to the kernel (clamped to the
             feature width at call time).
-        variant : kernel gather variant
-            (`repro.kernels.group_aggregate.VARIANTS`).
         backend : see `repro.kernels.ops` Backend dispatch rules; None
             resolves by platform.
         sched_bwd : optional TRANSPOSED-graph schedule (same duck typing);
@@ -69,7 +66,7 @@ class PlanExecutor:
         Without ``sched_bwd`` the result is forward-only (exactly what
         serving needs).  Example:
 
-        >>> ex = PlanExecutor.from_schedule(sched, dt=128, variant="folded")
+        >>> ex = PlanExecutor.from_schedule(sched, dt=128)
         >>> out = ex(feat)                       # (N, D) float32
         """
         ex = cls.__new__(cls)
@@ -78,7 +75,6 @@ class PlanExecutor:
         ex.sched_bwd = sched_bwd
         ex.backend = resolve_backend(backend)
         ex.dt = dt
-        ex.variant = variant
         ex.out_dtype = jnp.dtype(out_dtype)
         ex._perm = ex._inv_perm = None
         return ex
@@ -87,7 +83,7 @@ class PlanExecutor:
         """feat: (N, D) in the plan's (renumbered) node order -> (N, D) in
         the plan's ``feat_dtype`` (f32 unless a bf16 policy is active)."""
         return _kernel_aggregate(feat, self.sched, dt=self.dt,
-                                 backend=self.backend, variant=self.variant,
+                                 backend=self.backend,
                                  sched_bwd=self.sched_bwd,
                                  out_dtype=self.out_dtype)
 
@@ -99,7 +95,7 @@ class PlanExecutor:
         With a backward schedule, gradients flow to BOTH ``feat`` (via the
         transposed kernel) and ``edge_values`` (per-edge gather-dot)."""
         return _kernel_aggregate(feat, self.sched, dt=self.dt,
-                                 backend=self.backend, variant=self.variant,
+                                 backend=self.backend,
                                  edge_values=edge_values,
                                  sched_bwd=self.sched_bwd,
                                  out_dtype=self.out_dtype)

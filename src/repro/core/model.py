@@ -72,7 +72,6 @@ class AggConfig:
     dt: int = 128         # dim-tile width (paper dw analogue)
     src_win: int = 512    # feature-window rows (TPU shared-memory analogue)
     ont: int = 8          # output rows per block (structural, sublane-aligned)
-    variant: str = "folded"
     feat_dtype: str = "float32"   # feature/activation dtype policy
 
     def astuple(self):
@@ -221,12 +220,25 @@ def config_is_feasible(cfg: AggConfig, *, hw: TPUSpec | None = None,
                                 dim=dim) is None
 
 
+# bf16 MXU passes of one f32 x f32 matmul at Precision.HIGHEST (each
+# operand split into three bf16 terms, six products kept); the kernels run
+# every f32 matmul at HIGHEST
+_F32_HIGHEST_PASSES = 6
+
+
 @dataclasses.dataclass
 class KernelModel:
-    """Three-term latency model of the group_aggregate schedule: the
-    gather matmul on the MXU against the full src_win window plus the
-    W-build iota-compares on the VPU (the term that scales with src_win),
-    window DMA bytes, and per-grid-step overhead."""
+    """Three-term latency model of the group_aggregate schedule.
+
+    Compute prices every grid step of `kernels.group_aggregate._kernel`:
+    the per-slot compare-select on the VPU (gpt*gs*src_win elements), and
+    three MXU matmuls — the group one-hot sum (gpt, gpt*gs) @ (gpt*gs,
+    src_win), which grows as gpt²; the gather (gpt, src_win) @ (src_win,
+    dt); and the node scatter (ont, gpt) @ (gpt, dt).  Matmuls with f32
+    operands run at HIGHEST precision, priced as `_F32_HIGHEST_PASSES`
+    bf16 passes; the gather over a bf16 window is one pass.  Memory is the
+    window DMA bytes plus metadata and output flushes; overhead is per
+    grid step."""
 
     hw: TPUSpec = dataclasses.field(default_factory=device_spec)
 
@@ -239,11 +251,17 @@ class KernelModel:
         dt = dim_tile(cfg.dt, dim, cfg.feat_dtype)
         J = max(math.ceil(dim / dt), 1)
         steps = T * J
-        mxu_flops = steps * 2 * (cfg.gpt * cfg.src_win * dt
-                                 + cfg.ont * cfg.gpt * dt)
-        vpu_ops = steps * cfg.gs * cfg.gpt * cfg.src_win  # W build
-        peak = self.hw.peak_flops_bf16 if bytes_feat == 2 else self.hw.peak_flops_f32
-        t_compute = mxu_flops / peak + vpu_ops / (self.hw.peak_flops_f32 / 2)
+        slots = cfg.gpt * cfg.gs
+        f32 = _F32_HIGHEST_PASSES
+        onehot = 2 * cfg.gpt * slots * cfg.src_win            # f32 always
+        gather = 2 * cfg.gpt * cfg.src_win * dt
+        scatter = 2 * cfg.ont * cfg.gpt * dt                  # f32 always
+        mxu_flops = steps * (onehot + gather + scatter)
+        mxu_passes = steps * (f32 * (onehot + scatter)
+                              + (1 if bytes_feat == 2 else f32) * gather)
+        vpu_ops = steps * slots * cfg.src_win                 # compare-select
+        t_compute = (mxu_passes / self.hw.peak_flops_bf16
+                     + vpu_ops / (self.hw.peak_flops_f32 / 2))
         # memory: feature-window DMAs (dominant), metadata, output flushes
         n_blocks = max(props.num_nodes / cfg.ont, 1.0)
         bytes_windows = steps * cfg.src_win * dt * bytes_feat
@@ -253,7 +271,8 @@ class KernelModel:
         t_overhead = steps * self.hw.grid_step_overhead_s
         return {
             "tiles": T, "steps": steps,
-            "mxu_flops": mxu_flops, "vpu_ops": vpu_ops,
+            "mxu_flops": mxu_flops, "mxu_passes": mxu_passes,
+            "vpu_ops": vpu_ops,
             "bytes": bytes_windows + bytes_meta + bytes_out,
             "t_compute": t_compute, "t_memory": t_memory,
             "t_overhead": t_overhead,

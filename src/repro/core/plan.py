@@ -277,7 +277,7 @@ class Plan:
 
     def jit_statics(self) -> tuple:
         """Hashable static half of the convention: ``(fwd_statics,
-        bwd_statics_or_None, dt, variant, feat_dtype)`` — the jit-cache
+        bwd_statics_or_None, dt, feat_dtype)`` — the jit-cache
         key part.  ``feat_dtype`` is part of the key because the compiled
         executable's operand dtypes and the kernel's dim-tile geometry
         both depend on it.  Feed the (statics, args) pair to
@@ -286,8 +286,7 @@ class Plan:
         sb = self.sched_bwd()
         return (sched_statics(self.sched()),
                 None if sb is None else sched_statics(sb),
-                self.config.dt, self.config.variant,
-                self.config.feat_dtype)
+                self.config.dt, self.config.feat_dtype)
 
     @staticmethod
     def executor_from_args(statics: tuple, args: tuple, *,
@@ -298,10 +297,10 @@ class Plan:
         trainer's per-bucket steps, and the sharded per-device bodies."""
         from repro.core.aggregate import PlanExecutor
         from repro.kernels.ops import SchedView
-        st_f, st_b, dt, variant, feat_dtype = statics
+        st_f, st_b, dt, feat_dtype = statics
         a_f, a_b = args
         return PlanExecutor.from_schedule(
-            SchedView(a_f, st_f), dt=dt, variant=variant, backend=backend,
+            SchedView(a_f, st_f), dt=dt, backend=backend,
             sched_bwd=None if a_b is None else SchedView(a_b, st_b),
             out_dtype=feat_dtype)
 
@@ -334,8 +333,6 @@ class Plan:
         }
         for k in ("gs", "gpt", "dt", "src_win", "ont"):
             data[f"cfg_{k}"] = np.asarray(getattr(self.config, k))
-        data["cfg_variant"] = np.frombuffer(
-            self.config.variant.encode(), dtype=np.uint8)
         data["cfg_feat_dtype"] = np.frombuffer(
             self.config.feat_dtype.encode(), dtype=np.uint8)
         if self.perm is not None:
@@ -384,7 +381,6 @@ class Plan:
                 gs=int(z["cfg_gs"]), gpt=int(z["cfg_gpt"]),
                 dt=int(z["cfg_dt"]), src_win=int(z["cfg_src_win"]),
                 ont=int(z["cfg_ont"]),
-                variant=bytes(z["cfg_variant"]).decode(),
                 # plans saved before the dtype policy default to f32
                 feat_dtype=(bytes(z["cfg_feat_dtype"]).decode()
                             if "cfg_feat_dtype" in z else "float32")),

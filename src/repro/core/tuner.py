@@ -31,14 +31,7 @@ from repro.graphs.csr import CSRGraph, random_community_graph
 from repro.hw import TPUSpec, device_spec
 
 __all__ = ["TunerResult", "evolve", "tune", "community_profile",
-           "SEARCH_SPACE", "select_variant_measured", "measured_tune",
-           "MEASURED_VARIANTS"]
-
-# gather paths the measured stage races by default: every variant the
-# kernel has (`kernels.group_aggregate.VARIANTS`; only ``folded`` compiles
-# for the chip today, so the race is a single entry until a second variant
-# does)
-MEASURED_VARIANTS = ("folded",)
+           "SEARCH_SPACE"]
 
 SEARCH_SPACE = {
     "gs": [4, 8, 16, 32, 64],
@@ -54,16 +47,11 @@ class TunerResult:
     best_score: float
     history: list  # (iteration, best_score)
     evaluations: int  # UNIQUE score-fn evaluations (duplicates are memoized)
-    # best-first (score, config) over every UNIQUE config scored — the
-    # candidate list the measured stage (`measured_tune`) races on hardware
-    top: list = dataclasses.field(default_factory=list)
-    # (config, variant) -> measured p50 seconds, filled by `measured_tune`
-    measured: dict = dataclasses.field(default_factory=dict)
 
 
 def _random_config(rng: np.random.Generator,
                    base: AggConfig = AggConfig()) -> AggConfig:
-    # non-searched fields (ont, variant, feat_dtype) ride along from `base`
+    # non-searched fields (ont, feat_dtype) ride along from `base`
     return dataclasses.replace(
         base,
         gs=int(rng.choice(SEARCH_SPACE["gs"])),
@@ -105,8 +93,7 @@ def evolve(score_fn: Callable[[AggConfig], float], *, pop: int = 16,
     repeats into dict hits.  ``TunerResult.evaluations`` therefore counts
     UNIQUE score-function evaluations (the tuner's true cost).
 
-    ``base`` seeds the non-searched config fields (ont, variant,
-    feat_dtype); ``infeasibility_fn`` (reason string or None = feasible)
+    ``base`` seeds the non-searched config fields (ont, feat_dtype); ``infeasibility_fn`` (reason string or None = feasible)
     overrides the default `config_infeasibility` — e.g. one bound to a
     small-VMEM `TPUSpec` or a bf16-tightened Eq. 4.  Rejection sampling is
     BOUNDED: a sparse-but-nonempty feasible region proceeds with the
@@ -169,10 +156,8 @@ def evolve(score_fn: Callable[[AggConfig], float], *, pop: int = 16,
         scored = scored[:elite] + [(score(c), c) for c in children]
     scored.sort(key=lambda x: x[0])
     history.append((iters, scored[0][0]))
-    ranked = sorted(seen.items(), key=lambda kv: kv[1])
     return TunerResult(best=scored[0][1], best_score=scored[0][0],
-                       history=history, evaluations=len(seen),
-                       top=[(s, c) for c, s in ranked[:8]])
+                       history=history, evaluations=len(seen))
 
 
 def community_profile(community_sizes: Sequence[int], dim: int, *,
@@ -240,135 +225,3 @@ def tune(g: CSRGraph, dim: int, *, props: GraphProps | None = None,
     return evolve(score, pop=pop, iters=iters, seed=seed, base=base,
                   infeasibility_fn=lambda c: config_infeasibility(
                       c, hw=hw, dim=dim))
-
-
-# ---------------------------------------------------------------------------
-# 3. Measured stage — close the loop GNNAdvisor §5 only seeds analytically.
-# ---------------------------------------------------------------------------
-
-def plan_facing_dim(plan, default: int = 64) -> int:
-    """The feature width the KERNEL actually sees for a plan: after the
-    §4.2 dimension-reduced placement the aggregation runs at hidden_dim,
-    otherwise at in_dim.  This is the dim the measured selector benchmarks
-    at and the dim bucket `PlanCache` memoizes variant decisions under."""
-    arch = getattr(plan, "arch", None)
-    if arch is None:
-        return default
-    return arch.hidden_dim if plan.reduce_dim_first else arch.in_dim
-
-
-def select_variant_measured(plan, *, backend: str | None = None,
-                            variants: Sequence[str] = MEASURED_VARIANTS,
-                            dim: int | None = None, iters: int = 3,
-                            warmup: int | None = 2, seed: int = 0,
-                            margin: float = 0.05,
-                            registry=None) -> tuple[str, dict]:
-    """Race the gather variants on one PLANNED schedule and pick the winner.
-
-    Runs the plan's forward schedule under each candidate variant through
-    `repro.obs.profile.measure` (block-until-ready-honest, warmup absorbed)
-    on deterministic features at the plan's kernel-facing dim, and returns
-    ``(best_variant, {variant: p50_seconds})``.
-
-    Candidate ORDER is a preference: a later candidate only unseats an
-    earlier one by beating its p50 by more than ``margin`` (relative), so
-    measurement noise — including the XLA reference backend, where every
-    variant runs the same lowering — resolves to the FIRST candidate (the
-    default).  The selector can only move away from the default on a
-    strict, beyond-noise win; it never picks a variant measurably slower
-    than the default.
-
-    The measurement is per (schedule, dim) — callers memoize it per
-    workload shape class (`PlanCache` keys on graph fingerprint + pow2 dim
-    bucket) rather than per graph.
-    """
-    import jax
-    import numpy as np_
-
-    from repro.core.aggregate import PlanExecutor
-    from repro.obs.profile import measure
-
-    variants = tuple(variants)
-    if not variants:
-        raise ValueError("need at least one candidate variant")
-    cfg = plan.config
-    d = int(dim) if dim is not None else plan_facing_dim(plan)
-    rng = np_.random.default_rng(seed)
-    feat = rng.standard_normal((plan.graph.num_nodes, d)).astype(np_.float32)
-    import jax.numpy as jnp
-    feat_j = jnp.asarray(feat, dtype=jnp.dtype(cfg.feat_dtype))
-
-    sched = plan.sched()
-    p50s: dict = {}
-    for v in variants:
-        ex = PlanExecutor.from_schedule(
-            sched, dt=cfg.dt, variant=v, backend=backend,
-            out_dtype=cfg.feat_dtype)
-        fn = jax.jit(lambda x, _ex=ex: _ex(x))
-        p50s[v] = measure(fn, feat_j, warmup=warmup, iters=iters).p50
-    best = variants[0]
-    for v in variants[1:]:
-        if p50s[v] < p50s[best] * (1.0 - margin):
-            best = v
-    if registry is not None:
-        for v, p50 in p50s.items():
-            registry.gauge(
-                "variant_measured_p50_seconds", labels={"variant": str(v)},
-                desc="measured p50 of the planned schedule per gather "
-                     "variant (select_variant_measured)").set(p50)
-        registry.counter(
-            "variant_selected_total", labels={"variant": str(best)},
-            desc="measured gather-variant selections, by winner").inc()
-    return best, p50s
-
-
-def measured_tune(g: CSRGraph, dim: int, *, top_k: int = 2,
-                  variants: Sequence[str] = MEASURED_VARIANTS,
-                  backend: str | None = None, mode: str = "model",
-                  iters: int = 12, pop: int = 16, seed: int = 0,
-                  feat_dtype: str = "float32", hw: TPUSpec | None = None,
-                  measure_iters: int = 3, warmup: int | None = 2,
-                  props: GraphProps | None = None) -> TunerResult:
-    """Analytical search, then measure the top-k candidates per variant.
-
-    Step 1 is the plain `tune` (the paper's evolutionary search over the
-    white-box model); step 2 builds REAL partitions for the ``top_k`` best
-    unique configs, races each under every candidate gather variant through
-    `repro.obs.profile.measure`, and returns a `TunerResult` whose ``best``
-    is the measured winner (variant stamped into the config) and whose
-    ``best_score`` is its measured p50 in seconds.  The full measurement
-    table lands in ``TunerResult.measured`` as ``{(config, variant): p50}``.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.aggregate import PlanExecutor
-    from repro.kernels.ops import DeviceSchedule
-    from repro.obs.profile import measure
-
-    analytic = tune(g, dim, props=props, mode=mode, iters=iters, pop=pop,
-                    seed=seed, feat_dtype=feat_dtype, hw=hw)
-    candidates = [c for _, c in analytic.top[:max(top_k, 1)]] or [analytic.best]
-    rng = np.random.default_rng(seed)
-    feat = rng.standard_normal((g.num_nodes, dim)).astype(np.float32)
-    feat_j = jnp.asarray(feat, dtype=jnp.dtype(feat_dtype))
-
-    table: dict = {}
-    for cfg in candidates:
-        p = partition_graph(g, gs=cfg.gs, gpt=cfg.gpt, ont=cfg.ont,
-                            src_win=cfg.src_win)
-        sched = DeviceSchedule(p)
-        for v in variants:
-            ex = PlanExecutor.from_schedule(
-                sched, dt=cfg.dt, variant=v, backend=backend,
-                out_dtype=feat_dtype)
-            fn = jax.jit(lambda x, _ex=ex: _ex(x))
-            table[(cfg, v)] = measure(fn, feat_j, warmup=warmup,
-                                      iters=measure_iters).p50
-    (best_cfg, best_variant), best_p50 = min(table.items(),
-                                             key=lambda kv: kv[1])
-    best = dataclasses.replace(best_cfg, variant=best_variant)
-    return TunerResult(best=best, best_score=best_p50,
-                       history=analytic.history,
-                       evaluations=analytic.evaluations,
-                       top=analytic.top, measured=table)

@@ -46,18 +46,13 @@ launches starts its second run from the first one's partial sums.
 from __future__ import annotations
 
 import functools
-from typing import Literal
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["group_aggregate_pallas", "group_edge_grad_pallas", "VARIANTS"]
-
-Variant = Literal["folded"]
-# canonical order: default first (tuner/selector candidate lists index this)
-VARIANTS: tuple = ("folded",)
+__all__ = ["group_aggregate_pallas", "group_edge_grad_pallas"]
 
 # tiles per metadata block: the sublane tile (see module docstring)
 _ROWS = 8
@@ -73,12 +68,6 @@ def _launches(T: int) -> list:
     """(first tile, tile count) of each launch covering T tiles."""
     return [(s, min(MAX_TILES_PER_CALL, T - s))
             for s in range(0, T, MAX_TILES_PER_CALL)]
-
-
-def _check_variant(variant: str) -> None:
-    if variant not in VARIANTS:
-        raise ValueError(f"unknown gather variant {variant!r}; "
-                         f"expected one of {VARIANTS}")
 
 
 def _rows(x: jax.Array) -> jax.Array:
@@ -197,15 +186,13 @@ def _edge_grad_kernel(nb_ref, tw_ref,                 # scalar prefetch (SMEM)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("gs", "gpt", "ont", "src_win", "dt", "variant",
-                     "interpret"),
+    static_argnames=("gs", "gpt", "ont", "src_win", "dt", "interpret"),
 )
 def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
                            nbrs: jax.Array, local_node: jax.Array,
                            tile_node_block: jax.Array, tile_window: jax.Array,
                            *, gs: int, gpt: int, ont: int, src_win: int,
-                           dt: int, variant: Variant = "folded",
-                           interpret: bool = False) -> jax.Array:
+                           dt: int, interpret: bool = False) -> jax.Array:
     """Per-slot edge-value cotangent: the backward of aggregation w.r.t. the
     (T, gpt, gs) edge-value tensor.
 
@@ -220,7 +207,6 @@ def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
     Returns (T, gpt, gs) float32.  Padded slots hold garbage; callers gather
     only real (edge_slot, edge_pos) entries.
     """
-    _check_variant(variant)
     out_rows, d_pad = grad_padded.shape
     n_src, d_pad2 = feat_padded.shape
     assert d_pad == d_pad2 and d_pad % dt == 0, (d_pad, d_pad2, dt)
@@ -262,7 +248,7 @@ def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
 @functools.partial(
     jax.jit,
     static_argnames=("gs", "gpt", "ont", "src_win", "dt", "out_rows",
-                     "variant", "interpret"),
+                     "interpret"),
 )
 def group_aggregate_pallas(feat_padded: jax.Array,
                            nbrs: jax.Array, edge_val: jax.Array,
@@ -270,7 +256,6 @@ def group_aggregate_pallas(feat_padded: jax.Array,
                            tile_node_block: jax.Array, tile_window: jax.Array,
                            *, gs: int, gpt: int, ont: int, src_win: int,
                            dt: int, out_rows: int,
-                           variant: Variant = "folded",
                            interpret: bool = False) -> jax.Array:
     """Run the group-aggregation kernel (one `pl.pallas_call`).
 
@@ -290,7 +275,6 @@ def group_aggregate_pallas(feat_padded: jax.Array,
         output-block / feature-window indices driving the BlockSpec index
         maps.
     gs, gpt, ont, src_win, dt, out_rows : static ints; out_rows % ont == 0.
-    variant : "folded" — see module docstring.
     interpret : run under the Pallas interpreter (CPU).
 
     Returns (out_rows, D_pad) float32: out[v] = Σ_slots ev · feat[nbr]
@@ -308,7 +292,6 @@ def group_aggregate_pallas(feat_padded: jax.Array,
     ...     jnp.asarray(p.tile_window), gs=p.gs, gpt=p.gpt, ont=p.ont,
     ...     src_win=p.src_win, dt=128, out_rows=p.padded_out_rows)
     """
-    _check_variant(variant)
     n_src, d_pad = feat_padded.shape
     assert n_src % src_win == 0 and d_pad % dt == 0, (n_src, d_pad, src_win, dt)
     assert out_rows % ont == 0

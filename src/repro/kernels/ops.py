@@ -263,7 +263,7 @@ def _scatter_edge_values(sched: DeviceSchedule,
 
 
 def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
-                    dt: int, backend: Backend, variant: str,
+                    dt: int, backend: Backend,
                     edge_values: Optional[jax.Array] = None,
                     out_dtype=None) -> jax.Array:
     """Forward-only aggregation (no AD rule on the Pallas paths).
@@ -291,6 +291,9 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
     else:
         ev = sched.edge_val
     if backend == "xla":
+        # edge-less schedules (the tile-only jit args `Plan.jit_args` passes
+        # for shape bucketing) have no edge list to sum over: gather every
+        # slot instead, padded slots contributing 0
         out = _ref.group_aggregate_ref(
             _pad_to(feat, sched.padded_src_rows, d),
             sched.nbrs, ev, sched.local_node,
@@ -305,7 +308,7 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
         sched.tile_node_block, sched.tile_window,
         gs=sched.gs, gpt=sched.gpt, ont=sched.ont, src_win=sched.src_win,
         dt=dt_eff, out_rows=sched.padded_out_rows,
-        variant=variant, interpret=(backend == "pallas_interpret"),
+        interpret=(backend == "pallas_interpret"),
     )
     # node blocks no tile names (bipartite sampled blocks: edge-less rows
     # past num_dst) keep the zeros the kernel's accumulator starts from
@@ -314,8 +317,7 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
 
 def _edge_cotangent(g_out: jax.Array, feat: jax.Array,
                     sched: DeviceSchedule, *, dt: int,
-                    backend: Backend,
-                    variant: str = "folded") -> jax.Array:
+                    backend: Backend) -> jax.Array:
     """Cotangent w.r.t. per-edge values (original CSR order): the per-edge
     gather-dot <g_out[dst], feat[src]>, via the forward schedule."""
     n, d = feat.shape
@@ -335,7 +337,7 @@ def _edge_cotangent(g_out: jax.Array, feat: jax.Array,
         sched.nbrs, sched.local_node,
         sched.tile_node_block, sched.tile_window,
         gs=sched.gs, gpt=sched.gpt, ont=sched.ont,
-        src_win=sched.src_win, dt=dt_eff, variant=variant,
+        src_win=sched.src_win, dt=dt_eff,
         interpret=(backend == "pallas_interpret"))
     return per_slot.reshape(T * gpt, gs)[sched.edge_slot, sched.edge_pos]
 
@@ -348,26 +350,24 @@ def _edge_cotangent(g_out: jax.Array, feat: jax.Array,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _aggregate_diff(statics, statics_bwd, opts, feat, edge_values, arrs,
                     arrs_bwd):
-    dt, backend, variant, out_dtype = opts
+    dt, backend, out_dtype = opts
     return _aggregate_impl(feat, SchedView(arrs, statics), dt=dt,
-                           backend=backend, variant=variant,
-                           edge_values=edge_values,
+                           backend=backend, edge_values=edge_values,
                            out_dtype=jnp.dtype(out_dtype))
 
 
 def _aggregate_diff_fwd(statics, statics_bwd, opts, feat, edge_values, arrs,
                         arrs_bwd):
-    dt, backend, variant, out_dtype = opts
+    dt, backend, out_dtype = opts
     out = _aggregate_impl(feat, SchedView(arrs, statics), dt=dt,
-                          backend=backend, variant=variant,
-                          edge_values=edge_values,
+                          backend=backend, edge_values=edge_values,
                           out_dtype=jnp.dtype(out_dtype))
     return out, (feat, edge_values, arrs, arrs_bwd)
 
 
 def _aggregate_diff_bwd(statics, statics_bwd, opts, res, g_out):
     feat, edge_values, arrs, arrs_bwd = res
-    dt, backend, variant, _ = opts
+    dt, backend, _ = opts
     sched = SchedView(arrs, statics)
     sched_bwd = SchedView(arrs_bwd, statics_bwd)
     # run the backward aggregation in the FORWARD feature dtype (bf16
@@ -379,10 +379,10 @@ def _aggregate_diff_bwd(statics, statics_bwd, opts, res, g_out):
     else:
         ev_bwd = edge_values[sched_bwd.edge_perm]
         ev_bar = _edge_cotangent(g_out, feat, sched,
-                                 dt=dt, backend=backend, variant=variant
+                                 dt=dt, backend=backend
                                  ).astype(edge_values.dtype)
     feat_bar = _aggregate_impl(g_out, sched_bwd, dt=dt, backend=backend,
-                               variant=variant, edge_values=ev_bwd)
+                               edge_values=ev_bwd)
     return (feat_bar.astype(feat.dtype), ev_bar,
             _zero_cotangents(arrs), _zero_cotangents(arrs_bwd))
 
@@ -392,7 +392,6 @@ _aggregate_diff.defvjp(_aggregate_diff_fwd, _aggregate_diff_bwd)
 
 def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
               dt: int = 128, backend: Optional[Backend] = None,
-              variant: str = "folded",
               edge_values: Optional[jax.Array] = None,
               sched_bwd: Optional[DeviceSchedule] = None,
               out_dtype=None) -> jax.Array:
@@ -405,11 +404,6 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
     16-bit between layers).
 
     backend: None resolves by platform (`resolve_backend`).
-
-    variant: gather path on the Pallas backends — one of
-    `repro.kernels.group_aggregate.VARIANTS`; applies to forward, feature
-    backward, and the edge-value cotangent alike so the custom VJP stays
-    variant-consistent.  The XLA reference ignores it (one lowering).
 
     edge_values: optional (E,) per-edge weights in ORIGINAL CSR edge order,
     overriding the schedule's static values — the dynamic-edge-value path
@@ -424,8 +418,7 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
     backend = resolve_backend(backend)
     if sched_bwd is None:
         return _aggregate_impl(feat, sched, dt=dt, backend=backend,
-                               variant=variant, edge_values=edge_values,
-                               out_dtype=out_dtype)
+                               edge_values=edge_values, out_dtype=out_dtype)
     if edge_values is not None and sched_bwd.edge_perm is None:
         raise ValueError(
             "dynamic edge_values need a backward schedule with edge_perm "
@@ -433,6 +426,6 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
     # out_dtype rides in nondiff opts as a canonical NAME (hashable)
     out_name = jnp.dtype(jnp.float32 if out_dtype is None else out_dtype).name
     return _aggregate_diff(sched_statics(sched), sched_statics(sched_bwd),
-                           (dt, backend, variant, out_name), feat,
+                           (dt, backend, out_name), feat,
                            edge_values,
                            sched_arrays(sched), sched_arrays(sched_bwd))

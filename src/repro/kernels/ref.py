@@ -14,7 +14,6 @@ import numpy as np
 __all__ = [
     "segment_aggregate_ref",
     "group_aggregate_ref",
-    "group_edge_grad_ref",
     "schedule_edges",
     "edge_centric_aggregate_ref",
     "node_centric_aggregate_ref",
@@ -87,26 +86,6 @@ def schedule_edges(nbrs: jax.Array, local_node: jax.Array,
     dst = (tile_node_block[edge_slot // gpt] * ont
            + local_node.reshape(-1)[edge_slot])
     return src, dst, flat
-
-
-def group_edge_grad_ref(grad_out: jax.Array, feat: jax.Array,
-                        nbrs: jax.Array, local_node: jax.Array,
-                        tile_node_block: jax.Array, ont: int) -> jax.Array:
-    """Oracle for `group_edge_grad_pallas`: per-slot <grad[dst], feat[src]>.
-
-    grad_out:        (out_rows, D) output cotangent (padded rows are zero).
-    feat:            (N_src_pad, D)
-    nbrs:            (T, gpt, gs) — source ids per slot
-    local_node:      (T, gpt), tile_node_block: (T,)
-    Returns (T, gpt, gs) float32 (padded slots carry don't-care values).
-    """
-    T, gpt, gs = nbrs.shape
-    rows = tile_node_block[:, None] * ont + local_node           # (T, gpt)
-    gsel = jnp.take(grad_out, rows.reshape(-1), axis=0).astype(jnp.float32)
-    fsel = jnp.take(feat, nbrs.reshape(-1), axis=0).astype(jnp.float32)
-    dots = (fsel.reshape(T, gpt, gs, -1)
-            * gsel.reshape(T, gpt, 1, -1)).sum(axis=-1)
-    return dots
 
 
 def edge_centric_aggregate_ref(feat, src, dst, edge_val, num_nodes):

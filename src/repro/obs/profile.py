@@ -308,8 +308,8 @@ def profile_plan(plan, feat=None, *, backend: Optional[str] = None,
     if plan.partition_bwd is not None:
         from repro.core.aggregate import PlanExecutor
         bwd_ex = PlanExecutor.from_schedule(
-            plan.sched_bwd(), dt=plan.config.dt, variant=plan.config.variant,
-            backend=backend, out_dtype=plan.config.feat_dtype)
+            plan.sched_bwd(), dt=plan.config.dt, backend=backend,
+            out_dtype=plan.config.feat_dtype)
         bwd_fn = jax.jit(lambda x: bwd_ex(x))
         ct = jnp.ones_like(feat_j)
         m_bwd = measure(bwd_fn, ct, warmup=warmup, iters=iters)
@@ -348,13 +348,8 @@ def profile_plan(plan, feat=None, *, backend: Optional[str] = None,
     report = ProfileReport(schedules=tuple(schedules), total=m_total,
                            dim=d, backend=backend)
     if registry is not None:
-        # the variant label makes residuals / achieved bytes attributable
-        # per GATHER PATH, not just per schedule — without it a measured
-        # selector flipping a plan from folded to direct would silently
-        # re-base every profile gauge it touches
-        variant = str(plan.config.variant)
         for s in schedules:
-            lbl = {"schedule": s.schedule, "variant": variant}
+            lbl = {"schedule": s.schedule}
             registry.gauge(
                 "kernel_model_residual", labels=lbl,
                 desc="measured p50 / KernelModel-predicted latency",

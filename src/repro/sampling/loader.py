@@ -59,8 +59,7 @@ def sampled_agg_config(g: CSRGraph):
     """
     from repro.core.model import AggConfig
     src_win = min(max(bucket_pow2(max(g.num_nodes // 8, 1)), 256), 4096)
-    return AggConfig(gs=8, gpt=8, dt=128, src_win=src_win, ont=8,
-                     variant="folded")
+    return AggConfig(gs=8, gpt=8, dt=128, src_win=src_win, ont=8)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -222,7 +221,7 @@ class SampledLoader:
             acfg = ent.plan.config
             key_parts.append((
                 acfg.gs, acfg.gpt, acfg.ont, acfg.src_win, acfg.dt,
-                acfg.variant, sub.num_nodes,
+                sub.num_nodes,
                 ent.executor.sched.num_tiles,
                 None if ent.executor.sched_bwd is None
                 else ent.executor.sched_bwd.num_tiles))
@@ -597,7 +596,7 @@ class ShardedSampledTrainStep:
                            for p in plans]
                 st_b = st_f
                 arrs_b = self._stack_parts(parts_b, jnp)
-            statics.append((st_f, st_b, c.dt, c.variant, c.feat_dtype))
+            statics.append((st_f, st_b, c.dt, c.feat_dtype))
             blocks.append((self._stack_parts(parts, jnp), arrs_b))
             layer_shapes.append((n_t, t_f,
                                  None if st_b is None else arrs_b[0].shape))

@@ -90,9 +90,9 @@ def graph_fingerprint(g: CSRGraph, arch_key: tuple = ()) -> tuple:
     different edge VALUES reuses the tuned config), but a mutated graph
     practically never collides with its pre-mutation self: indptr is
     cumulative, so even a single inserted or deleted edge shifts every
-    later sampled row pointer.  That is what keeps the config memo and the
-    measured-variant memo from silently serving decisions made for a
-    different graph after a `GraphDelta` lands."""
+    later sampled row pointer.  That is what keeps the config memo from
+    silently serving decisions made for a different graph after a
+    `GraphDelta` lands."""
     h = hashlib.blake2b(digest_size=8)
     h.update(np.int64([g.num_nodes, g.num_edges]).tobytes())
     if g.num_nodes:
@@ -160,14 +160,11 @@ class PlanCache:
                  bucket_shapes: bool = True, seed: int = 0,
                  with_backward: bool = False, config_fn=None,
                  feat_dtype: str = "float32",
-                 measure_variants: bool = False,
-                 variant_candidates: Optional[tuple] = None,
-                 variant_measure_iters: int = 3,
                  fingerprint_fn: Callable = graph_fingerprint,
                  registry: Optional[MetricsRegistry] = None):
         self.backend = resolve_backend(backend)
-        # fingerprint_fn: (CSRGraph, arch_key) -> hashable — the config/
-        # variant memo key.  Default is the content-aware
+        # fingerprint_fn: (CSRGraph, arch_key) -> hashable — the config
+        # memo key.  Default is the content-aware
         # `graph_fingerprint`; the sampled loader opts into the coarser
         # `shape_class_fingerprint` (see its docstring for why that is
         # safe there and nowhere else).
@@ -191,21 +188,6 @@ class PlanCache:
         # kernel model prices wrong) supply a heuristic; the memo and the
         # two-level hit accounting behave exactly as with the tuner.
         self.config_fn = config_fn
-        # measure_variants: race the kernel gather variants on each newly
-        # planned schedule (`core.tuner.select_variant_measured`) and stamp
-        # the measured winner into the plan's config.  The decision is
-        # memoized per (graph_fingerprint, pow2 kernel-facing-dim bucket) —
-        # the same shape-class key the config memo uses — so one
-        # measurement transfers across every graph in the workload class.
-        # Off by default: measurement costs a few kernel launches per new
-        # shape class, and on backend="xla" (single lowering) all variants
-        # tie, so the default folded wins and nothing changes.
-        self.measure_variants = measure_variants
-        self.variant_candidates = variant_candidates
-        self.variant_measure_iters = variant_measure_iters
-        self._variants: "OrderedDict[tuple, str]" = OrderedDict()
-        self.variant_selections = 0
-        self.variant_memo_hits = 0
         # with_backward: every built plan also carries the transposed-graph
         # schedule (`plan_for(with_backward=True)`) so cached entries are
         # train-ready — the sampled mini-batch loader's mode.  Backward tile
@@ -326,8 +308,6 @@ class PlanCache:
                     part_bwd, bucket_pow2(part_bwd.num_tiles))
             plan = dataclasses.replace(plan, partition=part,
                                        partition_bwd=part_bwd)
-        if self.measure_variants:
-            plan = self._apply_measured_variant(plan, fp)
         ent = CacheEntry(plan=plan, executor=plan.executor(self.backend),
                          fingerprint=fp, epoch=0 if epoch is None else epoch)
         self._h_build.observe(time.perf_counter() - t_build)
@@ -341,53 +321,17 @@ class PlanCache:
             self._c_evict.inc()
         return ent
 
-    def _apply_measured_variant(self, plan: Plan, fp: tuple) -> Plan:
-        """Stamp the measured-winner gather variant into a freshly built
-        plan (runs inside the cache lock — measurement serializes with
-        builds, which is what a shared cache wants: one thread measures,
-        everyone reuses).
-
-        The memo key is (graph fingerprint, pow2 bucket of the
-        kernel-facing dim): the variant tradeoff depends on the schedule
-        shape class and the feature width the kernel runs at, not on the
-        exact subgraph."""
-        from repro.core.tuner import plan_facing_dim, select_variant_measured
-
-        vkey = fp + (bucket_pow2(plan_facing_dim(plan)),)
-        variant = self._variants.get(vkey)
-        if variant is not None:
-            self._variants.move_to_end(vkey)
-            self.variant_memo_hits += 1
-        else:
-            kwargs = {} if self.variant_candidates is None else {
-                "variants": self.variant_candidates}
-            variant, _ = select_variant_measured(
-                plan, backend=self.backend, seed=self.seed,
-                iters=self.variant_measure_iters, registry=self.registry,
-                **kwargs)
-            self._variants[vkey] = variant
-            self.variant_selections += 1
-            # bound alongside the config memo (same workload-class growth)
-            while (self.max_configs is not None
-                   and len(self._variants) > self.max_configs):
-                self._variants.popitem(last=False)
-        if variant != plan.config.variant:
-            plan = dataclasses.replace(
-                plan, config=dataclasses.replace(plan.config, variant=variant))
-        return plan
-
     def invalidate(self, fingerprint: Optional[tuple] = None, *,
                    before_epoch: Optional[int] = None) -> int:
         """Keyed invalidation after a graph mutation (docs/dynamic.md).
 
         ``fingerprint``: drop the ready plans built under that fingerprint
-        plus its config-memo and measured-variant entries.  ``before_epoch``:
-        drop every ready plan stamped with an earlier graph epoch (the
-        serving engine's swap protocol — entries for egos of the
-        pre-mutation snapshot), plus ALL measured-variant entries (they
-        were measured on pre-mutation schedules); the config memo is kept,
-        a shape-class tuning decision survives content changes.  With
-        neither selector the whole cache (all three levels) is dropped.
+        plus its config-memo entry.  ``before_epoch``: drop every ready
+        plan stamped with an earlier graph epoch (the serving engine's swap
+        protocol — entries for egos of the pre-mutation snapshot); the
+        config memo is kept, a shape-class tuning decision survives content
+        changes.  With neither selector the whole cache (both levels) is
+        dropped.
         Returns the number of entries removed; each removal counts into
         ``plan_cache_invalidations_total``."""
         with self._lock:
@@ -403,17 +347,9 @@ class PlanCache:
             if fingerprint is not None:
                 if self._configs.pop(fingerprint, None) is not None:
                     n += 1
-                for vk in list(self._variants):
-                    if vk[:-1] == fingerprint:
-                        del self._variants[vk]
-                        n += 1
-            elif before_epoch is not None:
-                n += len(self._variants)
-                self._variants.clear()
-            else:
-                n += len(self._configs) + len(self._variants)
+            elif before_epoch is None:
+                n += len(self._configs)
                 self._configs.clear()
-                self._variants.clear()
             self.invalidations += n
             self._c_invalidate.inc(n)
             return n
@@ -456,6 +392,4 @@ class PlanCache:
             "evictions": self.evictions,
             "config_evictions": self.config_evictions,
             "invalidations": self.invalidations,
-            "variant_selections": self.variant_selections,
-            "variant_memo_hits": self.variant_memo_hits,
         }

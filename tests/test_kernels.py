@@ -20,12 +20,11 @@ def _oracle(g, feat, ev):
                                      g.num_nodes)
 
 
-def _run(g, feat, ev, *, gs, gpt, ont, src_win, dt, variant, backend):
+def _run(g, feat, ev, *, gs, gpt, ont, src_win, dt, backend):
     p = partition_graph(g, gs=gs, gpt=gpt, ont=ont, src_win=src_win,
                         edge_vals=ev)
     sched = DeviceSchedule(p)
-    return aggregate(jnp.asarray(feat), sched, dt=dt, backend=backend,
-                     variant=variant)
+    return aggregate(jnp.asarray(feat), sched, dt=dt, backend=backend)
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float16])
@@ -37,7 +36,7 @@ def test_kernel_shape_dtype_sweep(dtype, dim, gs, rng):
     ev = rng.uniform(0.5, 1.5, g.num_edges).astype(np.float32)
     want = _oracle(g, feat.astype(np.float32), ev)
     got = _run(g, feat, ev, gs=gs, gpt=16, ont=8, src_win=64, dt=16,
-               variant="folded", backend="pallas_interpret")
+               backend="pallas_interpret")
     tol = 1e-4 if dtype == np.float32 else 2e-2
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
 
@@ -53,7 +52,7 @@ def test_kernel_config_sweep(gs, gpt, ont, src_win, dt, rng):
     ev = np.ones(g.num_edges, np.float32)
     want = _oracle(g, feat, ev)
     got = _run(g, feat, ev, gs=gs, gpt=gpt, ont=ont, src_win=src_win, dt=dt,
-               variant="folded", backend="pallas_interpret")
+               backend="pallas_interpret")
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 
@@ -64,7 +63,7 @@ def test_kernel_grid_graph_exact(rng):
     ev = np.ones(g.num_edges, np.float32)
     want = _oracle(g, feat, ev)
     got = _run(g, feat, ev, gs=4, gpt=8, ont=8, src_win=32, dt=16,
-               variant="folded", backend="pallas_interpret")
+               backend="pallas_interpret")
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
@@ -74,7 +73,7 @@ def test_xla_backend_matches(rng, small_graph):
     ev = rng.uniform(0.1, 2.0, g.num_edges).astype(np.float32)
     want = _oracle(g, feat, ev)
     got = _run(g, feat, ev, gs=8, gpt=16, ont=8, src_win=128, dt=32,
-               variant="folded", backend="xla")
+               backend="xla")
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 
@@ -94,7 +93,7 @@ def test_kernel_property_random(n, avg_deg, dim, gs, seed):
     ev = r.uniform(-1.0, 1.0, g.num_edges).astype(np.float32)
     want = _oracle(g, feat, ev)
     got = _run(g, feat, ev, gs=gs, gpt=8, ont=8, src_win=64, dt=8,
-               variant="folded", backend="pallas_interpret")
+               backend="pallas_interpret")
     np.testing.assert_allclose(got, want, atol=1e-3, rtol=1e-3)
 
 

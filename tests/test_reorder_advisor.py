@@ -79,6 +79,27 @@ def test_feasibility_constraints():
                                             src_win=8192))
 
 
+@pytest.mark.parametrize("feat_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("gpt", [8, 32])
+def test_kernel_model_prices_group_onehot_matmul(small_graph, gpt,
+                                                 feat_dtype):
+    """Each grid step's MXU work is the group one-hot sum (gpt, gpt*gs) @
+    (gpt*gs, src_win) — it grows as gpt² — the gather (gpt, src_win) @
+    (src_win, dt) and the node scatter (ont, gpt) @ (gpt, dt).  f32
+    matmuls run at HIGHEST, six bf16 passes; a bf16 window makes the
+    gather one pass."""
+    props = extract_graph_props(small_graph, detect_communities=False)
+    cfg = AggConfig(gs=8, gpt=gpt, dt=128, src_win=256, feat_dtype=feat_dtype)
+    t = KernelModel().terms(props, 128, cfg, tiles=10)
+    onehot, gather = 2 * gpt * gpt * 8 * 256, 2 * gpt * 256 * 128
+    scatter = 2 * 8 * gpt * 128
+    gather_passes = 1 if feat_dtype == "bfloat16" else 6
+    assert t["steps"] == 10
+    assert t["mxu_flops"] == 10 * (onehot + gather + scatter)
+    assert t["mxu_passes"] == 10 * (6 * (onehot + scatter)
+                                     + gather_passes * gather)
+
+
 def test_tuner_monotone_and_feasible(small_graph):
     res = tune(small_graph, 64, mode="model", iters=8, seed=0)
     scores = [s for _, s in res.history]

@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.model import AggConfig, config_is_feasible, vmem_working_set
 from repro.core.tuner import SEARCH_SPACE
-from repro.kernels.group_aggregate import (VARIANTS, group_aggregate_pallas,
+from repro.kernels.group_aggregate import (group_aggregate_pallas,
                                            group_edge_grad_pallas)
 from repro.kernels.ops import dim_tile
 
@@ -56,8 +56,7 @@ def _padded(cfg: AggConfig, d: int, dtype: str) -> tuple[int, int]:
     return dt, -(-d // dt) * dt
 
 
-def _compile_fwd(sharding, cfg: AggConfig, d: int, dtype: str,
-                 variant: str) -> str:
+def _compile_fwd(sharding, cfg: AggConfig, d: int, dtype: str) -> str:
     dt, d_pad = _padded(cfg, d, dtype)
     s = lambda shape, dt_: _shape(sharding, shape, dt_)
     args = (s((4 * cfg.src_win, d_pad), dtype),
@@ -65,20 +64,18 @@ def _compile_fwd(sharding, cfg: AggConfig, d: int, dtype: str,
             s((T, cfg.gpt), "int32"), s((T,), "int32"), s((T,), "int32"))
     fn = lambda *a: group_aggregate_pallas(
         *a, gs=cfg.gs, gpt=cfg.gpt, ont=cfg.ont, src_win=cfg.src_win, dt=dt,
-        out_rows=T * cfg.ont, variant=variant)
+        out_rows=T * cfg.ont)
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _compile_edge_grad(sharding, cfg: AggConfig, d: int, dtype: str,
-                       variant: str) -> str:
+def _compile_edge_grad(sharding, cfg: AggConfig, d: int, dtype: str) -> str:
     dt, d_pad = _padded(cfg, d, dtype)
     s = lambda shape, dt_: _shape(sharding, shape, dt_)
     args = (s((T * cfg.ont, d_pad), dtype), s((4 * cfg.src_win, d_pad), dtype),
             s((T, cfg.gpt, cfg.gs), "int32"), s((T, cfg.gpt), "int32"),
             s((T,), "int32"), s((T,), "int32"))
     fn = lambda *a: group_edge_grad_pallas(
-        *a, gs=cfg.gs, gpt=cfg.gpt, ont=cfg.ont, src_win=cfg.src_win, dt=dt,
-        variant=variant)
+        *a, gs=cfg.gs, gpt=cfg.gpt, ont=cfg.ont, src_win=cfg.src_win, dt=dt)
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
@@ -87,18 +84,14 @@ _BASE = AggConfig(gs=16, gpt=16, dt=128, src_win=512)
 
 @pytest.mark.parametrize("d", [16, 128, 640])
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("variant", VARIANTS)
-def test_aggregate_compiles(one_chip, variant, dtype, d):
-    assert "tpu_custom_call" in _compile_fwd(one_chip, _BASE, d, dtype,
-                                             variant)
+def test_aggregate_compiles(one_chip, dtype, d):
+    assert "tpu_custom_call" in _compile_fwd(one_chip, _BASE, d, dtype)
 
 
 @pytest.mark.parametrize("d", [16, 128, 640])
 @pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("variant", VARIANTS)
-def test_edge_grad_compiles(one_chip, variant, dtype, d):
-    assert "tpu_custom_call" in _compile_edge_grad(one_chip, _BASE, d, dtype,
-                                                   variant)
+def test_edge_grad_compiles(one_chip, dtype, d):
+    assert "tpu_custom_call" in _compile_edge_grad(one_chip, _BASE, d, dtype)
 
 
 # "largest" by the whole modelled working set, and by the per-slot
@@ -128,5 +121,4 @@ def test_largest_feasible_config_compiles(one_chip, dtype, kernel, measure):
     the feature operand as wide as the config's dim tile."""
     cfg = _largest_feasible(dtype, measure)
     compile_ = _compile_fwd if kernel == "aggregate" else _compile_edge_grad
-    assert "tpu_custom_call" in compile_(one_chip, cfg, cfg.dt, dtype,
-                                         VARIANTS[0])
+    assert "tpu_custom_call" in compile_(one_chip, cfg, cfg.dt, dtype)

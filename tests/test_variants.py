@@ -1,10 +1,6 @@
-"""Gather-variant plumbing and the measured per-schedule variant
-selection, plus the kernel on bipartite (unwritten-node-block) blocks and
-in interpret mode inside shard_map.
-
-Only ``folded`` compiles for the chip today (`VARIANTS`), so the races
-below have one candidate; the plumbing they test is what a second variant
-plugs into.
+"""The kernel on bipartite (unwritten-node-block) blocks and in interpret
+mode inside shard_map, the plan's jit statics and npz round trip, and the
+profile gauges' labels.
 """
 import json
 import os
@@ -20,8 +16,6 @@ import jax.numpy as jnp
 from repro.core.model import AggConfig
 from repro.core.partition import partition_graph, transpose_graph
 from repro.graphs.csr import random_power_law
-from repro.core.tuner import MEASURED_VARIANTS
-from repro.kernels.group_aggregate import VARIANTS
 from repro.kernels.ops import DeviceSchedule, aggregate
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -53,15 +47,6 @@ def test_bipartite_unvisited_blocks_read_zero(rng):
     assert np.all(out[g.num_nodes:] == 0.0)
     assert np.all(np.isfinite(out))
     np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
-
-
-def test_unknown_variant_raises(rng):
-    g = random_power_law(50, 3.0, seed=17)
-    sched, _ = _scheds(g, gs=4, gpt=4, src_win=32)
-    feat = jnp.asarray(rng.standard_normal((g.num_nodes, 8)), jnp.float32)
-    with pytest.raises(ValueError, match="unknown gather variant"):
-        aggregate(feat, sched, dt=8, backend="pallas_interpret",
-                  variant="banana")
 
 
 # ---------------------------------------------- interpret-mode shard_map
@@ -103,102 +88,56 @@ def test_kernel_in_shard_map_interpret():
     assert "OK" in r.stdout
 
 
-# ------------------------------------------------- variant plumbing keys
+# ----------------------------------------------- plan statics and npz
 
 
-def test_variant_in_jit_statics_and_npz_roundtrip(tmp_path):
+def test_config_in_jit_statics_and_npz_roundtrip(tmp_path):
     from repro.core.advisor import plan_for
     from repro.core.plan import Plan
+    from repro.kernels.ops import sched_statics
     g = random_power_law(150, 5.0, seed=18)
     cfg = AggConfig(gs=8, gpt=8, ont=8, src_win=64, dt=16,
-                    variant=VARIANTS[0])
-    plan = plan_for(g, arch="gcn", in_dim=16, config=cfg)
-    # cached executables key on jit_statics: the variant is part of them
-    assert VARIANTS[0] in plan.jit_statics()
+                    feat_dtype="bfloat16")
+    plan = plan_for(g, arch="gcn", in_dim=16, config=cfg,
+                    feat_dtype="bfloat16")
+    # cached executables key on jit_statics: the schedule geometry, the
+    # dim tile and the dtype policy
+    assert plan.jit_statics() == (sched_statics(plan.sched()), None, 16,
+                                  "bfloat16")
     path = str(tmp_path / "plan.npz")
     plan.save(path)
-    assert Plan.load(path).config.variant == VARIANTS[0]
+    assert Plan.load(path).config == cfg
 
 
-# -------------------------------------------------- measured selection
-
-
-def test_select_variant_measured_never_picks_slower():
-    """On the XLA reference backend every variant runs the same lowering,
-    so measurement is a noise wash — the selector sticks with the first
-    candidate (the default) unless a later one wins beyond the margin.
-    Either way it must NEVER return a variant measured slower than the
-    default."""
+def test_plan_load_ignores_retired_variant_field(tmp_path):
+    """Plans saved while the kernel had several gather variants carry a
+    ``cfg_variant`` field; they still load, to the same config."""
     from repro.core.advisor import plan_for
-    from repro.core.tuner import select_variant_measured
-    g = random_power_law(120, 4.0, seed=19)
-    plan = plan_for(g, arch="gcn", in_dim=16, tune_iters=2)
-    best, p50s = select_variant_measured(plan, backend="xla", iters=3,
-                                         warmup=1)
-    assert set(p50s) == set(MEASURED_VARIANTS)
-    if best != "folded":       # only on a strict beyond-margin win
-        assert p50s[best] < p50s["folded"] * 0.95
-    # a giant margin always resolves to the default
-    best2, _ = select_variant_measured(plan, backend="xla", iters=2,
-                                       warmup=1, margin=1.0)
-    assert best2 == "folded"
+    from repro.core.plan import Plan
+    g = random_power_law(150, 5.0, seed=19)
+    cfg = AggConfig(gs=8, gpt=8, ont=8, src_win=64, dt=16)
+    plan = plan_for(g, arch="gcn", in_dim=16, config=cfg)
+    path = str(tmp_path / "plan.npz")
+    plan.save(path)
+    data = dict(np.load(path))
+    data["cfg_variant"] = np.frombuffer(b"direct", dtype=np.uint8)
+    legacy = str(tmp_path / "legacy.npz")
+    np.savez_compressed(legacy, **data)
+    loaded = Plan.load(legacy)
+    assert loaded.config == cfg
+    feat = jnp.asarray(np.random.default_rng(0).standard_normal(
+        (g.num_nodes, 16)), jnp.float32)
+    np.testing.assert_allclose(np.asarray(loaded.executor("xla")(feat)),
+                               np.asarray(plan.executor("xla")(feat)),
+                               atol=1e-6, rtol=1e-6)
 
 
-def test_select_variant_measured_registry_labels():
-    from repro.core.advisor import plan_for
-    from repro.core.tuner import select_variant_measured
-    from repro.obs import MetricsRegistry
-    from repro.obs.export import lint_prometheus, to_prometheus_text
-    g = random_power_law(120, 4.0, seed=20)
-    plan = plan_for(g, arch="gcn", in_dim=16, tune_iters=2)
-    reg = MetricsRegistry()
-    best, _ = select_variant_measured(plan, backend="xla", iters=2,
-                                      warmup=1, registry=reg)
-    gauges = [m for m in reg.snapshot()
-              if m["name"] == "variant_measured_p50_seconds"]
-    assert ({m["labels"]["variant"] for m in gauges}
-            == set(MEASURED_VARIANTS))
-    assert lint_prometheus(to_prometheus_text(reg)) == []
-
-
-def test_measured_tune_returns_table():
-    from repro.core.tuner import measured_tune
-    g = random_power_law(200, 5.0, seed=21)
-    tr = measured_tune(g, 32, top_k=2, iters=3, pop=6, measure_iters=2,
-                       backend="pallas_interpret")
-    assert tr.best.variant in MEASURED_VARIANTS
-    assert tr.measured and all(p50 > 0 for p50 in tr.measured.values())
-    # the winner's measured p50 is the minimum of the table
-    assert tr.best_score == min(tr.measured.values())
-    # and it is never slower than the default-variant run of the SAME config
-    base_cfg = next(c for (c, v) in tr.measured if v == "folded"
-                    and c.astuple() == tr.best.astuple())
-    assert tr.best_score <= tr.measured[(base_cfg, "folded")]
-
-
-def test_plan_cache_variant_memo(rng):
-    """measure_variants races once per (fingerprint, dim-bucket) and
-    memoizes: a same-shape-class rebuild reuses the decision."""
-    from repro.serving.plan_cache import PlanCache
-    g = random_power_law(200, 5.0, seed=22)
-    cache = PlanCache(backend="pallas_interpret", measure_variants=True,
-                      variant_measure_iters=2)
-    e1 = cache.get_or_build(g, arch="gcn", in_dim=16, hidden_dim=16,
-                            num_layers=2)
-    assert cache.variant_selections == 1
-    # different edge values -> exact-level miss, fingerprint + variant hit
-    ev = rng.uniform(0.5, 1.5, g.num_edges).astype(np.float32)
-    e2 = cache.get_or_build(g, arch="gcn", in_dim=16, hidden_dim=16,
-                            num_layers=2, edge_vals=ev)
-    assert cache.variant_selections == 1 and cache.variant_memo_hits == 1
-    assert e2.plan.config.variant == e1.plan.config.variant
-    st = cache.stats()
-    assert st["variant_selections"] == 1 and st["variant_memo_hits"] == 1
+# ------------------------------------------------------ profile labels
 
 
 def test_profile_plan_variant_label():
-    """Satellite: profile_plan gauges carry the gather-path label and the
-    new label values survive the Prometheus escape-lint."""
+    """profile_plan gauges are labelled by schedule alone (the kernel has
+    one gather path) and survive the Prometheus escape-lint."""
     from repro.core.advisor import plan_for
     from repro.obs import MetricsRegistry
     from repro.obs.export import lint_prometheus, to_prometheus_text
@@ -211,8 +150,9 @@ def test_profile_plan_variant_label():
                  registry=reg)
     res = [m for m in reg.snapshot()
            if m["name"] == "kernel_model_residual"]
-    assert res and all(m["labels"]["variant"] == "folded" for m in res)
-    assert all("schedule" in m["labels"] for m in res)
+    assert res and all(set(m["labels"]) == {"schedule"} for m in res)
+    assert ({m["labels"]["schedule"] for m in res}
+            == {"forward"})
     assert lint_prometheus(to_prometheus_text(reg)) == []
 
 
@@ -221,8 +161,8 @@ def test_profile_plan_variant_label():
 
 def test_bench_compare_new_rows_exit_zero(tmp_path, capsys):
     """Rows present in the run but absent from the committed baseline are
-    'new' — informational, NOT gate failures (the variant-rollout path:
-    per-variant rows land before the baseline refresh)."""
+    'new' — informational, NOT gate failures (a benchmark's new rows land
+    before the baseline refresh)."""
     import importlib.util
     from repro.obs.baseline import make_baseline, save_baseline
     path = os.path.join(os.path.dirname(__file__), "..", "tools",
@@ -233,7 +173,7 @@ def test_bench_compare_new_rows_exit_zero(tmp_path, capsys):
 
     base_rows = [{"name": "agg/x/group", "us_per_call": 100.0,
                   "p50_us": 100.0, "p90_us": 105.0}]
-    cur_rows = base_rows + [{"name": "agg_variant/x/bf16_w512/direct",
+    cur_rows = base_rows + [{"name": "agg/x/group_bf16",
                              "us_per_call": 40.0}]
     bench_dir = tmp_path / "bench"
     base_dir = tmp_path / "baselines"
@@ -250,4 +190,4 @@ def test_bench_compare_new_rows_exit_zero(tmp_path, capsys):
                   "--baseline-dir", str(base_dir)])
     out = capsys.readouterr().out
     assert rc == 0
-    assert "new" in out and "agg_variant/x/bf16_w512/direct" in out
+    assert "new" in out and "agg/x/group_bf16" in out
