@@ -1,0 +1,9 @@
+"""compute_ms.serve: mean host milliseconds of the serving engine's
+`compute` span per batch fired in the window."""
+
+
+def read(r):
+    s = getattr(r, "serve", None)
+    if not s or not s["batches"]:
+        return None
+    return s["compute_s"] * 1e3 / s["batches"]

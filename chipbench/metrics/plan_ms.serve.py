@@ -1,0 +1,9 @@
+"""plan_ms.serve: mean host milliseconds of the serving engine's
+`plan` span per batch fired in the window."""
+
+
+def read(r):
+    s = getattr(r, "serve", None)
+    if not s or not s["batches"]:
+        return None
+    return s["plan_s"] * 1e3 / s["batches"]
