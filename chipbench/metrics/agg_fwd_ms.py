@@ -1,0 +1,15 @@
+"""agg_fwd_ms: device milliseconds per train step of the forward
+aggregation launches (`group_aggregate_fwd`) in the traced window."""
+from chipbench.lib.trace import sum_by_name
+
+KERNELS = ("group_aggregate_fwd",)
+
+
+def read(r):
+    win = getattr(r, "window", None)
+    if win is None or not r.trace_steps:
+        return None
+    ns = sum_by_name(win["ops"][0], KERNELS)
+    if ns <= 0:
+        return None
+    return ns * 1e-6 / r.trace_steps

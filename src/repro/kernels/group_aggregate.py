@@ -248,7 +248,7 @@ def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
 @functools.partial(
     jax.jit,
     static_argnames=("gs", "gpt", "ont", "src_win", "dt", "out_rows",
-                     "interpret"),
+                     "interpret", "name"),
 )
 def group_aggregate_pallas(feat_padded: jax.Array,
                            nbrs: jax.Array, edge_val: jax.Array,
@@ -256,7 +256,8 @@ def group_aggregate_pallas(feat_padded: jax.Array,
                            tile_node_block: jax.Array, tile_window: jax.Array,
                            *, gs: int, gpt: int, ont: int, src_win: int,
                            dt: int, out_rows: int,
-                           interpret: bool = False) -> jax.Array:
+                           interpret: bool = False,
+                           name: str = "group_aggregate") -> jax.Array:
     """Run the group-aggregation kernel (one `pl.pallas_call`).
 
     Arguments (T = number of tiles; all arrays device-resident)
@@ -276,6 +277,9 @@ def group_aggregate_pallas(feat_padded: jax.Array,
         maps.
     gs, gpt, ont, src_win, dt, out_rows : static ints; out_rows % ont == 0.
     interpret : run under the Pallas interpreter (CPU).
+    name : the kernel's name, which its launches carry in HLO and the
+        device trace (`repro.kernels.ops` names the forward and backward
+        passes ``group_aggregate_fwd`` / ``group_aggregate_bwd``).
 
     Returns (out_rows, D_pad) float32: out[v] = Σ_slots ev · feat[nbr]
     (0 on node blocks no tile names).
@@ -324,7 +328,7 @@ def group_aggregate_pallas(feat_padded: jax.Array,
             out_shape=jax.ShapeDtypeStruct((out_rows, d_pad), jnp.float32),
             input_output_aliases={6: 0},
             interpret=interpret,
-            name="group_aggregate",
+            name=name,
         )(tile_node_block[t0:t0 + nt], tile_window[t0:t0 + nt],
           feat_padded, nbrs_rows, ev_rows, ln, out)
     return out

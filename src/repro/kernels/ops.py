@@ -265,11 +265,15 @@ def _scatter_edge_values(sched: DeviceSchedule,
 def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
                     dt: int, backend: Backend,
                     edge_values: Optional[jax.Array] = None,
-                    out_dtype=None) -> jax.Array:
+                    out_dtype=None,
+                    kernel_name: str = "group_aggregate_fwd") -> jax.Array:
     """Forward-only aggregation (no AD rule on the Pallas paths).
 
     Accumulates in f32; the result is cast to ``out_dtype`` (None =
-    float32) as the final step — see the module docstring's dtype rules."""
+    float32) as the final step — see the module docstring's dtype rules.
+    ``kernel_name`` names the Pallas launches in the device trace: the
+    backward pass over the transposed schedule passes
+    ``group_aggregate_bwd``."""
     n, d = feat.shape
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     assert n == sched.num_nodes, (n, sched.num_nodes)
@@ -308,7 +312,7 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
         sched.tile_node_block, sched.tile_window,
         gs=sched.gs, gpt=sched.gpt, ont=sched.ont, src_win=sched.src_win,
         dt=dt_eff, out_rows=sched.padded_out_rows,
-        interpret=(backend == "pallas_interpret"),
+        interpret=(backend == "pallas_interpret"), name=kernel_name,
     )
     # node blocks no tile names (bipartite sampled blocks: edge-less rows
     # past num_dst) keep the zeros the kernel's accumulator starts from
@@ -382,7 +386,8 @@ def _aggregate_diff_bwd(statics, statics_bwd, opts, res, g_out):
                                  dt=dt, backend=backend
                                  ).astype(edge_values.dtype)
     feat_bar = _aggregate_impl(g_out, sched_bwd, dt=dt, backend=backend,
-                               edge_values=ev_bwd)
+                               edge_values=ev_bwd,
+                               kernel_name="group_aggregate_bwd")
     return (feat_bar.astype(feat.dtype), ev_bar,
             _zero_cotangents(arrs), _zero_cotangents(arrs_bwd))
 

@@ -245,7 +245,7 @@ def _main_gnn(args) -> int:
     # skips the single-device executor the sharded step never runs
     model = build_gnn(g, cfg, reorder="auto", tune_iters=6, seed=args.seed,
                       with_backward=True if args.shards > 1 else None,
-                      with_executor=args.shards == 1)
+                      with_executor=args.shards == 1, tracer=tracer)
     batch = {"feat": jnp.asarray(model.plan.renumber_features(feat)),
              "labels": jnp.asarray(model.plan.renumber_features(labels))}
 

@@ -27,11 +27,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.advisor import advise
+from repro.core.advisor import analyze, plan_for
 from repro.core.aggregate import PlanExecutor
 from repro.core.plan import Plan
 from repro.graphs.csr import CSRGraph
-from repro.kernels.ops import resolve_backend
+from repro.kernels.ops import resolve_backend, sched_arrays
+from repro.obs.compile import install_compile_listener
+from repro.obs.trace import SpanTracer, process_tracer
 
 Pytree = Any
 
@@ -269,7 +271,8 @@ def build_gnn(g: CSRGraph, cfg: GNNConfig, *, key: Optional[jax.Array] = None,
               reorder: str = "auto", tune_iters: int = 6,
               config=None, seed: int = 0,
               with_backward: Optional[bool] = None,
-              with_executor: bool = True) -> GNNModel:
+              with_executor: bool = True,
+              tracer: Optional[SpanTracer] = None) -> GNNModel:
     """Run the advisor on the graph, build the plan executor + parameters.
 
     with_backward: attach the transposed-schedule backward partition so
@@ -283,26 +286,41 @@ def build_gnn(g: CSRGraph, cfg: GNNConfig, *, key: Optional[jax.Array] = None,
     want the plan + params — sharded training re-plans per shard — avoid
     pinning a never-executed full-graph schedule on device 0.  The
     returned model's ``executor`` is None; don't call its ``logits``.
+
+    tracer: where the spans go (None = the process tracer): ``plan``
+    around the whole call, and under it ``analyze`` (GCN edge values, graph
+    properties, the renumbering decision and any renumbering), ``tune``,
+    ``partition`` (forward, transposed and backward) and ``executor`` (the
+    device schedule's upload, synced, and parameter init).
     """
-    key = key if key is not None else jax.random.PRNGKey(seed)
+    tr = tracer if tracer is not None else process_tracer()
     if with_backward is None:
         with_backward = cfg.backend.startswith("pallas")
-    if cfg.arch == "gcn":
-        g2, vals = gcn_edge_values(g)
-        plan = advise(g2, arch="gcn", in_dim=cfg.in_dim,
-                      hidden_dim=cfg.hidden_dim, num_layers=cfg.num_layers,
-                      edge_vals=vals, reorder=reorder, tune_iters=tune_iters,
-                      config=config, seed=seed, with_backward=with_backward,
-                      feat_dtype=cfg.feat_dtype)
-    else:
-        plan = advise(g, arch=cfg.arch, in_dim=cfg.in_dim,
-                      hidden_dim=cfg.hidden_dim, num_layers=cfg.num_layers,
-                      reorder=reorder, tune_iters=tune_iters, config=config,
-                      seed=seed, with_backward=with_backward,
-                      feat_dtype=cfg.feat_dtype)
-    executor = (PlanExecutor(plan, backend=cfg.backend) if with_executor
-                else None)
-    params = init_gnn_params(cfg, key)
+    with tr.span("plan"):
+        with tr.span("analyze"):
+            if cfg.arch == "gcn":
+                g, vals = gcn_edge_values(g)
+            else:
+                vals = None
+            g_run, vals_run, perm, props = analyze(g, vals, reorder=reorder,
+                                                   seed=seed)
+        plan = plan_for(g_run, arch=cfg.arch, in_dim=cfg.in_dim,
+                        hidden_dim=cfg.hidden_dim, num_layers=cfg.num_layers,
+                        edge_vals=vals_run, config=config,
+                        tune_iters=tune_iters, seed=seed, props=props,
+                        with_backward=with_backward,
+                        feat_dtype=cfg.feat_dtype, tracer=tr)
+        plan.perm = perm
+        with tr.span("executor", block=True) as sp:
+            executor = (PlanExecutor(plan, backend=cfg.backend)
+                        if with_executor else None)
+            params = init_gnn_params(
+                cfg, key if key is not None else jax.random.PRNGKey(seed))
+            if executor is None:
+                sp.sync(params)
+            else:
+                sp.sync((params, sched_arrays(executor.sched),
+                         sched_arrays(executor.sched_bwd)))
     return GNNModel(cfg=cfg, plan=plan, executor=executor, params=params)
 
 
@@ -325,6 +343,10 @@ def make_gnn_train_step(model: GNNModel, opt, *, jit: bool = True):
     configured backend — on "pallas"/"pallas_interpret" the backward pass is
     the transposed-schedule kernel, so the plan must carry
     ``partition_bwd`` (`build_gnn` attaches it for Pallas backends).
+
+    The jitted step is named ``gnn_train_step``; building it installs the
+    process's compile listener (`repro.obs.compile`), which times its
+    trace, lowering and backend compile and counts its cache hits.
     """
     from repro.optim.adamw import adamw_update
 
@@ -334,7 +356,9 @@ def make_gnn_train_step(model: GNNModel, opt, *, jit: bool = True):
             "training on a Pallas backend needs a backward schedule: "
             "build the model with with_backward=True")
 
-    def step_fn(state, batch):
+    # the name JAX's compile stages carry (`repro.obs.compile` labels its
+    # spans and counters with it): stable, and distinct from other steps
+    def gnn_train_step(state, batch):
         params, opt_state = state
         (loss, metrics), grads = jax.value_and_grad(
             model.loss, has_aux=True)(params, batch["feat"], batch["labels"],
@@ -342,7 +366,10 @@ def make_gnn_train_step(model: GNNModel, opt, *, jit: bool = True):
         params, opt_state, om = adamw_update(opt, grads, opt_state, params)
         return (params, opt_state), {**metrics, **om}
 
-    return jax.jit(step_fn) if jit else step_fn
+    if not jit:
+        return gnn_train_step
+    install_compile_listener()
+    return jax.jit(gnn_train_step)
 
 
 def init_gnn_params(cfg: GNNConfig, key: jax.Array) -> Pytree:

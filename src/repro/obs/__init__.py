@@ -3,27 +3,28 @@
 One `MetricsRegistry` threaded through the serving engine, plan cache,
 sampled loader, trainer, sharded executors and benchmarks; a `SpanTracer`
 for nested wall-clock spans with honest-under-async-dispatch close
-semantics; JSON / Prometheus exporters that render the same registry; a
-Chrome/Perfetto trace exporter over the tracer's records; the on-device
-measurement harness (`measure` / `profile_plan`) that turns the analytical
-`KernelModel` into a measured one; and the persisted perf-baseline layer
-(`repro.obs.baseline`) behind `tools/bench_compare.py`'s CI regression
-gate.
+semantics, each also a profiler annotation on the device trace's clock;
+the process tracer (`process_tracer`) that the planner and the compile
+listener (`repro.obs.compile`) record in; JSON / Prometheus exporters that
+render the same registry; a Chrome/Perfetto trace exporter over the
+tracer's records; the wall-clock timing harness (`measure`); and the
+persisted perf-baseline layer (`repro.obs.baseline`) behind
+`tools/bench_compare.py`'s CI regression gate.
 """
 from repro.obs.baseline import (BASELINE_SCHEMA, append_history,
                                 compare_rows, load_baseline, make_baseline,
                                 row_tolerance, save_baseline,
                                 validate_baseline)
 from repro.obs.chrome_trace import chrome_trace_doc, write_chrome_trace
+from repro.obs.compile import install_compile_listener
 from repro.obs.context import run_context
 from repro.obs.export import (lint_prometheus, registry_to_json,
                               to_prometheus_text, unescape_label_value,
                               write_metrics)
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                exponential_bounds, pow2_bounds)
-from repro.obs.profile import (Measurement, ProfileReport, ScheduleProfile,
-                               measure, profile_plan)
-from repro.obs.trace import Span, SpanTracer
+from repro.obs.profile import Measurement, measure
+from repro.obs.trace import Span, SpanTracer, process_tracer
 
 __all__ = [
     "BASELINE_SCHEMA",
@@ -32,20 +33,19 @@ __all__ = [
     "Histogram",
     "Measurement",
     "MetricsRegistry",
-    "ProfileReport",
-    "ScheduleProfile",
     "Span",
     "SpanTracer",
     "append_history",
     "chrome_trace_doc",
     "compare_rows",
     "exponential_bounds",
+    "install_compile_listener",
     "lint_prometheus",
     "load_baseline",
     "make_baseline",
     "measure",
     "pow2_bounds",
-    "profile_plan",
+    "process_tracer",
     "registry_to_json",
     "row_tolerance",
     "run_context",

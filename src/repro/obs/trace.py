@@ -20,18 +20,43 @@ imported; a no-op when jax is absent), so the recorded duration covers the
 device work.  ``SpanTracer(block_until_ready=True)`` makes that the
 default for every span that registered a sync value; ``span(...,
 block=False)`` opts a single span out.
+
+**On the profiler's clock**: each span also enters
+``jax.profiler.TraceAnnotation("repro/<path>")`` (when jax is importable),
+so while a profiler session is active the span lands on the trace's
+``/host:CPU`` plane beside the device ops, on the same clock, and a device
+gap can be put down to what the host was doing in it.
+
+**The process tracer**: `process_tracer()` is one tracer over one
+registry per process.  Code that is handed no tracer (the planner, the
+compile listener of `repro.obs.compile`) records there; components that
+are handed one (the launch drivers, `Trainer`, `ServingEngine`) keep
+using theirs.
 """
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Optional
 
 from repro.obs.metrics import MetricsRegistry
 
-__all__ = ["Span", "SpanTracer"]
+__all__ = ["Span", "SpanTracer", "process_tracer"]
+
+ANNOTATION_PREFIX = "repro/"
+
+
+def _annotation(path: str):
+    """The span's profiler annotation.  A no-op until something else has
+    imported jax: no profiler session can be open before then, and the
+    registry stays dependency-free."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return nullcontext()
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + path)
 
 
 class Span:
@@ -79,6 +104,9 @@ class SpanTracer:
         self._records: deque = deque(maxlen=max_spans)
         self._local = threading.local()
         self._t0 = time.perf_counter()
+        # the same epoch on the wall clock, for records timed elsewhere
+        # (`add_record`: JAX's compile events carry time.time() stamps)
+        self._t0_wall = time.time()
         # compact per-tracer thread ids: the Chrome-trace exporter wants
         # small stable track numbers, not 64-bit thread idents
         self._tids: dict = {}
@@ -102,36 +130,63 @@ class SpanTracer:
     def span(self, name: str, *, block: Optional[bool] = None, **attrs):
         stack = self._stack()
         path = "/".join([s.path for s in stack[-1:]] + [name])
-        sp = Span(path, time.perf_counter(), attrs)
-        stack.append(sp)
-        try:
-            yield sp
-        finally:
-            stack.pop()
-            if (self.block_until_ready if block is None else block) \
-                    and sp._sync is not None:
-                try:
-                    import jax
-                    jax.block_until_ready(sp._sync)
-                except ImportError:        # registry stays dependency-free
-                    pass
-            sp.duration_s = time.perf_counter() - sp.t_start
-            self.registry.histogram(
-                "span_seconds", labels={"span": path},
-                desc="wall-clock span durations (repro.obs.trace)",
-            ).observe(sp.duration_s)
-            # tid + thread name ride in every record: the Chrome-trace
-            # exporter needs a per-thread track, the JSON exporter's
-            # ``spans`` section gets attributable multi-thread traces
-            self._records.append({
-                "span": path,
-                "t_rel_s": round(sp.t_start - self._t0, 6),
-                "duration_s": round(sp.duration_s, 6),
-                "tid": self._tid(),
-                "thread": threading.current_thread().name,
-                **({"attrs": dict(sp.attrs)} if sp.attrs else {}),
-            })
+        with _annotation(path):
+            sp = Span(path, time.perf_counter(), attrs)
+            stack.append(sp)
+            try:
+                yield sp
+            finally:
+                stack.pop()
+                if (self.block_until_ready if block is None else block) \
+                        and sp._sync is not None:
+                    try:
+                        import jax
+                        jax.block_until_ready(sp._sync)
+                    except ImportError:    # registry stays dependency-free
+                        pass
+                sp.duration_s = time.perf_counter() - sp.t_start
+                self.registry.histogram(
+                    "span_seconds", labels={"span": path},
+                    desc="wall-clock span durations (repro.obs.trace)",
+                ).observe(sp.duration_s)
+                self._append(path, sp.t_start - self._t0, sp.duration_s,
+                             sp.attrs)
+
+    def add_record(self, path: str, start_s: float, end_s: float,
+                   **attrs) -> None:
+        """Append a ring-buffer record for work timed elsewhere, from its
+        wall-clock (``time.time()``) start and end; no histogram."""
+        self._append(path, start_s - self._t0_wall, end_s - start_s, attrs)
+
+    def _append(self, path: str, t_rel_s: float, duration_s: float,
+                attrs: dict) -> None:
+        # tid + thread name ride in every record: the Chrome-trace
+        # exporter needs a per-thread track, the JSON exporter's
+        # ``spans`` section gets attributable multi-thread traces
+        self._records.append({
+            "span": path,
+            "t_rel_s": round(t_rel_s, 6),
+            "duration_s": round(duration_s, 6),
+            "tid": self._tid(),
+            "thread": threading.current_thread().name,
+            **({"attrs": dict(attrs)} if attrs else {}),
+        })
 
     def records(self) -> list:
         """Retained span records, oldest first (bounded by max_spans)."""
         return list(self._records)
+
+
+_PROCESS: Optional[SpanTracer] = None
+_PROCESS_LOCK = threading.Lock()
+
+
+def process_tracer() -> SpanTracer:
+    """The process-wide tracer over the process-wide registry, made on
+    first use.  Its ring buffer is larger than a component's: set-up
+    (planning, one record per JAX compile stage) fills it in bursts."""
+    global _PROCESS
+    with _PROCESS_LOCK:
+        if _PROCESS is None:
+            _PROCESS = SpanTracer(MetricsRegistry(), max_spans=4096)
+        return _PROCESS

@@ -1,8 +1,7 @@
 """Profiling harness, Chrome-trace export, perf baselines + CI gate.
 
 Covers the observability tentpole: `measure` calibration and stats,
-`profile_plan` per-schedule attribution (sum-to-total identity) and
-registry side-effects, the Chrome/Perfetto exporter's event structure,
+the Chrome/Perfetto exporter's event structure,
 `repro.obs.baseline` verdicts, and the `tools/bench_compare.py` CLI
 (clean / regressed / missing-row / schema-mismatch exits).
 """
@@ -17,9 +16,8 @@ import numpy as np
 import pytest
 
 from repro.obs import (MetricsRegistry, SpanTracer, chrome_trace_doc,
-                       compare_rows, make_baseline, measure, profile_plan,
-                       row_tolerance, save_baseline, validate_baseline,
-                       write_chrome_trace)
+                       compare_rows, make_baseline, measure, row_tolerance,
+                       save_baseline, validate_baseline, write_chrome_trace)
 from repro.obs.profile import Measurement
 
 
@@ -80,58 +78,6 @@ def test_measure_calibrated_warmup_absorbs_slow_first_call():
 def test_measure_rejects_zero_iters():
     with pytest.raises(ValueError):
         measure(lambda: None, iters=0)
-
-
-# ----------------------------------------------------------- profile_plan
-
-@pytest.fixture(scope="module")
-def profiled_plan():
-    from repro.core.advisor import plan_for
-    from repro.graphs.csr import random_power_law
-
-    g = random_power_law(300, 5.0, seed=0)
-    return plan_for(g, in_dim=16, hidden_dim=16, tune_iters=2,
-                    with_backward=True)
-
-
-def test_profile_plan_attribution_sums_to_total(profiled_plan):
-    reg = MetricsRegistry()
-    rep = profile_plan(profiled_plan, dim=16, iters=5, registry=reg)
-    names = [s.schedule for s in rep.schedules]
-    assert names == ["forward", "backward"]
-    att = rep.attribution()
-    assert set(att) == {"forward", "backward"}
-    assert all(v > 0 for v in att.values())
-    # the total runs the same jitted callables back to back, so the
-    # per-schedule sum matches it up to CPU timing noise
-    assert rep.attribution_error() < 0.5
-    # registry side-effects: residual gauges labelled per schedule
-    snap = {(m["name"], m["labels"].get("schedule")): m
-            for m in reg.snapshot()}
-    for sched in ("forward", "backward"):
-        assert ("kernel_model_residual", sched) in snap
-        assert snap[("kernel_model_residual", sched)]["value"] > 0
-        assert ("profile_achieved_bytes_per_s", sched) in snap
-    hist = [m for m in reg.snapshot()
-            if m["name"] == "profile_schedule_seconds"]
-    assert len(hist) == 2 and all(h["count"] == 5 for h in hist)
-
-
-def test_profile_plan_shard_rows_excluded_from_attribution(profiled_plan):
-    rep = profile_plan(profiled_plan, dim=16, iters=3, shards=2)
-    names = [s.schedule for s in rep.schedules]
-    assert "shard0/forward" in names and "shard1/forward" in names
-    assert set(rep.attribution()) == {"forward", "backward"}
-    rows = rep.to_rows()
-    assert len(rows) == 4
-    for r in rows:
-        assert r["residual"] > 0 and r["p50_us"] > 0
-
-
-def test_profile_plan_label_prefix(profiled_plan):
-    rep = profile_plan(profiled_plan, dim=16, iters=2, label="b64/")
-    assert [s.schedule for s in rep.schedules] == ["b64/forward",
-                                                  "b64/backward"]
 
 
 # ----------------------------------------------------------- chrome trace

@@ -122,3 +122,34 @@ def test_largest_feasible_config_compiles(one_chip, dtype, kernel, measure):
     cfg = _largest_feasible(dtype, measure)
     compile_ = _compile_fwd if kernel == "aggregate" else _compile_edge_grad
     assert "tpu_custom_call" in compile_(one_chip, cfg, cfg.dt, dtype)
+
+
+def test_forward_and_backward_launches_are_named_by_direction(one_chip):
+    """The forward pass and the backward pass over the transposed schedule
+    compile to launches named ``group_aggregate_fwd`` and
+    ``group_aggregate_bwd``: the device trace tells them apart by name."""
+    from repro.core.advisor import plan_for
+    from repro.graphs.csr import random_power_law
+    from repro.kernels.ops import (SchedView, aggregate, sched_arrays,
+                                   sched_statics)
+
+    plan = plan_for(random_power_law(600, 6.0, seed=5), in_dim=16,
+                    config=_BASE, with_backward=True)
+    fwd, bwd = plan.sched(), plan.sched_bwd()
+    st, st_bwd = sched_statics(fwd), sched_statics(bwd)
+
+    def loss(feat, arrs, arrs_bwd):
+        return aggregate(feat, SchedView(arrs, st), backend="pallas",
+                         sched_bwd=SchedView(arrs_bwd, st_bwd)).sum()
+
+    def abstract(tree):
+        return jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                            tree)
+
+    # the value too, or the forward launch is dead code in a gradient
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        _shape(one_chip, (600, 16), "float32"),
+        abstract(sched_arrays(fwd)), abstract(sched_arrays(bwd)),
+    ).compile().as_text()
+    assert "%group_aggregate_fwd" in text and "%group_aggregate_bwd" in text
+    assert "%group_aggregate." not in text

@@ -132,30 +132,6 @@ def test_plan_load_ignores_retired_variant_field(tmp_path):
                                atol=1e-6, rtol=1e-6)
 
 
-# ------------------------------------------------------ profile labels
-
-
-def test_profile_plan_variant_label():
-    """profile_plan gauges are labelled by schedule alone (the kernel has
-    one gather path) and survive the Prometheus escape-lint."""
-    from repro.core.advisor import plan_for
-    from repro.obs import MetricsRegistry
-    from repro.obs.export import lint_prometheus, to_prometheus_text
-    from repro.obs.profile import profile_plan
-    g = random_power_law(150, 4.0, seed=23)
-    cfg = AggConfig(gs=8, gpt=8, ont=8, src_win=64, dt=16)
-    plan = plan_for(g, arch="gcn", in_dim=16, config=cfg)
-    reg = MetricsRegistry()
-    profile_plan(plan, backend="xla", dim=16, iters=2, warmup=1,
-                 registry=reg)
-    res = [m for m in reg.snapshot()
-           if m["name"] == "kernel_model_residual"]
-    assert res and all(set(m["labels"]) == {"schedule"} for m in res)
-    assert ({m["labels"]["schedule"] for m in res}
-            == {"forward"})
-    assert lint_prometheus(to_prometheus_text(reg)) == []
-
-
 # ---------------------------------------------- bench_compare: new rows
 
 
