@@ -7,11 +7,12 @@
 returns an executable `Plan` with everything the runtime needs (the
 `repro.core.plan` IR — `AggregationPlan` is its historical alias).
 
-Planning records spans in the tracer it is handed, else in the process
-tracer (`repro.obs.process_tracer`): ``analyze`` (graph properties and the
-renumbering), ``tune`` and ``partition`` (forward, transposed and
-backward), each nested under whatever span the caller has open; and it
-counts each plan's forward tiles, padded slots and real edges
+Planning records spans in the process tracer
+(`repro.obs.process_tracer`), or in the tracer `plan_for` is handed:
+``analyze`` (graph properties and the renumbering), ``tune`` and
+``partition`` (forward, transposed and backward), each nested under
+whatever span is open on that tracer; and it counts each plan's forward
+tiles, padded slots and real edges in the same tracer's registry
 (``plan_tiles_total``, ``plan_padded_slots_total``, ``plan_edges_total``).
 """
 from __future__ import annotations
@@ -74,19 +75,17 @@ def advise(g: CSRGraph, *, arch: str = "gcn", in_dim: int = 128,
            tune_mode: str = "model", tune_iters: int = 12,
            config: Optional[AggConfig] = None, seed: int = 0,
            with_backward: bool = False,
-           feat_dtype: Optional[str] = None,
-           tracer: Optional[SpanTracer] = None) -> AggregationPlan:
+           feat_dtype: Optional[str] = None) -> AggregationPlan:
     """Run the full GNNAdvisor decision loop for one input: `analyze`
     (with its renumbering), then `plan_for` on the result."""
-    tr = tracer if tracer is not None else process_tracer()
-    with tr.span("analyze"):
+    with process_tracer().span("analyze"):
         g_run, vals_run, perm, props = analyze(g, edge_vals, reorder=reorder,
                                                seed=seed)
     plan = plan_for(g_run, arch=arch, in_dim=in_dim, hidden_dim=hidden_dim,
                     num_layers=num_layers, edge_vals=vals_run, config=config,
                     tune_mode=tune_mode, tune_iters=tune_iters, seed=seed,
                     props=props, with_backward=with_backward,
-                    feat_dtype=feat_dtype, tracer=tr)
+                    feat_dtype=feat_dtype)
     plan.perm = perm
     return plan
 

@@ -49,6 +49,16 @@ import time
 GNN_ARCHS = ("gcn", "gin", "gat")
 
 
+def _process_tracer():
+    """The run's tracer: the process tracer, with JAX's compile listener
+    on it.  The planner's spans and each function's compile stages and
+    cache lookups then land in the registry the run exports
+    (docs/observability.md)."""
+    from repro.obs import install_compile_listener, process_tracer
+    install_compile_listener()
+    return process_tracer()
+
+
 def _write_metrics(args, registry, tracer=None) -> None:
     if not args.metrics_out:
         return
@@ -60,7 +70,8 @@ def _write_metrics(args, registry, tracer=None) -> None:
 
 
 def _write_trace(args, tracer) -> None:
-    """--trace-out: the Trainer's span records as a Chrome/Perfetto trace
+    """--trace-out: the run's span records (the Trainer's steps, the
+    planner's spans, JAX's compile stages) as a Chrome/Perfetto trace
     (open in ui.perfetto.dev or chrome://tracing —
     docs/observability.md)."""
     if not getattr(args, "trace_out", None):
@@ -124,14 +135,13 @@ def _main_gnn_sampled(args) -> int:
     from repro.models.gnn import (GNNConfig, init_gnn_params,
                                   structural_labels)
     from repro.optim.adamw import AdamWConfig, adamw_init, cosine_schedule
-    from repro.obs import MetricsRegistry, SpanTracer
     from repro.runtime.trainer import (FailureInjector, Trainer,
                                        TrainerConfig)
     from repro.sampling import (LoaderConfig, SampledLoader,
                                 SampledTrainStep, ShardedSampledTrainStep)
 
-    registry = MetricsRegistry()
-    tracer = SpanTracer(registry)
+    tracer = _process_tracer()
+    registry = tracer.registry
     t0 = time.time()
     g, spec, feat = make_dataset(args.dataset, scale=args.scale,
                                  max_nodes=args.max_nodes, seed=args.seed,
@@ -221,13 +231,12 @@ def _main_gnn(args) -> int:
     from repro.graphs.datasets import make_dataset
     from repro.models.gnn import (GNNConfig, build_gnn, make_gnn_train_step,
                                   planted_labels)
-    from repro.obs import MetricsRegistry, SpanTracer
     from repro.optim.adamw import AdamWConfig, adamw_init, cosine_schedule
     from repro.runtime.trainer import (FailureInjector, Trainer,
                                        TrainerConfig)
 
-    registry = MetricsRegistry()
-    tracer = SpanTracer(registry)
+    tracer = _process_tracer()
+    registry = tracer.registry
     max_nodes = args.max_nodes if args.max_nodes is not None else 2000
     g, spec, feat = make_dataset(args.dataset, scale=args.scale,
                                  max_nodes=max_nodes, seed=args.seed)
@@ -245,7 +254,7 @@ def _main_gnn(args) -> int:
     # skips the single-device executor the sharded step never runs
     model = build_gnn(g, cfg, reorder="auto", tune_iters=6, seed=args.seed,
                       with_backward=True if args.shards > 1 else None,
-                      with_executor=args.shards == 1, tracer=tracer)
+                      with_executor=args.shards == 1)
     batch = {"feat": jnp.asarray(model.plan.renumber_features(feat)),
              "labels": jnp.asarray(model.plan.renumber_features(labels))}
 
@@ -377,12 +386,11 @@ def main(argv=None) -> int:
     from repro.data import PipelineConfig, TokenPipeline, make_lm_batch
     from repro.models.lm import make_train_step
     from repro.nn.transformer import lm_init
-    from repro.obs import MetricsRegistry, SpanTracer
     from repro.optim.adamw import AdamWConfig, adamw_init, cosine_schedule
     from repro.runtime.trainer import (FailureInjector, Trainer, TrainerConfig)
 
-    registry = MetricsRegistry()
-    tracer = SpanTracer(registry)
+    tracer = _process_tracer()
+    registry = tracer.registry
     arch = get_arch(args.arch)
     cfg = arch.reduced() if args.reduced else arch.full()
     params, specs = lm_init(cfg, jax.random.PRNGKey(args.seed))

@@ -33,7 +33,7 @@ from repro.core.plan import Plan
 from repro.graphs.csr import CSRGraph
 from repro.kernels.ops import resolve_backend, sched_arrays
 from repro.obs.compile import install_compile_listener
-from repro.obs.trace import SpanTracer, process_tracer
+from repro.obs.trace import process_tracer
 
 Pytree = Any
 
@@ -271,8 +271,7 @@ def build_gnn(g: CSRGraph, cfg: GNNConfig, *, key: Optional[jax.Array] = None,
               reorder: str = "auto", tune_iters: int = 6,
               config=None, seed: int = 0,
               with_backward: Optional[bool] = None,
-              with_executor: bool = True,
-              tracer: Optional[SpanTracer] = None) -> GNNModel:
+              with_executor: bool = True) -> GNNModel:
     """Run the advisor on the graph, build the plan executor + parameters.
 
     with_backward: attach the transposed-schedule backward partition so
@@ -287,13 +286,13 @@ def build_gnn(g: CSRGraph, cfg: GNNConfig, *, key: Optional[jax.Array] = None,
     pinning a never-executed full-graph schedule on device 0.  The
     returned model's ``executor`` is None; don't call its ``logits``.
 
-    tracer: where the spans go (None = the process tracer): ``plan``
+    Spans go to the process tracer (`repro.obs.process_tracer`): ``plan``
     around the whole call, and under it ``analyze`` (GCN edge values, graph
     properties, the renumbering decision and any renumbering), ``tune``,
     ``partition`` (forward, transposed and backward) and ``executor`` (the
     device schedule's upload, synced, and parameter init).
     """
-    tr = tracer if tracer is not None else process_tracer()
+    tr = process_tracer()
     if with_backward is None:
         with_backward = cfg.backend.startswith("pallas")
     with tr.span("plan"):
@@ -309,7 +308,7 @@ def build_gnn(g: CSRGraph, cfg: GNNConfig, *, key: Optional[jax.Array] = None,
                         edge_vals=vals_run, config=config,
                         tune_iters=tune_iters, seed=seed, props=props,
                         with_backward=with_backward,
-                        feat_dtype=cfg.feat_dtype, tracer=tr)
+                        feat_dtype=cfg.feat_dtype)
         plan.perm = perm
         with tr.span("executor", block=True) as sp:
             executor = (PlanExecutor(plan, backend=cfg.backend)

@@ -232,7 +232,8 @@ class ServingEngine:
                 ent = self.cache.get_or_build(
                     sub, arch=cfg.arch, in_dim=cfg.in_dim,
                     hidden_dim=cfg.hidden_dim, num_layers=cfg.num_layers,
-                    edge_vals=vals, epoch=self.graph_epoch)
+                    edge_vals=vals, epoch=self.graph_epoch,
+                    tracer=self.trace)
                 if ent.apply_fn is None:
                     ent.apply_fn = self._make_apply(ent)
             feat_sub = np.zeros((sub.num_nodes, cfg.in_dim), np.float32)

@@ -37,7 +37,7 @@ from repro.core.partition import pad_partition_tiles
 from repro.core.plan import Plan
 from repro.graphs.csr import CSRGraph
 from repro.kernels.ops import resolve_backend
-from repro.obs import MetricsRegistry
+from repro.obs import MetricsRegistry, SpanTracer
 
 __all__ = [
     "CacheEntry",
@@ -242,16 +242,22 @@ class PlanCache:
     def get_or_build(self, g: CSRGraph, *, arch: str, in_dim: int,
                      hidden_dim: int, num_layers: int,
                      edge_vals: Optional[np.ndarray] = None,
-                     epoch: Optional[int] = None) -> CacheEntry:
+                     epoch: Optional[int] = None,
+                     tracer: Optional[SpanTracer] = None) -> CacheEntry:
+        """The ready plan for ``g``, built on a miss.  A build's planner
+        spans nest under the span open on ``tracer`` (the caller's; None
+        = the process tracer)."""
         with self._lock:
             return self._get_or_build_locked(
                 g, arch=arch, in_dim=in_dim, hidden_dim=hidden_dim,
-                num_layers=num_layers, edge_vals=edge_vals, epoch=epoch)
+                num_layers=num_layers, edge_vals=edge_vals, epoch=epoch,
+                tracer=tracer)
 
     def _get_or_build_locked(self, g: CSRGraph, *, arch: str, in_dim: int,
                              hidden_dim: int, num_layers: int,
                              edge_vals: Optional[np.ndarray] = None,
-                             epoch: Optional[int] = None
+                             epoch: Optional[int] = None,
+                             tracer: Optional[SpanTracer] = None
                              ) -> CacheEntry:
         arch_key = (arch, in_dim, hidden_dim, num_layers,
                     self.feat_dtype) + (
@@ -293,7 +299,7 @@ class PlanCache:
                         config=config, tune_mode=self.tune_mode,
                         tune_iters=self.tune_iters, seed=self.seed,
                         with_backward=self.with_backward,
-                        feat_dtype=self.feat_dtype)
+                        feat_dtype=self.feat_dtype, tracer=tracer)
         if config is None:
             self._set_config(fp, plan.config)
         if plan.tuner is not None:

@@ -84,6 +84,29 @@ def test_plan_for_alone_nests_under_the_open_span(small_graph):
     assert tr.registry.get("plan_tiles_total").value > 0
 
 
+def test_serving_plan_cache_spans_nest_under_serve_batch(small_graph):
+    import numpy as np
+
+    from repro.serving import ServingConfig, ServingEngine
+
+    cfg = GNNConfig(arch="gcn", in_dim=6, hidden_dim=6, num_classes=3,
+                    num_layers=2, backend="xla")
+    feat = np.ones((small_graph.num_nodes, 6), np.float32)
+    eng = ServingEngine(small_graph, feat, cfg,
+                        serving=ServingConfig(max_batch=4, tune_iters=2))
+    process_before = _sums(process_tracer().registry, "span_seconds", "span")
+    eng.serve_batch([1, 2, 3])               # a miss: tuned and partitioned
+    eng.serve_batch([1, 2, 3])               # an exact hit: no planning
+    got = _sums(eng.registry, "span_seconds", "span")
+    for child in ("analyze", "tune", "partition"):
+        assert got[f"serve_batch/plan/{child}"][0] == 1, got
+    assert got["serve_batch/plan"][0] == 2
+    assert eng.registry.get("plan_tiles_total").value > 0
+    # the engine's planning stays out of the process tracer
+    assert _sums(process_tracer().registry, "span_seconds",
+                 "span") == process_before
+
+
 def test_span_lands_on_the_profiler_host_plane(tmp_path):
     tr = SpanTracer(MetricsRegistry())
     with jax.profiler.trace(str(tmp_path)):
@@ -176,3 +199,39 @@ def test_train_step_compiles_under_its_stable_name(small_graph):
     assert _value(reg, "jit_compiles_total", fun="gnn_train_step") == n0 + 1
     for stage in ("trace", "lower", "backend"):
         assert reg.get(f"jit_{stage}_seconds", {"fun": "gnn_train_step"})
+
+
+def test_train_driver_exports_setup_metrics(tmp_path):
+    """`--metrics-out` of the GNN training driver carries the planner's
+    spans and the train step's compile stages and cache lookups, and
+    `--trace-out` the compile records."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    metrics, trace = tmp_path / "metrics.json", tmp_path / "trace.json"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
+           "PYTHONPATH": os.pathsep.join(
+               [os.path.join(os.path.dirname(__file__), "..", "src")]
+               + [p for p in [os.environ.get("PYTHONPATH")] if p])}
+    subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--arch", "gcn",
+         "--dataset", "cora", "--max-nodes", "300", "--steps", "2",
+         "--hidden-dim", "8", "--backend", "xla",
+         "--ckpt-dir", str(tmp_path / "ckpt"),
+         "--metrics-out", str(metrics), "--trace-out", str(trace)],
+        env=env, check=True, capture_output=True, timeout=240)
+    rows = json.loads(metrics.read_text())["metrics"]
+    have = {(m["name"], tuple(sorted(m["labels"].items()))) for m in rows}
+    step = (("fun", "gnn_train_step"),)
+    for name in ("jit_compiles_total", "compile_cache_misses_total",
+                 "jit_trace_seconds", "jit_lower_seconds",
+                 "jit_backend_seconds"):
+        assert (name, step) in have, name
+    for child in ("", "/analyze", "/tune", "/partition", "/executor"):
+        assert ("span_seconds", (("span", "plan" + child),)) in have
+    events = json.loads(trace.read_text())["traceEvents"]
+    assert {"jit/trace", "jit/lower", "jit/backend"} <= {
+        e["name"] for e in events}
