@@ -13,7 +13,9 @@ Planning records spans in the process tracer
 ``partition`` (forward, transposed and backward), each nested under
 whatever span is open on that tracer; and it counts each plan's forward
 tiles, padded slots and real edges in the same tracer's registry
-(``plan_tiles_total``, ``plan_padded_slots_total``, ``plan_edges_total``).
+(``plan_tiles_total``, ``plan_padded_slots_total``, ``plan_edges_total``),
+with the node-block height of the newest plan (gauge
+``plan_node_block_rows``).
 """
 from __future__ import annotations
 
@@ -189,10 +191,14 @@ def plan_for(g: CSRGraph, *, arch: str = "gcn", in_dim: int = 128,
 
 def _count_plan(registry, part: GroupPartition) -> None:
     """The forward plan's work counts (`padded_slots_per_edge` is their
-    ratio): tiles, slots the kernel visits, and real edges."""
+    ratio): tiles, slots the kernel visits, and real edges; and the node
+    block height it runs at."""
     for name, n in (("plan_tiles_total", part.num_tiles),
                     ("plan_padded_slots_total",
                      part.num_tiles * part.gpt * part.gs),
                     ("plan_edges_total", part.num_edges)):
         registry.counter(name, desc="forward plan counts "
                                     "(repro.core.advisor)").inc(int(n))
+    registry.gauge("plan_node_block_rows",
+                   desc="node-block height (ont) of the newest plan "
+                        "(repro.core.advisor)").set(part.ont)

@@ -65,13 +65,16 @@ def feat_dtype_align(feat_dtype: str) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class AggConfig:
-    """The tunable hyper-parameters (paper: gs, tpb, dw; +TPU window)."""
+    """The tunable hyper-parameters (paper: gs, tpb, dw; +TPU window and
+    node-block height).  The tuner searches every field but ``feat_dtype``
+    (`core.tuner.SEARCH_SPACE`); the defaults are what a pinned config
+    leaves unset."""
 
     gs: int = 16          # group size (paper gs)
     gpt: int = 16         # groups per tile (paper tpb analogue)
     dt: int = 128         # dim-tile width (paper dw analogue)
     src_win: int = 512    # feature-window rows (TPU shared-memory analogue)
-    ont: int = 8          # output rows per block (structural, sublane-aligned)
+    ont: int = 8          # output rows per node block (paper's leader block)
     feat_dtype: str = "float32"   # feature/activation dtype policy
 
     def astuple(self):

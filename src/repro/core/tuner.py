@@ -12,8 +12,8 @@ Implements the paper's three-step strategy:
   3. *Evolutionary optimization*: population → keep elite → crossover +
      mutation, 10–15 iterations (paper: "10-15 iterations … enough").
 
-The search space is the TPU knob set (gs, gpt, dt, src_win) constrained by
-the Eq. 3/4 feasibility re-derivations in `core.model`.
+The search space is the TPU knob set (gs, gpt, dt, src_win, ont)
+constrained by the Eq. 3/4 feasibility re-derivations in `core.model`.
 """
 from __future__ import annotations
 
@@ -38,6 +38,13 @@ SEARCH_SPACE = {
     "gpt": [8, 16, 32, 64, 128],
     "dt": [64, 128, 256, 512],
     "src_win": [128, 256, 512, 1024, 2048],
+    # node-block height (the paper's leader-node block, §5.2): tiles follow
+    # (node block, window) buckets, so taller blocks pack more edges into
+    # each tile.  Capped at 128: one MXU row block keeps the (ont, gpt)
+    # scatter one-hot a single pass; 128 -> 256 removes few more tiles while
+    # the scatter's rows double; and a mutation repartitions a whole block
+    # per dirty row (`Plan.apply_delta`).
+    "ont": [8, 16, 32, 64, 128],
 }
 
 
@@ -51,21 +58,15 @@ class TunerResult:
 
 def _random_config(rng: np.random.Generator,
                    base: AggConfig = AggConfig()) -> AggConfig:
-    # non-searched fields (ont, feat_dtype) ride along from `base`
+    # the non-searched field (feat_dtype) rides along from `base`
     return dataclasses.replace(
-        base,
-        gs=int(rng.choice(SEARCH_SPACE["gs"])),
-        gpt=int(rng.choice(SEARCH_SPACE["gpt"])),
-        dt=int(rng.choice(SEARCH_SPACE["dt"])),
-        src_win=int(rng.choice(SEARCH_SPACE["src_win"])),
-    )
+        base, **{k: int(rng.choice(v)) for k, v in SEARCH_SPACE.items()})
 
 
 def _crossover(a: AggConfig, b: AggConfig, rng: np.random.Generator) -> AggConfig:
-    pick = lambda x, y: x if rng.random() < 0.5 else y
     return dataclasses.replace(
-        a, gs=pick(a.gs, b.gs), gpt=pick(a.gpt, b.gpt),
-        dt=pick(a.dt, b.dt), src_win=pick(a.src_win, b.src_win))
+        a, **{k: getattr(a if rng.random() < 0.5 else b, k)
+              for k in SEARCH_SPACE})
 
 
 def _mutate(c: AggConfig, rng: np.random.Generator, p: float = 0.25) -> AggConfig:
@@ -93,7 +94,7 @@ def evolve(score_fn: Callable[[AggConfig], float], *, pop: int = 16,
     repeats into dict hits.  ``TunerResult.evaluations`` therefore counts
     UNIQUE score-function evaluations (the tuner's true cost).
 
-    ``base`` seeds the non-searched config fields (ont, feat_dtype); ``infeasibility_fn`` (reason string or None = feasible)
+    ``base`` seeds the non-searched config field (feat_dtype); ``infeasibility_fn`` (reason string or None = feasible)
     overrides the default `config_infeasibility` — e.g. one bound to a
     small-VMEM `TPUSpec` or a bf16-tightened Eq. 4.  Rejection sampling is
     BOUNDED: a sparse-but-nonempty feasible region proceeds with the
@@ -194,7 +195,7 @@ def tune(g: CSRGraph, dim: int, *, props: GraphProps | None = None,
          mode: str = "model", iters: int = 12, pop: int = 16,
          seed: int = 0, feat_dtype: str = "float32",
          hw: TPUSpec | None = None) -> TunerResult:
-    """Pick (gs, gpt, dt, src_win) for a given graph and embedding dim.
+    """Pick (gs, gpt, dt, src_win, ont) for a given graph and embedding dim.
 
     mode="model":   white-box model over predicted tile counts (fast; §7.1).
     mode="profile": score by building real partitions (exact tiles; §7.2).

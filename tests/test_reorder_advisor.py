@@ -2,13 +2,14 @@
 import numpy as np
 import pytest
 
-from repro.core.advisor import advise
+from repro.core.advisor import advise, plan_for
 from repro.core.aggregate import PlanExecutor
 from repro.core.extractor import extract_graph_props
 from repro.core.model import AggConfig, KernelModel, config_is_feasible, paper_eq2_latency
 from repro.core.partition import partition_graph, partition_stats
 from repro.core.reorder import renumber
-from repro.core.tuner import community_profile, evolve, tune
+from repro.core.tuner import (SEARCH_SPACE, _crossover, _mutate,
+                              community_profile, evolve, tune)
 from repro.graphs.csr import random_community_graph, random_power_law
 
 
@@ -106,6 +107,50 @@ def test_tuner_monotone_and_feasible(small_graph):
     assert scores[-1] <= scores[0]
     assert config_is_feasible(res.best)
     assert res.evaluations > 0
+
+
+@pytest.fixture(scope="module")
+def power_law_3k():
+    return random_power_law(3000, 8.0, seed=3)
+
+
+@pytest.mark.parametrize("dim", [16, 64])
+def test_tuner_picks_node_block_height(power_law_3k, dim):
+    """``ont`` is searched: the pick comes from the search space, and its
+    plan runs fewer tiles than the same knobs at the old fixed 8 rows."""
+    c = tune(power_law_3k, dim, iters=6, seed=0).best
+    assert c.ont in SEARCH_SPACE["ont"]
+    tiles = lambda ont: partition_graph(power_law_3k, gs=c.gs, gpt=c.gpt,
+                                        ont=ont, src_win=c.src_win).num_tiles
+    assert tiles(c.ont) < tiles(8)
+
+
+def test_crossover_and_mutate_carry_node_block_height():
+    """Crossover takes ``ont`` from one parent and keeps a shared one;
+    mutation moves it along the search space and never off it."""
+    rng = np.random.default_rng(0)
+    a, b = AggConfig(ont=8), AggConfig(ont=128)
+    assert {_crossover(a, b, rng).ont for _ in range(64)} == {8, 128}
+    assert all(_crossover(a, a, rng).ont == 8 for _ in range(16))
+    assert all(_mutate(b, rng, p=0.0).ont == 128 for _ in range(16))
+    space = SEARCH_SPACE["ont"]
+    moved = {_mutate(AggConfig(ont=32), rng, p=1.0).ont for _ in range(64)}
+    assert moved == {16, 32, 64}
+    assert {_mutate(b, rng, p=1.0).ont for _ in range(64)} <= set(space)
+
+
+def test_pinned_config_keeps_its_node_block_height(power_law_3k):
+    """A caller's config skips the tuner: ``ont=8`` plans exactly as the
+    partitioner does at 8 rows."""
+    cfg = AggConfig(gs=4, gpt=32, dt=128, src_win=2048, ont=8)
+    plan = plan_for(power_law_3k, in_dim=16, config=cfg)
+    ref = partition_graph(power_law_3k, gs=4, gpt=32, ont=8, src_win=2048)
+    assert plan.config == cfg and plan.tuner is None
+    assert plan.partition.ont == 8
+    for f in ("nbrs", "edge_val", "local_node", "tile_node_block",
+              "tile_window", "edge_slot", "edge_pos"):
+        np.testing.assert_array_equal(getattr(plan.partition, f),
+                                      getattr(ref, f), err_msg=f)
 
 
 def test_tuner_profile_mode(community_graph):

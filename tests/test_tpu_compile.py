@@ -7,6 +7,7 @@ block shapes the chip's tiling refuses, in-kernel ops Mosaic cannot lower,
 and working sets over the scoped VMEM limit — at no chip time.  Nothing
 runs, so these tests say nothing about results or speed.
 """
+import dataclasses
 import itertools
 
 import jax
@@ -94,6 +95,17 @@ def test_edge_grad_compiles(one_chip, dtype, d):
     assert "tpu_custom_call" in _compile_edge_grad(one_chip, _BASE, d, dtype)
 
 
+@pytest.mark.parametrize("d", [16, 128])
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("kernel", ["aggregate", "edge_grad"])
+def test_tallest_node_block_compiles(one_chip, kernel, dtype, d):
+    """The tallest node block the tuner searches: a (128, dt) output block
+    and a (128, gpt) scatter one-hot."""
+    cfg = dataclasses.replace(_BASE, ont=max(SEARCH_SPACE["ont"]))
+    compile_ = _compile_fwd if kernel == "aggregate" else _compile_edge_grad
+    assert "tpu_custom_call" in compile_(one_chip, cfg, d, dtype)
+
+
 # "largest" by the whole modelled working set, and by the per-slot
 # (gpt*gs, src_win) matrices alone — the term the compiler counts several
 # times over
@@ -104,10 +116,8 @@ _SIZE = {"working_set": vmem_working_set,
 def _largest_feasible(dtype: str, measure: str) -> AggConfig:
     """The feasible config of the tuner's search space that is largest by
     ``measure``."""
-    cands = [AggConfig(gs=gs, gpt=gpt, dt=dt, src_win=w, feat_dtype=dtype)
-             for gs, gpt, dt, w in itertools.product(
-                 SEARCH_SPACE["gs"], SEARCH_SPACE["gpt"], SEARCH_SPACE["dt"],
-                 SEARCH_SPACE["src_win"])]
+    cands = [AggConfig(**dict(zip(SEARCH_SPACE, vals)), feat_dtype=dtype)
+             for vals in itertools.product(*SEARCH_SPACE.values())]
     return max((c for c in cands if config_is_feasible(c)),
                key=lambda c: (_SIZE[measure](c), vmem_working_set(c)))
 
