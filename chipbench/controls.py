@@ -50,12 +50,10 @@ def train_program(cell, seeds):
 
 
 def train_control(cell, seeds, precision):
-    model, g = cell.config["model"], cell.config["graph"]
-    indptr, indices = graphs.make_graph(g)
+    indptr, indices = graphs.make_graph(cell.config["graph"])
     n = len(indptr) - 1
     for seed in seeds:
-        inputs = train.make_inputs(seed, model, n, g["feat_dim"],
-                                   g["num_classes"])
+        inputs = train.cell_inputs(cell, seed, n)
         ref = train.reference_steps(cell, indptr, indices, inputs)
         ctl = train.reference_steps(cell, indptr, indices, inputs,
                                     precision)

@@ -68,20 +68,47 @@ def community(num_communities: int, community_size: int, *, p_intra: float,
     return from_edges(n, src, dst, symmetrize=True)
 
 
-def make_graph(spec: dict):
-    """The configuration's ``graph`` entry -> ``(indptr, indices)``.
-
-    ``spec["num_nodes"]`` and ``spec["num_edges"]`` are the published
-    sizes the generator aims at; ``spec["seed"]`` fixes the graph.
-    """
+def _power_law(spec: dict):
     n = int(spec["num_nodes"])
-    avg_deg = spec["num_edges"] / spec["num_nodes"]
-    if spec["type"] == "II":
+    return power_law(n, spec["num_edges"] / spec["num_nodes"],
+                     exponent=spec["exponent"], seed=spec["seed"])
+
+
+def _community(spec: dict):
+    """Without ``community_size`` or ``num_communities``: communities of
+    up to 40 nodes at the published mean degree (the DD replica).  With
+    either: that many communities of that size (the other one follows from
+    ``num_nodes``), drawing ``num_edges`` undirected pairs in all, as
+    `power_law` draws them."""
+    n = int(spec["num_nodes"])
+    size, num = spec.get("community_size"), spec.get("num_communities")
+    if size is None and num is None:
+        avg_deg = spec["num_edges"] / spec["num_nodes"]
         comm = max(2, min(40, int(np.sqrt(n))))
         return community(max(1, n // comm), comm,
                          p_intra=min(0.9, avg_deg / max(comm - 1, 1)),
                          seed=spec["seed"])
-    if spec["type"] == "III":
-        return power_law(n, avg_deg, exponent=spec["exponent"],
-                         seed=spec["seed"])
-    raise ValueError(f"no generator for graph type {spec['type']!r}")
+    size = int(size or n // num)
+    num = int(num or max(1, n // size))
+    return community(num, size,
+                     p_intra=spec["num_edges"] / (num * size * (size - 1) / 2),
+                     seed=spec["seed"])
+
+
+_GENERATORS = {"power_law": _power_law, "community": _community}
+
+
+def make_graph(spec: dict):
+    """The configuration's ``graph`` entry -> ``(indptr, indices)``.
+
+    ``spec["generator"]`` names the generator (``power_law``: Chung-Lu
+    with ``exponent``; ``community``: disjoint Erdos-Renyi communities,
+    optionally of ``community_size`` nodes, ``num_communities`` of them);
+    ``spec["num_nodes"]`` and ``spec["num_edges"]`` are the published
+    sizes it aims at; ``spec["seed"]`` fixes the graph.
+    """
+    name = spec["generator"]
+    if name not in _GENERATORS:
+        raise ValueError(f"no generator {name!r}; known: "
+                         f"{sorted(_GENERATORS)}")
+    return _GENERATORS[name](spec)

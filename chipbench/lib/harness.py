@@ -5,7 +5,8 @@ line.
 A cell ``<config>.<traffic>`` in ``BENCHMARK.json`` is run from four kinds
 of files, each found by its name:
 
-* ``configs/<config>.json``: the model, its graph and its numerics;
+* ``configs/<config>.json``: the model, its graph and its numerics; the
+  model's ``arch`` names its module, ``lib/arch/<arch>.py``;
 * ``mixes/<traffic>.json``: the traffic's parameters and the ``loop``
   (``lib/<loop>.py``) that runs that kind of traffic;
 * ``workloads/<cell>.json``: the cell's own parameters (rates, lengths)
@@ -24,6 +25,8 @@ import sys
 import threading
 from pathlib import Path
 from typing import Optional
+
+from . import arch
 
 __all__ = ["ROOT", "BENCH_DIR", "Cell", "load_cell", "require_chips",
            "enable_compile_cache", "CompileCounter", "read_metrics",
@@ -75,9 +78,13 @@ def load_cell(name: str, manifest: Optional[dict] = None) -> Cell:
                          f"{sorted(by_name)}")
     w = by_name[name]
     cfg = next(c for c in manifest["configs"] if c["name"] == w["config"])
+    config = _load_json(ROOT / cfg["file"])
+    # an architecture or a task the benchmark lacks fails here, before any
+    # graph is built
+    arch.load(config["model"]["arch"])
+    arch.task(config["graph"])
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load_json(ROOT / cfg["file"]),
+        name=name, chips=int(w["chips"]), config=config,
         mix=_load_json(BENCH_DIR / "mixes" / f"{w['traffic']}.json"),
         params=_load_json(BENCH_DIR / "workloads" / f"{name}.json"),
         end_to_end=[m for m in manifest["end_to_end"] if _reports(m, name)],

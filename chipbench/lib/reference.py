@@ -1,20 +1,11 @@
-"""Plain float32 GCN and GIN, their loss, and AdamW: the benchmark's
-reference.
+"""The benchmark's reference: a plain float32 GNN, its loss, and AdamW.
 
 It imports nothing of the program.  It works on the original CSR (no
 renumbering, no tiles, no schedule): aggregation is a gather of the source
 rows and a sorted `segment_sum` into the destination rows.  Dense matmuls
-run at ``Precision.HIGHEST``.
-
-GCN (Kipf & Welling, arXiv:1609.02907): ``A_hat = D^-1/2 (A + I) D^-1/2``
-with ``D`` the degrees of ``A + I``; each layer is ``A_hat (X W)``, ReLU
-between layers (the projection before the aggregation, as GNNAdvisor
-places it; the product is the same).
-
-GIN (Xu et al., arXiv:1810.00826): each layer is
-``MLP((1 + eps) x + sum of neighbours)`` with the two-layer MLP
-``relu(h W) Wb``.  As in the program, there is no ReLU or normalisation
-between GIN layers.
+run at ``Precision.HIGHEST``.  Each architecture's graph, parameters and
+forward live in its module, ``lib/arch/<arch>.py``, found by the model's
+``arch``; this module holds what they share.
 
 ``Numerics("high")`` is the control: every product a three-pass bfloat16
 product, ``a_hi b_hi + a_hi b_lo + a_lo b_hi``, as the MXU computes at
@@ -31,8 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["Numerics", "Graph", "graph_arrays", "init_params", "logits",
-           "loss", "adamw_steps", "adamw_state"]
+from .arch import load as load_arch
+
+__all__ = ["Numerics", "Graph", "coo", "graph_arrays", "param_shapes",
+           "init_params", "logits", "loss", "adamw_steps", "adamw_state"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -94,27 +87,33 @@ class Numerics:
         return jnp.dot(a, b, precision=HIGHEST)
 
     def aggregate(self, x, g: "Graph"):
-        """``out[v] = sum_e vals[e] * x[cols[e]]`` over v's row."""
+        """``out[v] = sum_e vals[e] * x[cols[e]]`` over v's row;
+        differentiable in ``x`` and in ``g.vals`` (edge values computed from
+        the parameters, as attention's are)."""
         prod = _prod3 if self.precision == "high" else _mul
         n = x.shape[0]
 
         @jax.custom_vjp
-        def agg(x):
+        def agg(x, vals):
             return jax.ops.segment_sum(
-                _edge_msg(x[g.cols], g.vals, prod), g.rows, num_segments=n,
+                _edge_msg(x[g.cols], vals, prod), g.rows, num_segments=n,
                 indices_are_sorted=True)
 
-        def fwd(x):
-            return agg(x), None
+        def fwd(x, vals):
+            return agg(x, vals), (x, vals)
 
-        def bwd(_, ct):
+        def bwd(res, ct):
+            x, vals = res
             # the transpose: each source gathers its destinations' cotangent
-            return (jax.ops.segment_sum(
-                _edge_msg(ct[g.rows], g.vals, prod), g.cols,
-                num_segments=n),)
+            dx = jax.ops.segment_sum(_edge_msg(ct[g.rows], vals, prod),
+                                     g.cols, num_segments=n)
+            if vals is None:
+                return dx, None
+            # each edge's value: its destination's cotangent . its source
+            return dx, jnp.sum(prod(ct[g.rows], x[g.cols]), axis=1)
 
         agg.defvjp(fwd, bwd)
-        return agg(x)
+        return agg(x, g.vals)
 
 
 def _edge_msg(msg, vals, prod):
@@ -134,39 +133,22 @@ class Graph:
     vals: jax.Array | None
 
 
-def graph_arrays(indptr: np.ndarray, indices: np.ndarray, arch: str) -> Graph:
-    """Original CSR -> the aggregation graph of ``arch`` on the device.
-    GCN adds a self-loop on every node and the symmetric normalisation."""
+def coo(indptr: np.ndarray, indices: np.ndarray):
+    """The original CSR as destination ``rows`` (sorted) and source
+    ``cols``, int32 on the host."""
     n = len(indptr) - 1
-    deg = np.diff(indptr)
-    rows = np.repeat(np.arange(n, dtype=np.int32), deg)
-    cols = np.asarray(indices, np.int32)
-    if arch == "gin":
-        return Graph(jnp.asarray(rows), jnp.asarray(cols), None)
-    if arch != "gcn":
-        raise ValueError(f"no reference for arch {arch!r}")
-    loops = np.arange(n, dtype=np.int32)
-    rows = np.concatenate([rows, loops])
-    cols = np.concatenate([cols, loops])
-    order = np.argsort(rows, kind="stable")
-    rows, cols = rows[order], cols[order]
-    inv_sqrt = 1.0 / np.sqrt((deg + 1).astype(np.float64))
-    vals = (inv_sqrt[rows] * inv_sqrt[cols]).astype(np.float32)
-    return Graph(jnp.asarray(rows), jnp.asarray(cols), jnp.asarray(vals))
+    rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(indptr))
+    return rows, np.asarray(indices, np.int32)
+
+
+def graph_arrays(indptr: np.ndarray, indices: np.ndarray, arch: str) -> Graph:
+    """Original CSR -> the aggregation graph of ``arch`` on the device."""
+    return load_arch(arch).graph_arrays(indptr, indices)
 
 
 def param_shapes(model: dict, in_dim: int, num_classes: int) -> dict:
-    """Parameter names and shapes: ``w{i}`` (and GIN's ``w{i}b``)."""
-    L, h = model["num_layers"], model["hidden_dim"]
-    dims = [in_dim] + [h] * (L - 1) + [num_classes]
-    shapes = {}
-    for i in range(L):
-        if model["arch"] == "gcn":
-            shapes[f"w{i}"] = (dims[i], dims[i + 1])
-        else:
-            shapes[f"w{i}"] = (dims[i], h)
-            shapes[f"w{i}b"] = (h, dims[i + 1])
-    return shapes
+    """Parameter names and shapes, as the model's architecture gives them."""
+    return load_arch(model["arch"]).param_shapes(model, in_dim, num_classes)
 
 
 def init_params(key, model: dict, in_dim: int, num_classes: int) -> dict:
@@ -178,23 +160,17 @@ def init_params(key, model: dict, in_dim: int, num_classes: int) -> dict:
 
 
 def logits(params, feat, g: Graph, model: dict, num: Numerics):
-    x = feat
-    L = model["num_layers"]
-    for i in range(L):
-        if model["arch"] == "gcn":
-            x = num.aggregate(num.mm(x, params[f"w{i}"]), g)
-            if i < L - 1:
-                x = jax.nn.relu(x)
-        else:
-            h = (1.0 + model["gin_eps"]) * x + num.aggregate(x, g)
-            x = num.mm(jax.nn.relu(num.mm(h, params[f"w{i}"])),
-                       params[f"w{i}b"])
-    return x
+    return load_arch(model["arch"]).logits(params, feat, g, model, num)
 
 
 def loss(params, feat, labels, g: Graph, model: dict, num: Numerics):
-    """Mean softmax cross-entropy over every node."""
-    logp = jax.nn.log_softmax(logits(params, feat, g, model, num), axis=-1)
+    """Class ids ``(N,)``: mean softmax cross-entropy over every node.
+    Multi-label 0/1 targets ``(N, C)``: mean sigmoid binary cross-entropy
+    over every node and class, ``log(1 + e^z) - y z``."""
+    z = logits(params, feat, g, model, num)
+    if labels.ndim == 2:
+        return jnp.mean(jax.nn.softplus(z) - labels * z)
+    logp = jax.nn.log_softmax(z, axis=-1)
     return -jnp.mean(jnp.take_along_axis(logp, labels[:, None], axis=1))
 
 

@@ -24,10 +24,13 @@ import types
 
 import numpy as np
 
-from . import compare, graphs, harness, loadgen, reference
+from . import arch, compare, graphs, harness, loadgen, reference
 from .trace_window import TraceWindow
 
-__all__ = ["run", "percentile", "DRAIN_S"]
+__all__ = ["run", "percentile", "DRAIN_S", "CHECKS"]
+
+# the numbers `compare.serve_numbers` gives `compare.judge`
+CHECKS = ("logit_gap", "unanswered")
 
 DRAIN_S = 60.0            # wait past the window's close for answers
 WARM_PASSES_MAX = 10
@@ -110,12 +113,8 @@ def build(cell, seed: int, tw, hooks=None):
     n = len(indptr) - 1
     harness.log(f"graph {gspec['dataset']}: {n} nodes, {len(indices)} "
                 f"edges ({time.perf_counter() - t:.2f}s)")
-    gcfg = GNNConfig(arch=model["arch"], in_dim=gspec["feat_dim"],
-                     hidden_dim=model["hidden_dim"],
-                     num_classes=gspec["num_classes"],
-                     num_layers=model["num_layers"],
-                     gin_eps=model.get("gin_eps", 0.0),
-                     feat_dtype=cfg["dtype"])
+    gcfg = GNNConfig(**arch.load(model["arch"]).program_config(
+        model, gspec, cfg["dtype"]))
     params, feat = make_inputs(seed, model, n, gspec["feat_dim"],
                                gspec["num_classes"])
     engine = ServingEngine(

@@ -16,18 +16,22 @@ import types
 
 import numpy as np
 
-from . import compare, graphs, harness, reference
+from . import arch, compare, graphs, harness, reference
 from .trace_window import TraceWindow
 
-__all__ = ["run", "CHECKED_STEPS"]
+__all__ = ["run", "CHECKED_STEPS", "CHECKS"]
 
 CHECKED_STEPS = 3
+# the numbers `compare.train_numbers` gives `compare.judge`
+CHECKS = ("loss_gap", "grad_norm_gap", "grad_diff", "update_norm_gap")
 
 
 def make_inputs(seed: int, model: dict, nodes: int, in_dim: int,
-                classes: int):
+                classes: int, labels: str = "single"):
     """Weights, features and labels from the seed, on the device, in one
-    jitted call, in the original node order."""
+    jitted call, in the original node order.  ``labels`` is the task:
+    ``"single"``, a class a node, uniform; ``"multi"``, ``(nodes,
+    classes)`` 0/1 targets, each 1 with probability one half."""
     import jax
     import jax.numpy as jnp
 
@@ -36,10 +40,20 @@ def make_inputs(seed: int, model: dict, nodes: int, in_dim: int,
         kp, kf, kl = jax.random.split(key, 3)
         params = reference.init_params(kp, model, in_dim, classes)
         feat = jax.random.normal(kf, (nodes, in_dim), jnp.float32)
-        labels = jax.random.randint(kl, (nodes,), 0, classes, jnp.int32)
-        return params, feat, labels
+        if labels == "multi":
+            y = jax.random.bernoulli(kl, 0.5, (nodes, classes))
+            return params, feat, y.astype(jnp.float32)
+        y = jax.random.randint(kl, (nodes,), 0, classes, jnp.int32)
+        return params, feat, y
 
     return draw(harness.jax_key(seed))
+
+
+def cell_inputs(cell, seed: int, nodes: int):
+    """`make_inputs` for the cell's model, graph widths and task."""
+    g = cell.config["graph"]
+    return make_inputs(seed, cell.config["model"], nodes, g["feat_dim"],
+                       g["num_classes"], arch.task(g))
 
 
 def _opt_config(mix: dict):
@@ -67,12 +81,8 @@ def build(cell, hooks=None):
     n, e = len(indptr) - 1, len(indices)
     harness.log(f"graph {gspec['dataset']}: {n} nodes, {e} edges "
                 f"({host['graph_s']:.2f}s)")
-    gcfg = GNNConfig(arch=model["arch"], in_dim=gspec["feat_dim"],
-                     hidden_dim=model["hidden_dim"],
-                     num_classes=gspec["num_classes"],
-                     num_layers=model["num_layers"],
-                     gin_eps=model.get("gin_eps", 0.0),
-                     feat_dtype=cfg["dtype"])
+    gcfg = GNNConfig(**arch.load(model["arch"]).program_config(
+        model, gspec, cfg["dtype"]))
     t = time.perf_counter()
     prog = build_gnn(CSRGraph(indptr, indices), gcfg,
                      reorder=plan_spec["reorder"],
@@ -87,9 +97,7 @@ def build(cell, hooks=None):
     step_fn = make_gnn_train_step(prog, _opt_config(cell.mix))
     if hooks and "step" in hooks:
         step_fn = hooks["step"](step_fn)
-    shapes = jax.eval_shape(
-        lambda: make_inputs(0, model, n, gspec["feat_dim"],
-                            gspec["num_classes"]))
+    shapes = jax.eval_shape(lambda: cell_inputs(cell, 0, n))
     params = shapes[0]
     batch = {"feat": shapes[1], "labels": shapes[2]}
     state = (params, jax.eval_shape(adamw_init, params))
@@ -108,9 +116,7 @@ def feed(b, cell, seed: int):
     import jax.numpy as jnp
     from repro.optim.adamw import adamw_init
 
-    g = cell.config["graph"]
-    params, feat, labels = make_inputs(seed, cell.config["model"], b.nodes,
-                                       g["feat_dim"], g["num_classes"])
+    params, feat, labels = cell_inputs(cell, seed, b.nodes)
     if b.perm is None:
         batch = {"feat": feat, "labels": labels}
     else:

@@ -11,8 +11,8 @@ for p in (ROOT, ROOT / "src"):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-# graph sizes at which a whole run fits a CPU test
-TINY_GRAPH = {"III": (1500, 1500 * 12), "II": (2000, 2000 * 5)}
+# graph sizes at which a whole run fits a CPU test, by generator
+TINY_GRAPH = {"power_law": (1500, 1500 * 12), "community": (2000, 2000 * 5)}
 
 
 # cells whose files are kept for a later PR but that BENCHMARK.json does not
@@ -50,15 +50,23 @@ def _unlisted_cell(name: str):
         per_layer=[{"name": m, "unit": "-"} for m in layers])
 
 
-def tiny_cell(name: str):
-    """The cell with its graph cut to a CPU size."""
+def tiny_cell(name: str, manifest=None):
+    """The cell with its graph cut to a CPU size; ``manifest`` stands in
+    for BENCHMARK.json."""
     from chipbench.lib import harness
 
     cell = (_unlisted_cell(name) if name in UNLISTED
-            else harness.load_cell(name))
+            else harness.load_cell(name, manifest))
     cell.config = copy.deepcopy(cell.config)
     g = cell.config["graph"]
-    g["num_nodes"], g["num_edges"] = TINY_GRAPH[g["type"]]
+    if "community_size" in g or "num_communities" in g:
+        # one community of the configured size and density
+        k = g.get("num_communities") or g["num_nodes"] // g["community_size"]
+        g["num_nodes"] = g.get("community_size") or g["num_nodes"] // k
+        g["num_edges"] /= k
+        g["num_communities"] = 1
+    else:
+        g["num_nodes"], g["num_edges"] = TINY_GRAPH[g["generator"]]
     cell.config["plan"]["tune_iters"] = 2
     if "rate_rps" in cell.params:
         cell.params = {**cell.params, "rate_rps": 40}

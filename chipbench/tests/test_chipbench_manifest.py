@@ -1,5 +1,6 @@
 """BENCHMARK.json keeps to the benchmark's contract, and every cell finds
 its files by name."""
+import importlib
 import json
 import re
 from pathlib import Path
@@ -11,9 +12,6 @@ BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
-NUMBERS = {"train": {"loss_gap", "grad_norm_gap", "grad_diff",
-                     "update_norm_gap"},
-           "serve": {"logit_gap", "unanswered"}}
 
 
 def _line(text):
@@ -74,9 +72,12 @@ def test_cell_files_found_by_name(cell):
     mix = json.loads((ROOT / "chipbench" / "mixes"
                       / f"{w['traffic']}.json").read_text())
     assert (ROOT / "chipbench" / "lib" / f"{mix['loop']}.py").exists()
+    assert (ROOT / "chipbench" / "lib" / "arch"
+            / f"{config['model']['arch']}.py").exists()
     params = json.loads((ROOT / "chipbench" / "workloads"
                          / f"{cell}.json").read_text())
-    assert set(params["limits"]) == NUMBERS[mix["loop"]]
+    loop = importlib.import_module(f"chipbench.lib.{mix['loop']}")
+    assert set(params["limits"]) == set(loop.CHECKS)
     for m in BENCH["per_layer"]:
         if _reported(m, cell):
             assert (ROOT / "chipbench" / "metrics"

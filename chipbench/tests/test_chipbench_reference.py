@@ -22,7 +22,9 @@ def test_graph_copy_matches_the_program_generator(dataset, gtype):
     spec = PAPER_DATASETS[dataset]
     scale = 0.01
     g, _, _ = make_dataset(dataset, scale=scale, seed=3)
-    ours = graphs.make_graph({"type": gtype, "seed": 3, "exponent": 2.1,
+    generator = {"III": "power_law", "II": "community"}[gtype]
+    ours = graphs.make_graph({"generator": generator, "seed": 3,
+                              "exponent": 2.1,
                               "num_nodes": int(spec.num_nodes * scale),
                               "num_edges": spec.num_edges
                               * int(spec.num_nodes * scale)
@@ -63,7 +65,7 @@ def _plan_order(prog, x):
 @pytest.mark.parametrize("arch", ["gcn", "gin"])
 def test_reference_matches_the_program(arch):
     indptr, indices = graphs.make_graph(
-        {"type": "III", "num_nodes": 300, "num_edges": 2400,
+        {"generator": "power_law", "num_nodes": 300, "num_edges": 2400,
          "exponent": 2.1, "seed": 1})
     n = len(indptr) - 1
     in_dim, classes = DIMS[arch]

@@ -13,6 +13,8 @@ import pytest
 
 from conftest import tiny_cell
 
+from chipbench.lib import serve
+
 ROOT = Path(__file__).resolve().parents[2]
 
 
@@ -95,6 +97,7 @@ def test_sound_serve_run_is_correct(cpu_run):
     out = cpu_run(tiny_cell("gin-dd.serve"), seconds=1.0, trace=True)
     assert out["correct"], out["checks"]
     assert out["attempted"] == 40 and out["failed"] == 0
+    assert set(out["checks"]) == set(serve.CHECKS)
     m = out["metrics"]
     assert {"extract_ms.serve", "plan_ms.serve", "compute_ms.serve",
             "batch_size.serve", "plan_cache_hit_rate.serve",
