@@ -14,8 +14,8 @@ Planning records spans in the process tracer
 whatever span is open on that tracer; and it counts each plan's forward
 tiles, padded slots and real edges in the same tracer's registry
 (``plan_tiles_total``, ``plan_padded_slots_total``, ``plan_edges_total``),
-with the node-block height of the newest plan (gauge
-``plan_node_block_rows``).
+with the node-block height and the aggregated feature width of the newest
+plan (gauges ``plan_node_block_rows``, ``plan_agg_width``).
 """
 from __future__ import annotations
 
@@ -143,11 +143,12 @@ def plan_for(g: CSRGraph, *, arch: str = "gcn", in_dim: int = 128,
         with tr.span("analyze"):
             props = extract_graph_props(g, detect_communities=False)
     archp = extract_arch_props(arch, in_dim, hidden_dim, num_layers)
+    # the §4.2 placement: the width the kernel aggregates at
+    width = archp.hidden_dim if archp.reduce_dim_first else archp.in_dim
     tuner_res = None
     if config is None:
         with tr.span("tune"):
-            tuner_res = tune(g, archp.hidden_dim if archp.reduce_dim_first
-                             else archp.in_dim,
+            tuner_res = tune(g, width,
                              props=props, mode=tune_mode, iters=tune_iters,
                              seed=seed, feat_dtype=feat_dtype or "float32")
         config = tuner_res.best
@@ -180,7 +181,7 @@ def plan_for(g: CSRGraph, *, arch: str = "gcn", in_dim: int = 128,
                                        ont=config.ont, src_win=config.src_win,
                                        edge_vals=vals_t)
         stats = partition_stats(part)
-    _count_plan(tr.registry, part)
+    _count_plan(tr.registry, part, width)
     return Plan(
         graph=g, partition=part, config=config, graph_props=props,
         arch=archp, perm=None, tuner=tuner_res, stats=stats,
@@ -189,10 +190,10 @@ def plan_for(g: CSRGraph, *, arch: str = "gcn", in_dim: int = 128,
     )
 
 
-def _count_plan(registry, part: GroupPartition) -> None:
+def _count_plan(registry, part: GroupPartition, width: int) -> None:
     """The forward plan's work counts (`padded_slots_per_edge` is their
     ratio): tiles, slots the kernel visits, and real edges; and the node
-    block height it runs at."""
+    block height and the feature width it runs at."""
     for name, n in (("plan_tiles_total", part.num_tiles),
                     ("plan_padded_slots_total",
                      part.num_tiles * part.gpt * part.gs),
@@ -202,3 +203,6 @@ def _count_plan(registry, part: GroupPartition) -> None:
     registry.gauge("plan_node_block_rows",
                    desc="node-block height (ont) of the newest plan "
                         "(repro.core.advisor)").set(part.ont)
+    registry.gauge("plan_agg_width",
+                   desc="feature width the newest plan aggregates at, the "
+                        "one it was tuned for (repro.core.advisor)").set(width)

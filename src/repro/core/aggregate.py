@@ -89,11 +89,13 @@ class PlanExecutor:
 
     def aggregate_edges(self, feat: jax.Array,
                         edge_values: jax.Array) -> jax.Array:
-        """Aggregation with DYNAMIC per-edge weights (original CSR edge
-        order of the plan's graph) — the GAT-type path: the schedule is
-        reused, only the edge-value tensor is re-scattered per forward.
-        With a backward schedule, gradients flow to BOTH ``feat`` (via the
-        transposed kernel) and ``edge_values`` (per-edge gather-dot)."""
+        """Aggregation with DYNAMIC per-edge weights, (E,) or (E, H) with
+        a value per head (original CSR edge order of the plan's graph) —
+        the GAT-type path: the schedule is reused, only the edge-value
+        tensor is laid out anew per forward (a gather through the
+        schedule's ``slot_edge``).  With a backward schedule, gradients
+        flow to BOTH ``feat`` (via the transposed kernel) and
+        ``edge_values`` (per-edge, per-head gather-dot)."""
         return _kernel_aggregate(feat, self.sched, dt=self.dt,
                                  backend=self.backend,
                                  edge_values=edge_values,

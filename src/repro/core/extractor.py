@@ -8,7 +8,8 @@ every downstream decision:
   * community statistics     -> whether renumbering pays off (§6.1, §8.6.2)
   * GNN architecture type    -> aggregation placement (§4.2): type-1
     (GCN-like, order-independent, reduce-dim-first) vs type-2 (GIN/GAT-like,
-    edge-valued, full-dim aggregation).
+    edge-valued); GIN aggregates its full input dim, GAT its projected
+    heads (so the tuner plans GAT at ``hidden_dim``, heads x head width).
 """
 from __future__ import annotations
 
@@ -53,7 +54,7 @@ class GNNArchProps:
     in_dim: int
     hidden_dim: int
     num_layers: int
-    reduce_dim_first: bool  # type 1 => True (aggregate after W projection)
+    reduce_dim_first: bool  # aggregate after the W projection (GCN, GAT)
 
 
 def _label_propagation_communities(g: CSRGraph, *, rounds: int = 5,
@@ -146,5 +147,5 @@ def extract_arch_props(name: str, in_dim: int, hidden_dim: int,
         raise ValueError(f"unknown GNN architecture {name!r}")
     return GNNArchProps(
         name=name_l, agg_type=agg_type, in_dim=in_dim, hidden_dim=hidden_dim,
-        num_layers=num_layers, reduce_dim_first=(agg_type == 1),
+        num_layers=num_layers, reduce_dim_first=(name_l != "gin"),
     )

@@ -26,8 +26,8 @@ The backward (transposed) schedule is patched the same way with dirtiness
 measured on SOURCE endpoints, using a synthetic transposed-edge enumeration
 ``[kept old transposed edges, repartitioned sub edges]``.  Only the
 *composition* of (edge_perm, edge_slot, edge_pos) is observable — the
-kernel gathers ``edge_values[edge_perm]`` and scatters through the slot
-maps — so the enumeration is free as long as every forward edge appears
+kernel gathers ``edge_values[edge_perm]`` and lays it out through the
+slot maps — so the enumeration is free as long as every forward edge appears
 exactly once (checked).
 
 `Plan.apply_delta` drives both and falls back to a full repartition at the

@@ -66,11 +66,13 @@ def _stack_dir(scheds, *, with_edges: bool) -> tuple:
     laid out like `kernels.ops.sched_arrays`.  Tile tensors are already
     uniform (`shard_plan` pads); the (E_p,)-sized edge members are padded
     to the max edge count — padded ``edge_slot`` entries point one past
-    the flat group range, so their scatter updates are dropped."""
+    the flat group range, so the edge cotangent's gather never reads
+    them back — and every shard's padded slots name edge E_max, the 0
+    the edge-value layout appends after the E_max padded values."""
     tiles = tuple(jnp.stack([getattr(s, f) for s in scheds])
                   for f in _TILE_FIELDS)
     if not with_edges:
-        return tiles + (None, None, None)
+        return tiles + (None, None, None, None)
     oob = scheds[0].nbrs.shape[0] * scheds[0].gpt     # out-of-range slot
     e_max = max(int(s.edge_slot.shape[0]) for s in scheds)
 
@@ -84,8 +86,11 @@ def _stack_dir(scheds, *, with_edges: bool) -> tuple:
                                 constant_values=fill))
         return jnp.stack(cols)
 
+    slot_edge = jnp.stack([
+        jnp.where(s.slot_edge < s.edge_slot.shape[0], s.slot_edge, e_max)
+        for s in scheds])
     return tiles + (padded("edge_slot", oob), padded("edge_pos", 0),
-                    padded("edge_perm", 0))
+                    padded("edge_perm", 0), slot_edge)
 
 
 def stack_shard_args(shards: PlanShards, *, with_edges: bool = False):

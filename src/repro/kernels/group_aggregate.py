@@ -37,6 +37,12 @@ blocks — 8 tiles per block — and ``local_node`` as (8, gpt) blocks of
 row out of the block in-kernel (a transposed block gives the slots as a
 column).
 
+Edge values may carry a head axis (attention's): ``(H, T, gpt, gs)``, one
+value per slot and head, laid out as (H, T, gpt*gs) rows.  The feature
+columns are then H equal head blocks, each a whole number of dim tiles,
+and dim tile j weights its window with head ``j // (J / H)``'s values, so
+each grid step is the one-head kernel; H = 1 is the plain aggregation.
+
 The scalar-prefetched (T,) metadata lives in SMEM (1 MiB on v5e), so a
 schedule longer than `MAX_TILES_PER_CALL` tiles runs as several launches
 over consecutive tile ranges.  Each launch accumulates into the previous
@@ -74,6 +80,12 @@ def _rows(x: jax.Array) -> jax.Array:
     """(T, ...) -> (T rounded up to 8, prod(...)); pad rows are never read."""
     x = x.reshape(x.shape[0], -1)
     return jnp.pad(x, ((0, -x.shape[0] % _ROWS), (0, 0)))
+
+
+def _head_rows(x: jax.Array) -> jax.Array:
+    """(H, T, ...) -> (H, T rounded up to 8, prod(...)): `_rows` per head."""
+    x = x.reshape(x.shape[0], x.shape[1], -1)
+    return jnp.pad(x, ((0, 0), (0, -x.shape[1] % _ROWS), (0, 0)))
 
 
 def _tile_row(ref, t) -> jax.Array:
@@ -150,12 +162,13 @@ def _edge_grad_kernel(nb_ref, tw_ref,                 # scalar prefetch (SMEM)
                       out_ref,                         # (8, gpt*gs) block
                       *, gs: int, gpt: int, ont: int, src_win: int):
     del prior_ref
-    t = pl.program_id(0)
-    j = pl.program_id(1)
+    t = pl.program_id(1)
+    j = pl.program_id(2)
     row = pl.ds(jax.lax.rem(t, _ROWS), 1)
 
-    # dim tiles are innermost here (grid (T, J)), so every j-step revisits
-    # the tile's output row: zero on the first, accumulate after.
+    # a head's dim tiles are innermost here (grid (H, T, J/H)), so every
+    # j-step revisits the tile's output row of that head: zero on the
+    # first, accumulate after.
     @pl.when(j == 0)
     def _zero():
         out_ref[row, :] = jnp.zeros((1, gpt * gs), jnp.float32)
@@ -186,52 +199,61 @@ def _edge_grad_kernel(nb_ref, tw_ref,                 # scalar prefetch (SMEM)
 
 @functools.partial(
     jax.jit,
-    static_argnames=("gs", "gpt", "ont", "src_win", "dt", "interpret"),
+    static_argnames=("gs", "gpt", "ont", "src_win", "dt", "heads",
+                     "interpret"),
 )
 def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
                            nbrs: jax.Array, local_node: jax.Array,
                            tile_node_block: jax.Array, tile_window: jax.Array,
                            *, gs: int, gpt: int, ont: int, src_win: int,
-                           dt: int, interpret: bool = False) -> jax.Array:
+                           dt: int, heads: int = 1,
+                           interpret: bool = False) -> jax.Array:
     """Per-slot edge-value cotangent: the backward of aggregation w.r.t. the
-    (T, gpt, gs) edge-value tensor.
+    (H, T, gpt, gs) edge-value tensor.
 
-    For slot (t, g, s) holding edge (v <- u):  out[t, g, s] = <grad[v], feat[u]>
-    — each group's output-row cotangent is selected from the VMEM-resident
-    node block (one-hot matmul), dotted against the whole feature window
-    (one MXU matmul), and each slot keeps its own window row (same schedule
+    For slot (t, g, s) holding edge (v <- u) and head h:
+    out[h, t, g, s] = <grad[v], feat[u]> over head h's columns — each
+    group's output-row cotangent is selected from the VMEM-resident node
+    block (one-hot matmul), dotted against the whole feature window (one
+    MXU matmul), and each slot keeps its own window row (same schedule
     metadata, same scalar-prefetch-driven BlockSpecs as the forward kernel).
 
     grad_padded: (out_rows, D_pad) output cotangent, out_rows % ont == 0.
-    feat_padded: (N_src_pad, D_pad), N_src_pad % src_win == 0, D_pad % dt == 0.
-    Returns (T, gpt, gs) float32.  Padded slots hold garbage; callers gather
-    only real (edge_slot, edge_pos) entries.
+    feat_padded: (N_src_pad, D_pad), N_src_pad % src_win == 0; D_pad is
+    ``heads`` equal head blocks, each a multiple of dt.
+    Returns (H, T, gpt, gs) float32.  Padded slots hold garbage; callers
+    gather only real (edge_slot, edge_pos) entries.
     """
     out_rows, d_pad = grad_padded.shape
     n_src, d_pad2 = feat_padded.shape
-    assert d_pad == d_pad2 and d_pad % dt == 0, (d_pad, d_pad2, dt)
+    assert d_pad == d_pad2 and d_pad % (dt * heads) == 0, (d_pad, d_pad2, dt)
     assert n_src % src_win == 0 and out_rows % ont == 0
     T = nbrs.shape[0]
     assert nbrs.shape == (T, gpt, gs) and local_node.shape == (T, gpt)
-    J = d_pad // dt
+    jh = d_pad // dt // heads                    # dim tiles per head
     kernel = functools.partial(_edge_grad_kernel, gs=gs, gpt=gpt, ont=ont,
                                src_win=src_win)
     K = gpt * gs
     nbrs_rows, ln = _rows(nbrs), _rows(local_node)
-    out = jnp.zeros(nbrs_rows.shape, jnp.float32)
+    out = jnp.zeros((heads,) + nbrs_rows.shape, jnp.float32)
     for t0, nt in _launches(T):
-        meta = lambda t, j, nb, tw, t0=t0: ((t0 + t) // _ROWS, 0)
+        meta = lambda h, t, j, nb, tw, t0=t0: ((t0 + t) // _ROWS, 0)
+        col = lambda h, j: h * jh + j
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(nt, J),
+            grid=(heads, nt, jh),
             in_specs=[
-                pl.BlockSpec((ont, dt), lambda t, j, nb, tw: (nb[t], j)),
-                pl.BlockSpec((src_win, dt), lambda t, j, nb, tw: (tw[t], j)),
+                pl.BlockSpec((ont, dt),
+                             lambda h, t, j, nb, tw: (nb[t], col(h, j))),
+                pl.BlockSpec((src_win, dt),
+                             lambda h, t, j, nb, tw: (tw[t], col(h, j))),
                 pl.BlockSpec((_ROWS, K), meta),
                 pl.BlockSpec((_ROWS, gpt), meta),
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec((_ROWS, K), meta),
+            out_specs=pl.BlockSpec(
+                (None, _ROWS, K),
+                lambda h, t, j, nb, tw, t0=t0: (h, (t0 + t) // _ROWS, 0)),
         )
         out = pl.pallas_call(
             kernel,
@@ -242,7 +264,7 @@ def group_edge_grad_pallas(grad_padded: jax.Array, feat_padded: jax.Array,
             name="group_edge_grad",
         )(tile_node_block[t0:t0 + nt], tile_window[t0:t0 + nt],
           grad_padded, feat_padded, nbrs_rows, ln, out)
-    return out[:T].reshape(T, gpt, gs)
+    return out[:, :T].reshape(heads, T, gpt, gs)
 
 
 @functools.partial(
@@ -269,8 +291,10 @@ def group_aggregate_pallas(feat_padded: jax.Array,
         (`repro.kernels.ops.dim_tile`).
     nbrs : (T, gpt, gs) int32 — global source ids per slot.  Padded slots
         point at their tile's window base so local ids stay in range.
-    edge_val : (T, gpt, gs) float32 — per-edge weights; exactly 0 marks a
-        padded slot.
+    edge_val : (T, gpt, gs) or (H, T, gpt, gs) float32 — per-edge weights,
+        one per head; exactly 0 marks a padded slot.  With H heads the
+        D_pad columns are H equal head blocks, each a multiple of ``dt``,
+        and head h's values weight head h's columns.
     local_node : (T, gpt) int32 — target row within the output node block.
     tile_node_block / tile_window : (T,) int32 — scalar-prefetched per-tile
         output-block / feature-window indices driving the BlockSpec index
@@ -297,16 +321,22 @@ def group_aggregate_pallas(feat_padded: jax.Array,
     ...     src_win=p.src_win, dt=128, out_rows=p.padded_out_rows)
     """
     n_src, d_pad = feat_padded.shape
-    assert n_src % src_win == 0 and d_pad % dt == 0, (n_src, d_pad, src_win, dt)
-    assert out_rows % ont == 0
     T = nbrs.shape[0]
-    assert nbrs.shape == (T, gpt, gs) and edge_val.shape == (T, gpt, gs)
+    if edge_val.ndim == 3:
+        edge_val = edge_val[None]
+    heads = edge_val.shape[0]
+    assert n_src % src_win == 0 and d_pad % (dt * heads) == 0, (
+        n_src, d_pad, src_win, dt, heads)
+    assert out_rows % ont == 0
+    assert nbrs.shape == (T, gpt, gs) and edge_val.shape[1:] == (T, gpt, gs)
     assert local_node.shape == (T, gpt)
     J = d_pad // dt
+    jh = J // heads                              # dim tiles per head
     kernel = functools.partial(_kernel, gs=gs, gpt=gpt, ont=ont,
                                src_win=src_win)
     K = gpt * gs
-    nbrs_rows, ev_rows, ln = _rows(nbrs), _rows(edge_val), _rows(local_node)
+    nbrs_rows, ln = _rows(nbrs), _rows(local_node)
+    ev_rows = _head_rows(edge_val)
     out = jnp.zeros((out_rows, d_pad), jnp.float32)
     for t0, nt in _launches(T):
         meta = lambda j, t, nb, tw, t0=t0: ((t0 + t) // _ROWS, 0)
@@ -316,7 +346,9 @@ def group_aggregate_pallas(feat_padded: jax.Array,
             in_specs=[
                 pl.BlockSpec((src_win, dt), lambda j, t, nb, tw: (tw[t], j)),
                 pl.BlockSpec((_ROWS, K), meta),
-                pl.BlockSpec((_ROWS, K), meta),
+                pl.BlockSpec((None, _ROWS, K),
+                             lambda j, t, nb, tw, t0=t0: (
+                                 j // jh, (t0 + t) // _ROWS, 0)),
                 pl.BlockSpec((_ROWS, gpt), meta),
                 pl.BlockSpec((ont, dt), lambda j, t, nb, tw: (nb[t], j)),
             ],

@@ -58,7 +58,7 @@ the transposed-schedule launch (the backward window DMAs enjoy the same
 bf16 halving), accumulated in f32, and the returned cotangents match the
 primals' dtypes (``feat.dtype`` and ``edge_values.dtype``).  Static edge
 values stay float32 inside schedules; dynamic edge values keep their own
-dtype through `_scatter_edge_values`.
+dtype through `_layout_edge_values`.
 """
 from __future__ import annotations
 
@@ -79,9 +79,9 @@ from repro.kernels.group_aggregate import (group_aggregate_pallas,
 if TYPE_CHECKING:                      # avoid core<->kernels import cycle
     from repro.core.partition import GroupPartition
 
-__all__ = ["aggregate", "DeviceSchedule", "dim_tile", "schedule_to_device",
-           "SchedView", "sched_arrays", "sched_static", "sched_statics",
-           "sched_statics_for", "BACKENDS", "resolve_backend"]
+__all__ = ["aggregate", "DeviceSchedule", "dim_tile", "head_columns",
+           "schedule_to_device", "SchedView", "sched_arrays", "sched_static",
+           "sched_statics", "sched_statics_for", "BACKENDS", "resolve_backend"]
 
 Backend = Literal["pallas", "pallas_interpret", "xla"]
 BACKENDS: tuple = ("pallas", "pallas_interpret", "xla")
@@ -134,6 +134,9 @@ class DeviceSchedule:
     backward schedule, ``edge_perm`` maps its CSR edge order back to the
     forward graph's edge order (``ev_bwd = ev_fwd[edge_perm]``); it is
     ``None`` for ordinary forward schedules.
+
+    ``slot_edge`` ((T*gpt*gs,)) is the CSR edge each slot holds, E for a
+    padded one: dynamic edge values are laid out by a gather through it.
     """
 
     def __init__(self, p: "GroupPartition",
@@ -146,12 +149,23 @@ class DeviceSchedule:
         self.edge_slot = jnp.asarray(p.edge_slot)
         self.edge_pos = jnp.asarray(p.edge_pos)
         self.edge_perm = None if edge_perm is None else jnp.asarray(edge_perm)
+        self.slot_edge = jnp.asarray(_slot_edges(p))
         self.gs, self.gpt, self.ont, self.src_win = p.gs, p.gpt, p.ont, p.src_win
         self.num_nodes = p.num_nodes
         self.num_edges = p.num_edges
         self.padded_src_rows = p.padded_src_rows
         self.padded_out_rows = p.padded_out_rows
         self.num_tiles = p.num_tiles
+
+
+def _slot_edges(p: "GroupPartition") -> np.ndarray:
+    """(T*gpt*gs,) int32: the CSR edge each of the partition's slots holds,
+    E (one past the last edge) for a padded slot."""
+    e = len(p.edge_slot)
+    slots = np.full(np.size(p.nbrs), e, np.int32)
+    slots[np.asarray(p.edge_slot, np.int64) * p.gs
+          + np.asarray(p.edge_pos)] = np.arange(e, dtype=np.int32)
+    return slots
 
 
 def schedule_to_device(p: "GroupPartition") -> DeviceSchedule:
@@ -172,10 +186,12 @@ def schedule_to_device(p: "GroupPartition") -> DeviceSchedule:
 # call sites over using these helpers directly.
 
 _SCHED_ARRAY_FIELDS = ("nbrs", "edge_val", "local_node", "tile_node_block",
-                       "tile_window", "edge_slot", "edge_pos", "edge_perm")
+                       "tile_window", "edge_slot", "edge_pos", "edge_perm",
+                       "slot_edge")
 # the first N fields are tile-shaped (uniform after tile padding) — the
-# (E,)-sized edge members sit after this split point so callers can drop
-# or pad them independently (Plan.jit_args, graph_shard stacking)
+# (E,)-sized edge members, and the slots' edge index that only dynamic
+# edge values read, sit after this split point so callers can drop them
+# (Plan.jit_args) or pad them to one edge count (graph_shard stacking)
 N_TILE_FIELDS = 5
 # num_edges deliberately NOT part of the static signature: raw edge counts
 # are unbucketed and nothing in the compute path reads them — including
@@ -246,20 +262,60 @@ def _pad_to(x: jax.Array, rows: int, cols: int) -> jax.Array:
     return jnp.pad(x, ((0, rows - r), (0, cols - c)))
 
 
-def _scatter_edge_values(sched: DeviceSchedule,
-                         edge_values: jax.Array) -> jax.Array:
-    """Lay per-edge values (original CSR order) out in schedule layout.
+def _head_tile(dt: int, d: int, heads: int, dtype) -> int:
+    """Dim-tile width for D columns that are ``heads`` equal head blocks:
+    `dim_tile` of one head's width.  With several heads a tile never spans
+    the whole array, so a head narrower than a lane tile is padded to one
+    (a block's minor dim is a multiple of 128 lanes or the array's)."""
+    dt_eff = dim_tile(dt, d // heads, dtype)
+    if heads > 1 and dt_eff % LANES:
+        dt_eff = LANES
+    return dt_eff
 
-    The scatter buffer keeps the edge values' own (float) dtype — under the
-    bf16 policy a bf16 edge-value tensor stays bf16 through the layout
+
+def head_columns(dt: int, d: int, heads: int, dtype) -> int:
+    """Columns each of ``heads`` equal head blocks of a D-wide operand
+    takes in the kernel: one head's width rounded up to its dim tile."""
+    tile = _head_tile(dt, d, heads, dtype)
+    return -(-(d // heads) // tile) * tile
+
+
+def _pad_heads(x: jax.Array, rows: int, heads: int,
+               width: int) -> jax.Array:
+    """(r, H*w) -> (rows, H*width): rows and each head block zero-padded.
+    One head stays 2-D: an (r, 1, w) view makes XLA lay out the arrays
+    around the kernel anew (copies the one-head step would pay for)."""
+    if heads == 1:
+        return _pad_to(x, rows, width)
+    r, d = x.shape
+    x = jnp.pad(x.reshape(r, heads, d // heads),
+                ((0, rows - r), (0, 0), (0, width - d // heads)))
+    return x.reshape(rows, heads * width)
+
+
+def _unpad_heads(x: jax.Array, n: int, heads: int, w: int) -> jax.Array:
+    """The inverse of `_pad_heads`: (rows, H*width) -> (n, H*w)."""
+    if heads == 1:
+        return x[:n, :w]
+    return x[:n].reshape(n, heads, -1)[:, :, :w].reshape(n, heads * w)
+
+
+def _layout_edge_values(sched: DeviceSchedule,
+                         edge_values: jax.Array) -> jax.Array:
+    """Lay per-edge values (original CSR order, (E, H)) out in schedule
+    layout, (H, T, gpt, gs): a gather through ``slot_edge``, padded slots
+    reading an appended 0.
+
+    The layout keeps the edge values' own (float) dtype — under the bf16
+    policy a bf16 edge-value tensor stays bf16 through the layout
     transform; the kernels up-cast to f32 at the accumulating matmul."""
     T, gpt, gs = sched.edge_val.shape
+    heads = edge_values.shape[1]
     ev_dtype = (edge_values.dtype
                 if jnp.issubdtype(edge_values.dtype, jnp.floating)
                 else jnp.float32)
-    return jnp.zeros((T * gpt, gs), ev_dtype).at[
-        sched.edge_slot, sched.edge_pos].set(
-        edge_values.astype(ev_dtype)).reshape(T, gpt, gs)
+    ev = jnp.pad(edge_values.astype(ev_dtype).T, ((0, 0), (0, 1)))
+    return jnp.take(ev, sched.slot_edge, axis=1).reshape(heads, T, gpt, gs)
 
 
 def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
@@ -269,12 +325,16 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
                     kernel_name: str = "group_aggregate_fwd") -> jax.Array:
     """Forward-only aggregation (no AD rule on the Pallas paths).
 
+    ``edge_values`` is None (the schedule's static values) or (E, H): head
+    h's values weight the h-th of H equal column blocks of ``feat``.
     Accumulates in f32; the result is cast to ``out_dtype`` (None =
     float32) as the final step — see the module docstring's dtype rules.
     ``kernel_name`` names the Pallas launches in the device trace: the
     backward pass over the transposed schedule passes
     ``group_aggregate_bwd``."""
     n, d = feat.shape
+    heads = 1 if edge_values is None else edge_values.shape[1]
+    assert d % heads == 0, (d, heads)
     out_dtype = jnp.float32 if out_dtype is None else out_dtype
     assert n == sched.num_nodes, (n, sched.num_nodes)
     if sched.num_tiles == 0:
@@ -291,24 +351,26 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
                                          sched.padded_out_rows)
         return out[:n].astype(out_dtype)
     if edge_values is not None:
-        ev = _scatter_edge_values(sched, edge_values)
+        ev = _layout_edge_values(sched, edge_values)
     else:
-        ev = sched.edge_val
+        ev = sched.edge_val[None]
     if backend == "xla":
         # edge-less schedules (the tile-only jit args `Plan.jit_args` passes
         # for shape bucketing) have no edge list to sum over: gather every
         # slot instead, padded slots contributing 0
-        out = _ref.group_aggregate_ref(
-            _pad_to(feat, sched.padded_src_rows, d),
-            sched.nbrs, ev, sched.local_node,
-            sched.tile_node_block, sched.ont, sched.padded_out_rows,
-        )
+        feat_p = _pad_to(feat, sched.padded_src_rows, d)
+        w = d // heads
+        out = jnp.concatenate([_ref.group_aggregate_ref(
+            feat_p[:, h * w:(h + 1) * w], sched.nbrs, ev[h],
+            sched.local_node, sched.tile_node_block, sched.ont,
+            sched.padded_out_rows) for h in range(heads)], axis=1)
         return out[:n].astype(out_dtype)
-    dt_eff = dim_tile(dt, d, feat.dtype)
-    d_pad = -(-d // dt_eff) * dt_eff
-    feat_p = _pad_to(feat, sched.padded_src_rows, d_pad)
+    dt_eff = _head_tile(dt, d, heads, feat.dtype)
+    w = d // heads
+    w_pad = -(-w // dt_eff) * dt_eff
     out = group_aggregate_pallas(
-        feat_p, sched.nbrs, ev, sched.local_node,
+        _pad_heads(feat, sched.padded_src_rows, heads, w_pad),
+        sched.nbrs, ev, sched.local_node,
         sched.tile_node_block, sched.tile_window,
         gs=sched.gs, gpt=sched.gpt, ont=sched.ont, src_win=sched.src_win,
         dt=dt_eff, out_rows=sched.padded_out_rows,
@@ -316,34 +378,37 @@ def _aggregate_impl(feat: jax.Array, sched: DeviceSchedule, *,
     )
     # node blocks no tile names (bipartite sampled blocks: edge-less rows
     # past num_dst) keep the zeros the kernel's accumulator starts from
-    return out[:n, :d].astype(out_dtype)
+    return _unpad_heads(out, n, heads, w).astype(out_dtype)
 
 
 def _edge_cotangent(g_out: jax.Array, feat: jax.Array,
-                    sched: DeviceSchedule, *, dt: int,
-                    backend: Backend) -> jax.Array:
-    """Cotangent w.r.t. per-edge values (original CSR order): the per-edge
-    gather-dot <g_out[dst], feat[src]>, via the forward schedule."""
+                    sched: DeviceSchedule, *, dt: int, backend: Backend,
+                    heads: int) -> jax.Array:
+    """Cotangent w.r.t. per-edge values (original CSR order, (E, H)): the
+    per-edge gather-dot <g_out[dst], feat[src]> over each head's columns,
+    via the forward schedule."""
     n, d = feat.shape
+    w = d // heads
     T, gpt, gs = sched.edge_val.shape
     if backend == "xla":
         src, dst, _ = _ref.schedule_edges(
             sched.nbrs, sched.local_node, sched.tile_node_block,
             sched.edge_slot, sched.edge_pos, sched.ont)
-        return (_pad_to(g_out, sched.padded_out_rows, d)[dst]
-                .astype(jnp.float32)
-                * feat[src].astype(jnp.float32)).sum(axis=-1)
-    dt_eff = dim_tile(dt, d, feat.dtype)
-    d_pad = -(-d // dt_eff) * dt_eff
+        prod = (_pad_to(g_out, sched.padded_out_rows, d)[dst]
+                .astype(jnp.float32) * feat[src].astype(jnp.float32))
+        return prod.reshape(-1, heads, w).sum(axis=-1)
+    dt_eff = _head_tile(dt, d, heads, feat.dtype)
+    w_pad = -(-w // dt_eff) * dt_eff
     per_slot = group_edge_grad_pallas(
-        _pad_to(g_out, sched.padded_out_rows, d_pad),
-        _pad_to(feat, sched.padded_src_rows, d_pad),
+        _pad_heads(g_out, sched.padded_out_rows, heads, w_pad),
+        _pad_heads(feat, sched.padded_src_rows, heads, w_pad),
         sched.nbrs, sched.local_node,
         sched.tile_node_block, sched.tile_window,
         gs=sched.gs, gpt=sched.gpt, ont=sched.ont,
-        src_win=sched.src_win, dt=dt_eff,
+        src_win=sched.src_win, dt=dt_eff, heads=heads,
         interpret=(backend == "pallas_interpret"))
-    return per_slot.reshape(T * gpt, gs)[sched.edge_slot, sched.edge_pos]
+    return per_slot.reshape(heads, T * gpt, gs)[
+        :, sched.edge_slot, sched.edge_pos].T
 
 
 # --- the differentiable wrapper: forward over the CSR schedule, backward
@@ -382,8 +447,8 @@ def _aggregate_diff_bwd(statics, statics_bwd, opts, res, g_out):
         ev_bar = None
     else:
         ev_bwd = edge_values[sched_bwd.edge_perm]
-        ev_bar = _edge_cotangent(g_out, feat, sched,
-                                 dt=dt, backend=backend
+        ev_bar = _edge_cotangent(g_out, feat, sched, dt=dt, backend=backend,
+                                 heads=edge_values.shape[1]
                                  ).astype(edge_values.dtype)
     feat_bar = _aggregate_impl(g_out, sched_bwd, dt=dt, backend=backend,
                                edge_values=ev_bwd,
@@ -410,9 +475,13 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
 
     backend: None resolves by platform (`resolve_backend`).
 
-    edge_values: optional (E,) per-edge weights in ORIGINAL CSR edge order,
-    overriding the schedule's static values — the dynamic-edge-value path
-    GAT-type aggregation needs (weights recomputed every forward).
+    edge_values: optional (E,) or (E, H) per-edge weights in ORIGINAL CSR
+    edge order, overriding the schedule's static values — the
+    dynamic-edge-value path attention needs (weights recomputed every
+    forward).  With H heads, ``feat``'s D columns are H equal head blocks
+    and head h's values weight the h-th; (E,) is the one-head case.  Their
+    cotangent has their shape: per edge and head, a dot over that head's
+    columns.
 
     sched_bwd: optional `DeviceSchedule` over the TRANSPOSED graph (same
     config), making the call differentiable w.r.t. ``feat`` and
@@ -421,6 +490,8 @@ def aggregate(feat: jax.Array, sched: DeviceSchedule, *,
     builds the pair with ``with_backward=True``.
     """
     backend = resolve_backend(backend)
+    if edge_values is not None and edge_values.ndim == 1:
+        edge_values = edge_values[:, None]
     if sched_bwd is None:
         return _aggregate_impl(feat, sched, dt=dt, backend=backend,
                                edge_values=edge_values, out_dtype=out_dtype)

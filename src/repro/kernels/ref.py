@@ -46,9 +46,18 @@ def selective_scan_ref(xc, dt_raw, b, c, a_log, dt_bias, d_skip):
 
 def segment_aggregate_ref(feat: jax.Array, src: jax.Array, dst: jax.Array,
                           edge_val: jax.Array, num_nodes: int) -> jax.Array:
-    """out[v] = sum_{e: dst_e = v} edge_val_e * feat[src_e]   (float32 accum)."""
+    """out[v] = sum_{e: dst_e = v} edge_val_e * feat[src_e]   (float32 accum).
+
+    ``edge_val`` (E,) or (E, H): with H heads, feat's columns are H equal
+    head blocks and head h's values weight head h's columns."""
     gathered = jnp.take(feat, src, axis=0).astype(jnp.float32)
-    gathered = gathered * edge_val[:, None].astype(jnp.float32)
+    ev = edge_val.astype(jnp.float32)
+    if ev.ndim == 1:
+        gathered = gathered * ev[:, None]
+    else:
+        e, d = gathered.shape
+        gathered = (gathered.reshape(e, ev.shape[1], -1)
+                    * ev[:, :, None]).reshape(e, d)
     return jax.ops.segment_sum(gathered, dst, num_segments=num_nodes)
 
 

@@ -365,14 +365,15 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     if args.sampled and args.arch not in ("gcn", "gin"):
-        p.error("--sampled supports gcn/gin only")
+        p.error("--sampled supports gcn/gin only (GAT trains full-graph)")
     if args.stream_deltas and not args.sampled:
         p.error("--stream-deltas requires --sampled (the resident-graph "
                 "loader owns the swap protocol)")
     if args.shards < 1:
         p.error("--shards must be >= 1")
     if args.shards > 1 and args.arch not in ("gcn", "gin"):
-        p.error("--shards supports gcn/gin only")
+        p.error("--shards supports gcn/gin only (GAT trains on one "
+                "device)")
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     if args.arch in GNN_ARCHS:

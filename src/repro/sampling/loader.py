@@ -112,7 +112,8 @@ class SampledLoader:
             # jitted step (gat needs per-block dynamic-edge plumbing the
             # sampled path does not carry)
             raise ValueError(
-                f"sampled training supports gcn/gin, not {cfg.arch!r}")
+                f"sampled training supports gcn/gin, not {cfg.arch!r}: "
+                "GAT trains full-graph only")
         if len(loader.fanouts) != cfg.num_layers:
             raise ValueError(
                 f"fanouts {loader.fanouts} must name one fanout per layer "

@@ -38,7 +38,8 @@ import numpy as np
 
 from repro.graphs.csr import CSRGraph
 from repro.graphs.subgraph import batch_egos, extract_ego, pad_to_nodes
-from repro.models.gnn import GNNConfig, GNNModel, gcn_edge_values, init_gnn_params
+from repro.models.gnn import (GNNConfig, GNNModel, aggregation_graph,
+                              gcn_edge_values, init_gnn_params)
 from repro.obs import MetricsRegistry, SpanTracer, pow2_bounds
 from repro.serving.admission import AdmissionQueue, AsyncRequest, SLOClass
 from repro.serving.batcher import (ClockBatcher, DeadlineBatcher,
@@ -148,11 +149,9 @@ class ServingEngine:
         self.params = params if params is not None else init_gnn_params(
             cfg, key if key is not None else jax.random.PRNGKey(0))
         # resident aggregation graph: GCN folds self-loops + A-hat weights
-        # from FULL-graph degrees; GIN/GAT aggregate the raw graph.
-        if cfg.arch == "gcn":
-            self.src_graph, self.src_vals = gcn_edge_values(graph)
-        else:
-            self.src_graph, self.src_vals = graph, None
+        # from FULL-graph degrees; GAT attends over A + I; GIN aggregates
+        # the raw graph.
+        self.src_graph, self.src_vals = aggregation_graph(graph, cfg.arch)
         # one registry per engine unless the caller threads a shared one in
         # (the launch drivers do — engine + cache + tracer then export as
         # one document; see docs/observability.md)
@@ -231,7 +230,7 @@ class ServingEngine:
             with self.trace.span("plan"):
                 ent = self.cache.get_or_build(
                     sub, arch=cfg.arch, in_dim=cfg.in_dim,
-                    hidden_dim=cfg.hidden_dim, num_layers=cfg.num_layers,
+                    hidden_dim=cfg.agg_hidden_dim, num_layers=cfg.num_layers,
                     edge_vals=vals, epoch=self.graph_epoch,
                     tracer=self.trace)
                 if ent.apply_fn is None:
@@ -329,10 +328,7 @@ class ServingEngine:
                 feat2 = np.concatenate([feat2, new])
         assert feat2.shape == (g2.num_nodes, cfg.in_dim), \
             (feat2.shape, g2.num_nodes, cfg.in_dim)
-        if cfg.arch == "gcn":
-            src_graph, src_vals = gcn_edge_values(g2)
-        else:
-            src_graph, src_vals = g2, None
+        src_graph, src_vals = aggregation_graph(g2, cfg.arch)
         self.graph, self.feat = g2, feat2
         self.src_graph, self.src_vals = src_graph, src_vals
         self.graph_epoch += 1

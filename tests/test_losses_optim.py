@@ -7,8 +7,8 @@ import jax.numpy as jnp
 
 from repro.nn.losses import chunked_softmax_xent, softmax_xent_dense
 from repro.optim.adamw import (AdamWConfig, adamw_init, adamw_update,
-                               clip_by_global_norm, cosine_schedule,
-                               global_norm, linear_warmup)
+                               bias_correction, clip_by_global_norm,
+                               cosine_schedule, global_norm, linear_warmup)
 from repro.optim.compression import (compress_decompress, ef_init,
                                      quantize_int8, dequantize_int8)
 
@@ -112,3 +112,14 @@ def test_error_feedback_beats_plain_quantization():
             w = w - 0.05 * g_hat
         return float(jnp.abs(w - target).max())
     assert run(True) <= run(False) + 1e-6
+
+
+@pytest.mark.parametrize("beta", [0.0, 0.9, 0.95, 0.999, 0.9999])
+def test_bias_correction_to_float32_precision(beta):
+    """1 - beta**t against double precision over the first thousand steps:
+    formed directly in float32 it cancels for beta near 1."""
+    steps = np.arange(1, 1001)
+    got = np.asarray(jax.jit(lambda t: bias_correction(beta, t))(
+        jnp.asarray(steps, jnp.int32)), np.float64)
+    want = 1.0 - beta ** steps.astype(np.float64)
+    np.testing.assert_allclose(got, want, rtol=1e-6)

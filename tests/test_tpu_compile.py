@@ -163,3 +163,40 @@ def test_forward_and_backward_launches_are_named_by_direction(one_chip):
     ).compile().as_text()
     assert "%group_aggregate_fwd" in text and "%group_aggregate_bwd" in text
     assert "%group_aggregate." not in text
+
+
+# GAT-PPI's plan (the tuner's pick at width 1,024) and its head layouts:
+# layers 1-2 (4 heads of 256), layer 3 (6 of 121, each padded to a lane
+# tile) and the softmax normalisers (a column of ones a head)
+_PPI = AggConfig(gs=4, gpt=32, dt=512, src_win=512, ont=128)
+
+
+@pytest.mark.parametrize("heads,width", [(4, 256), (6, 121), (4, 1)],
+                         ids=["hidden_heads", "output_heads", "normaliser"])
+@pytest.mark.parametrize("kernel", ["aggregate", "edge_grad"])
+def test_head_indexed_kernels_compile(one_chip, kernel, heads, width):
+    """A value per edge and head: each dim tile weights its window with
+    its head's values (forward), and each head's dim tiles accumulate the
+    edge cotangent of that head (backward)."""
+    from repro.kernels.ops import _head_tile
+
+    cfg, dtype = _PPI, "float32"
+    dt = _head_tile(cfg.dt, heads * width, heads, jnp.dtype(dtype))
+    d_pad = heads * -(-width // dt) * dt
+    s = lambda shape, dt_: _shape(one_chip, shape, dt_)
+    meta = (s((T, cfg.gpt, cfg.gs), "int32"),)
+    tail = (s((T, cfg.gpt), "int32"), s((T,), "int32"), s((T,), "int32"))
+    if kernel == "aggregate":
+        args = (s((4 * cfg.src_win, d_pad), dtype), *meta,
+                s((heads, T, cfg.gpt, cfg.gs), "float32"), *tail)
+        fn = lambda *a: group_aggregate_pallas(
+            *a, gs=cfg.gs, gpt=cfg.gpt, ont=cfg.ont, src_win=cfg.src_win,
+            dt=dt, out_rows=T * cfg.ont)
+    else:
+        args = (s((T * cfg.ont, d_pad), dtype),
+                s((4 * cfg.src_win, d_pad), dtype), *meta, *tail)
+        fn = lambda *a: group_edge_grad_pallas(
+            *a, gs=cfg.gs, gpt=cfg.gpt, ont=cfg.ont, src_win=cfg.src_win,
+            dt=dt, heads=heads)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
