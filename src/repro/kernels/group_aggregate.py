@@ -19,9 +19,13 @@ One `pl.pallas_call` realizes the paper's §5 workload-management stack:
 The gather is ``folded``: edge weights and the intra-group sum are folded
 INTO the gather matrix (W[g, r] = Σ_s ev[g,s]·1[nbr=r]), so each grid step
 runs one (gpt, src_win) @ (src_win, dt) MXU matmul against the VMEM-resident
-feature window.  (The paper-faithful one-hot-row-per-slot mapping and a
-dynamic-slice ``direct`` gather were dropped: neither passes the Mosaic
-compiler — `docs/performance.md`.)
+feature window.  The fold itself runs on the VPU: one compare-select of a
+(gpt, src_win) block per slot position s, summed over the gs positions, so
+the MXU sees only the gather and the node scatter.  (The paper-faithful
+one-hot-row-per-slot mapping and a dynamic-slice ``direct`` gather were
+dropped: neither passes the Mosaic compiler — `docs/performance.md`.  A
+(gpt, gpt*gs) group one-hot matmul over per-slot rows, which summed the
+same exact values, took about 45% of a grid step's MXU weight pushes.)
 
 Grid = (D/dt, T) with tiles innermost so output/feature block revisits are
 consecutive.  Scalar-prefetched per-tile metadata (`tile_node_block`,
@@ -35,10 +39,12 @@ gs=4).  So per-tile metadata runs as (T, gpt*gs) rows read in (8, gpt*gs)
 blocks — 8 tiles per block — and ``local_node`` as (8, gpt) blocks of
 (T, gpt), T padded to a multiple of 8.  Each grid step takes its tile's
 row out of the block in-kernel (a transposed block gives the slots as a
-column).
+column).  The aggregation kernel's rows are slot-major, (T, gs, gpt)
+flattened, so slot position s of every group is one contiguous run of
+gpt rows of that column; the edge-gradient kernel's stay group-major.
 
 Edge values may carry a head axis (attention's): ``(H, T, gpt, gs)``, one
-value per slot and head, laid out as (H, T, gpt*gs) rows.  The feature
+value per slot and head, laid out as (H, T, gs*gpt) rows.  The feature
 columns are then H equal head blocks, each a whole number of dim tiles,
 and dim tile j weights its window with head ``j // (J / H)``'s values, so
 each grid step is the one-head kernel; H = 1 is the plain aggregation.
@@ -132,17 +138,19 @@ def _kernel(nb_ref, tw_ref,                       # scalar prefetch (SMEM)
     def _load():
         out_ref[...] = acc_ref[...]
 
-    local = _tile_column(nbrs_ref, t) - tw_ref[t] * src_win  # (gpt*gs, 1)
+    # slot-major columns: rows s*gpt .. (s+1)*gpt are slot s of every group
+    local = _tile_column(nbrs_ref, t) - tw_ref[t] * src_win  # (gs*gpt, 1)
     evals = _tile_column(eval_ref, t).astype(jnp.float32)   # 0 => padding
     feat = feat_ref[...]                                     # (src_win, dt)
 
-    # W[g, r] = sum_s evals[g, s] * 1[local[g, s] == r]: the intra-group
-    # reduction folds into the gather matrix, so one (gpt, src_win) @
-    # (src_win, dt) matmul gathers and sums every group of the tile
-    cols = jax.lax.broadcasted_iota(jnp.int32, (gpt * gs, src_win), 1)
-    slot_w = jnp.where(local == cols, evals, 0.0)            # (gpt*gs, W)
-    w = jnp.dot(_group_onehot(gpt, gs), slot_w, precision=_EXACT,
-                preferred_element_type=jnp.float32)          # (gpt, W)
+    # W[g, r] = sum_s evals[s, g] * 1[local[s, g] == r]: the intra-group
+    # reduction folds into the gather matrix on the VPU, one compare-select
+    # per slot position, so one (gpt, src_win) @ (src_win, dt) matmul
+    # gathers and sums every group of the tile
+    cols = jax.lax.broadcasted_iota(jnp.int32, (gpt, src_win), 1)
+    slot = lambda s: jnp.where(local[s * gpt:(s + 1) * gpt] == cols,
+                               evals[s * gpt:(s + 1) * gpt], 0.0)
+    w = functools.reduce(jnp.add, map(slot, range(gs)))      # (gpt, W)
     # (bf16 windows multiply bf16 operands: exact products, f32 sums)
     per_group = jnp.dot(w.astype(feat.dtype), feat,
                         precision=_EXACT if feat.dtype == jnp.float32
@@ -335,8 +343,10 @@ def group_aggregate_pallas(feat_padded: jax.Array,
     kernel = functools.partial(_kernel, gs=gs, gpt=gpt, ont=ont,
                                src_win=src_win)
     K = gpt * gs
-    nbrs_rows, ln = _rows(nbrs), _rows(local_node)
-    ev_rows = _head_rows(edge_val)
+    # slot-major metadata rows (see `_kernel`)
+    nbrs_rows = _rows(jnp.swapaxes(nbrs, 1, 2))
+    ev_rows = _head_rows(jnp.swapaxes(edge_val, 2, 3))
+    ln = _rows(local_node)
     out = jnp.zeros((out_rows, d_pad), jnp.float32)
     for t0, nt in _launches(T):
         meta = lambda j, t, nb, tw, t0=t0: ((t0 + t) // _ROWS, 0)

@@ -8,8 +8,10 @@ import jax.numpy as jnp
 from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core.partition import partition_graph
+from repro.core.tuner import SEARCH_SPACE
 from repro.graphs.csr import from_edges, grid_graph, random_power_law
 from repro.kernels import ref
+from repro.kernels.group_aggregate import group_aggregate_pallas
 from repro.kernels.ops import DeviceSchedule, aggregate
 
 
@@ -165,3 +167,58 @@ def test_multi_launch_matches_reference(kernel, rng, monkeypatch):
                                    jax.grad(loss("xla"))(evj),
                                    atol=1e-4, rtol=1e-4)
     jax.clear_caches()
+
+
+# every group size the tuner searches, at its narrowest gpt and GCN's, with
+# one head and four; then a schedule over several launches, and sources
+# repeated inside a group (a multigraph)
+_FOLD_CASES = [
+    pytest.param(gs, gpt, heads, False, False, id=f"gs{gs}-gpt{gpt}-h{heads}")
+    for gs in SEARCH_SPACE["gs"] for gpt in (8, 32) for heads in (1, 4)
+] + [pytest.param(4, 8, 1, True, False, id="two_launches"),
+     pytest.param(8, 8, 4, False, True, id="repeated_source")]
+
+
+@pytest.mark.parametrize("gs,gpt,heads,multi_launch,multigraph", _FOLD_CASES)
+def test_folded_gather_matches_reference(gs, gpt, heads, multi_launch,
+                                         multigraph, rng, monkeypatch):
+    """The kernel folds each group's gs slots into its gather-matrix row
+    one slot position at a time: it matches the segment-sum oracle at every
+    plan, head-indexed values included."""
+    from repro.kernels import group_aggregate
+
+    if multi_launch:
+        monkeypatch.setattr(group_aggregate, "MAX_TILES_PER_CALL", 8)
+        jax.clear_caches()
+    g = random_power_law(160, 6.0, seed=gs + gpt)
+    if multigraph:                              # every edge twice
+        dst, src = g.to_coo()
+        g = from_edges(g.num_nodes, np.tile(src, 2), np.tile(dst, 2),
+                       dedup=False)
+    src_win, ont, dt = 64, 8, 8
+    p = partition_graph(g, gs=gs, gpt=gpt, ont=ont, src_win=src_win)
+    T = p.num_tiles
+    if multi_launch:
+        assert T > 8
+    ev = rng.uniform(0.5, 1.5, (g.num_edges, heads)).astype(np.float32)
+    slot_ev = np.zeros((heads, T * gpt * gs), np.float32)
+    slot_ev[:, p.edge_slot * gs + p.edge_pos] = ev.T
+    slot_ev = slot_ev.reshape(heads, T, gpt, gs)
+    if multigraph:                              # a group gathers a row twice
+        real = slot_ev[0] != 0
+        assert any(len(set(nb[m])) < m.sum()
+                   for nb, m in zip(p.nbrs.reshape(-1, gs),
+                                    real.reshape(-1, gs)))
+    d = 2 * dt if heads == 1 else heads * dt
+    feat = rng.standard_normal((-(-g.num_nodes // src_win) * src_win, d))
+    feat = feat.astype(np.float32)
+    got = group_aggregate_pallas(
+        jnp.asarray(feat), jnp.asarray(p.nbrs),
+        jnp.asarray(slot_ev if heads > 1 else slot_ev[0]),
+        jnp.asarray(p.local_node), jnp.asarray(p.tile_node_block),
+        jnp.asarray(p.tile_window), gs=gs, gpt=gpt, ont=ont,
+        src_win=src_win, dt=dt, out_rows=p.padded_out_rows, interpret=True)
+    want = _oracle(g, feat[:g.num_nodes], ev if heads > 1 else ev[:, 0])
+    np.testing.assert_allclose(got[:g.num_nodes], want, atol=1e-4, rtol=1e-4)
+    if multi_launch:
+        jax.clear_caches()

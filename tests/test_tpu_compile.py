@@ -81,12 +81,25 @@ def _compile_edge_grad(sharding, cfg: AggConfig, d: int, dtype: str) -> str:
 
 
 _BASE = AggConfig(gs=16, gpt=16, dt=128, src_win=512)
+# GCN-blogcatalog's plan (at its widths 16 and 40), and the longest fold
+# of slot positions at the tallest window, gs 64 over src_win 2048: the
+# forward kernel holds no per-slot matrix, so it compiles where Eq. 4's
+# model (which prices the edge-gradient kernel's per-slot matrices too)
+# refuses the plan
+_GCN = AggConfig(gs=4, gpt=32, dt=128, src_win=1024, ont=128)
+_UNROLL = AggConfig(gs=64, gpt=8, dt=128, src_win=2048)
+
+_AGG_CASES = (
+    [pytest.param(_BASE, dtype, d, id=f"{dtype}-{d}")
+     for dtype in DTYPES for d in (16, 128, 640)]
+    + [pytest.param(_GCN, "float32", d, id=f"gcn_plan-{d}") for d in (16, 40)]
+    + [pytest.param(_UNROLL, dtype, 16, id=f"gs64_unroll-{dtype}")
+       for dtype in DTYPES])
 
 
-@pytest.mark.parametrize("d", [16, 128, 640])
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_aggregate_compiles(one_chip, dtype, d):
-    assert "tpu_custom_call" in _compile_fwd(one_chip, _BASE, d, dtype)
+@pytest.mark.parametrize("cfg,dtype,d", _AGG_CASES)
+def test_aggregate_compiles(one_chip, cfg, dtype, d):
+    assert "tpu_custom_call" in _compile_fwd(one_chip, cfg, d, dtype)
 
 
 @pytest.mark.parametrize("d", [16, 128, 640])
